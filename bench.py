@@ -20,8 +20,15 @@ cache carries compilations across processes and rounds.
 
 Prints ONE JSON line: the headline SchedulingBasic number vs the
 reference's 270 pods/s CI floor (misc/performance-config.yaml:63), with
-per-workload results (value, threshold, vs_baseline, window percentiles)
-under "workloads".
+per-workload results (value, threshold, vs_baseline, window percentiles,
+and the device each row ran on) under "workloads". Exits non-zero,
+naming them, when any workload failed or timed out.
+
+On a machine with a chip: `python bench.py --no-test-gate` (the default
+path first runs the whole pytest suite). This process never touches JAX,
+so each run_one child has the chip to itself; the control-plane gates
+(--scaleout, --fanout-smoke, --overload, --chaos-smoke) force their
+children onto the CPU.
 """
 
 from __future__ import annotations
@@ -192,7 +199,7 @@ def _ab_scorer_run(workdir: str, smoke: bool, scale: float,
                    generations: int = 1) -> dict:
     from kubernetes_tpu.utils import jaxsetup
 
-    jaxsetup.setup(os.path.join(_repo, ".jax_cache"))
+    jaxsetup.setup()
 
     from kubernetes_tpu.config.types import Plugin, default_config
     from kubernetes_tpu.learn.checkpoint import save_checkpoint
@@ -514,7 +521,7 @@ def trace_overhead_smoke(pairs: int = 4) -> dict:
 
     from kubernetes_tpu.utils import jaxsetup
 
-    jaxsetup.setup(os.path.join(_repo, ".jax_cache"))
+    jaxsetup.setup()
     from kubernetes_tpu.config.types import default_config
     from kubernetes_tpu.perf.harness import run_workload
     from kubernetes_tpu.perf.workloads import scheduling_basic
@@ -571,6 +578,18 @@ def trace_overhead_smoke(pairs: int = 4) -> dict:
     }
 
 
+def _control_plane_env() -> dict:
+    """Environment for the control-plane gates' children (fabric shard
+    processes, scheduler replica arms, the chaos battery): they measure
+    no device metric and must not hold the chip — N of them would fight
+    over the one a measurement needs — so the CPU platform is set HARD,
+    whatever the caller's environment names."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = _repo + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
+
+
 def run_scaleout_bench(smoke: bool = False, replicas: int = 4,
                        timeout_s: float = 300.0) -> dict:
     """--scaleout: horizontal scale-out throughput A/B. Two arms on a
@@ -591,9 +610,7 @@ def run_scaleout_bench(smoke: bool = False, replicas: int = 4,
 
     pods = 200 if smoke else 800
     nodes = 16
-    env = dict(os.environ)
-    env["PYTHONPATH"] = _repo + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _control_plane_env()
 
     def run_arm(n_replicas: int) -> dict:
         from kubernetes_tpu.fabric.supervisor import spawn_local_cluster
@@ -679,11 +696,62 @@ def run_scaleout_bench(smoke: bool = False, replicas: int = 4,
     # instead of failing hardware that can't show the win
     cores = os.cpu_count() or 1
     hardware_limited = cores < replicas + 1
-    return {"metric": "scaleout", "single": single, "multi": multi,
+    return {"metric": "scaleout", "platform": "cpu",
+            "single": single, "multi": multi,
             "speedup": round(speedup, 2), "floor": 3.0,
             "cores": cores, "hardware_limited": hardware_limited,
             "ok": (single["complete"] and multi["complete"]
                    and (speedup >= 3.0 or hardware_limited))}
+
+
+# what names the device a row was measured on, and whether the run
+# stayed on it — kept on every published row
+ROW_DEVICE_KEYS = ("platform", "device_kind", "device_count",
+                   "device_fallbacks")
+ROW_KEYS = ("name", "pods_per_sec", "threshold", "vs_baseline", "passed",
+            "pods_scheduled", "elapsed_s", "p50", "p90", "p95", "p99",
+            "metrics", "quality", "measured_compiles", *ROW_DEVICE_KEYS)
+
+
+def run_workloads(fns, args: list[str], env: dict,
+                  run=None) -> tuple[dict, dict | None, list]:
+    """One ``perf.run_one`` subprocess per workload. Returns (rows by
+    short name, the SchedulingBasic row, names of workloads that failed
+    or timed out). A wedged or failed workload must not kill the bench —
+    the rest are still measured — but it is reported, and main() exits
+    non-zero on it. ``run`` (default subprocess.run) is the seam the
+    tests stub."""
+    run = run or subprocess.run
+    results: dict = {}
+    headline = None
+    failed: list[str] = []
+    for fn in fns:
+        try:
+            proc = run(
+                [sys.executable, "-m", "kubernetes_tpu.perf.run_one", fn,
+                 *args],
+                capture_output=True, text=True, timeout=1800, env=env,
+                cwd=_repo)
+        except subprocess.TimeoutExpired:
+            print(f"{fn}: TIMEOUT after 1800s", file=sys.stderr)
+            failed.append(fn)
+            continue
+        if proc.returncode != 0:
+            print(f"{fn}: FAILED\n{proc.stderr[-2000:]}", file=sys.stderr)
+            failed.append(fn)
+            continue
+        r = json.loads(proc.stdout.strip().splitlines()[-1])
+        print(f"{r['name']}: {r.get('pods_per_sec', 0):.1f} pods/s "
+              f"(threshold {r['threshold']}, warm {r.get('warm_s')}s, "
+              f"run {r.get('run_s')}s, {r.get('platform')})",
+              file=sys.stderr)
+        short = r["name"].split("/")[0]
+        if short in results:
+            short = r["name"]   # variant rows (e.g. _QueueingHintsEnabled)
+        results[short] = {k: r[k] for k in ROW_KEYS if k in r}
+        if short == "SchedulingBasic":
+            headline = r
+    return results, headline, failed
 
 
 def main() -> None:
@@ -725,7 +793,6 @@ def main() -> None:
         # processes over the slice ring must clear 3x one process's
         # pods/s, with the single-process arm measured fresh as the
         # no-regression reference
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
         r = run_scaleout_bench(smoke="--smoke" in sys.argv)
         print(json.dumps(r))
         if r["hardware_limited"]:
@@ -762,9 +829,7 @@ def main() -> None:
         # subscribers are evicted + recover, the binary codec carries
         # the storm in <= 1/3 the JSON bytes, and a steady-state drift
         # sentinel pass issues 0 full LISTs.
-        env = dict(os.environ)
-        env["PYTHONPATH"] = _repo + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = _control_plane_env()
         # both deployment modes, side by side in the artifact: the
         # in-process fabric (PR 9's tree) and the PROCESS-MODE fabric
         # (shard processes + stateless router + auto-discovered
@@ -793,6 +858,7 @@ def main() -> None:
                       f"{proc.stderr[-2000:]}", file=sys.stderr)
         combined["ok"] = all(combined[k].get("ok")
                              for k in ("inproc", "procs"))
+        combined["platform"] = "cpu"
         print(json.dumps(combined))
         sys.exit(rc if rc else (0 if combined["ok"] else 1))
     if "--scenario" in sys.argv:
@@ -829,7 +895,7 @@ def main() -> None:
         # stampede regime (SLO judged over priority uids only — the
         # shed best-effort tail is the protection working), plus the
         # flow-control shed accounting from the overload storm
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        os.environ["JAX_PLATFORMS"] = "cpu"   # control-plane gate
         from kubernetes_tpu.chaos import run_overload_storm
         from kubernetes_tpu.scenario.generators import generate
         from kubernetes_tpu.scenario.replay import replay_trace
@@ -841,6 +907,7 @@ def main() -> None:
         storm = run_overload_storm(seed=seed)
         print(json.dumps({
             "metric": "overload",
+            "platform": "cpu",
             "scenario": rep["name"],
             "speed": rep["speed"],
             "priority_pods": rep["slo_pods"],
@@ -892,16 +959,20 @@ def main() -> None:
         # kill-and-restart). Invariants: every pod bound exactly once
         # (fencing + bind-once), zero daemon deaths, poison quarantined
         # with a hub Event, cache-hub converged.
-        env = dict(os.environ)
-        env["PYTHONPATH"] = _repo + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = _control_plane_env()
         proc = subprocess.run(
             [sys.executable, "-m", "kubernetes_tpu.chaos",
              "--storm", "all"],
             capture_output=True, text=True, timeout=1200, env=env,
             cwd=_repo)
         out = proc.stdout.strip().splitlines()
-        print(out[-1] if out else '{"ok": false, "error": "no output"}')
+        try:
+            storm = json.loads(out[-1])
+        except (IndexError, ValueError):
+            storm = {"ok": False, "error": out[-1][:500] if out
+                     else "no output"}
+        storm["platform"] = "cpu"
+        print(json.dumps(storm))
         if proc.returncode != 0:
             print(f"chaos smoke FAILED\n{proc.stderr[-2000:]}",
                   file=sys.stderr)
@@ -922,8 +993,6 @@ def main() -> None:
     # throwaway alt-exporting trace file — opt-in because the alt
     # top_k + export I/O are a measured-perf change)
     regret_args = ["--regret"] if "--regret" in sys.argv else []
-    results = {}
-    headline = None
     env = dict(os.environ)
     env["PYTHONPATH"] = _repo + os.pathsep + env.get("PYTHONPATH", "")
     if not smoke and "--no-test-gate" not in sys.argv:
@@ -949,45 +1018,24 @@ def main() -> None:
                   file=sys.stderr)
             sys.exit(1)
         print("bench: test gate green", file=sys.stderr)
-    for fn in BENCH_WORKLOAD_FNS:
-        try:
-            proc = subprocess.run(
-                [sys.executable, "-m", "kubernetes_tpu.perf.run_one", fn,
-                 "--scale", scale, *regret_args],
-                capture_output=True, text=True, timeout=1800, env=env,
-                cwd=_repo)
-        except subprocess.TimeoutExpired:
-            # a wedged workload must not kill the whole bench: report and
-            # keep measuring the rest
-            print(f"{fn}: TIMEOUT after 1800s", file=sys.stderr)
-            continue
-        if proc.returncode != 0:
-            print(f"{fn}: FAILED\n{proc.stderr[-2000:]}", file=sys.stderr)
-            continue
-        r = json.loads(proc.stdout.strip().splitlines()[-1])
-        print(f"{r['name']}: {r.get('pods_per_sec', 0):.1f} pods/s "
-              f"(threshold {r['threshold']}, warm {r.get('warm_s')}s, "
-              f"run {r.get('run_s')}s)", file=sys.stderr)
-        short = r["name"].split("/")[0]
-        if short in results:
-            short = r["name"]   # variant rows (e.g. _QueueingHintsEnabled)
-        results[short] = {k: r[k] for k in (
-            "name", "pods_per_sec", "threshold", "vs_baseline", "passed",
-            "pods_scheduled", "elapsed_s", "p50", "p90", "p95", "p99",
-            "metrics", "quality")
-            if k in r}
-        if short == "SchedulingBasic":
-            headline = r
-
-    assert headline is not None, "SchedulingBasic must produce a result"
-    print(json.dumps({
-        "metric": "scheduling_throughput_5000nodes_production_path",
-        "value": round(headline["pods_per_sec"], 1),
-        "unit": "pods/sec",
-        "vs_baseline": round(headline["pods_per_sec"] / BASELINE_PODS_PER_SEC,
-                             2),
-        "workloads": results,
-    }))
+    results, headline, failed = run_workloads(
+        BENCH_WORKLOAD_FNS, ["--scale", scale, *regret_args], env)
+    if headline is not None:
+        print(json.dumps({
+            "metric": "scheduling_throughput_5000nodes_production_path",
+            "value": round(headline["pods_per_sec"], 1),
+            "unit": "pods/sec",
+            "vs_baseline": round(
+                headline["pods_per_sec"] / BASELINE_PODS_PER_SEC, 2),
+            **{k: headline[k] for k in ROW_DEVICE_KEYS},
+            "workloads": results,
+        }))
+    if failed:
+        # every workload was still measured first; a bench with a hole
+        # in it must not read as green
+        print("bench: FAILED or TIMED OUT: " + ", ".join(failed),
+              file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -170,6 +170,10 @@ def main(argv=None) -> int:
         if args.wal:
             print(f"hub journal WAL at {args.wal} "
                   f"(replayed rv={hub.current_rv})", file=sys.stderr)
+    # name the device the fused launches will run on, before any work
+    import json
+
+    print("device: " + json.dumps(jaxsetup.device_info()), file=sys.stderr)
     sched = Scheduler(hub, cfg)
 
     if args.fleet_endpoint:

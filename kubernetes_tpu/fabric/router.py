@@ -229,7 +229,7 @@ class _RouterHandler(_Handler):
                     http.client.HTTPException):
                 # a shard dying mid-frame surfaces IncompleteRead (an
                 # HTTPException) from the exact-length frame read —
-                # the same taxonomy hubclient's consume() handles
+                # the same error classes hubclient's consume() handles
                 pass
             finally:
                 events.put((shard, _DONE))

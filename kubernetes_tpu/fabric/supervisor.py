@@ -68,7 +68,9 @@ class FabricSupervisor:
     def spawn(self, name: str, role: str, extra: list[str]) -> FabricProc:
         env = dict(os.environ)
         env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        # shard/router/state processes are control plane: they must
+        # never take the chip from the scheduler process that needs it
+        env["JAX_PLATFORMS"] = "cpu"
         args = [sys.executable, "-m", "kubernetes_tpu.fabric.proc",
                 "--role", role, "--name", name, *extra]
         popen = subprocess.Popen(args, stdout=subprocess.PIPE,

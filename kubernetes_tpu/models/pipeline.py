@@ -100,25 +100,18 @@ ALT_NONE = -1e9
 
 # commit-scan unroll factor (see the lax.scan call): amortizes per-iteration
 # dispatch overhead, which dominates the topology scan at these shapes.
-# 16 on TPU (+15-25% on the topology workloads); 4 on CPU, where the only
-# effect of a bigger body is slower XLA:CPU compiles. Resolved LAZILY at
-# first trace via the real backend (no JAX init at import);
-# KUBERNETES_TPU_SCAN_UNROLL overrides (>=1).
-import os as _os
-
+# 16 on TPU (+15-25% on the topology workloads on the round-5 rig; the
+# full-width [2048 x 8192] program compiles and runs at 16 on a v5e —
+# chip_smoke.py leg B); 4 on CPU, where the only effect of a bigger body
+# is slower XLA:CPU compiles. Resolved LAZILY at first trace via the real
+# backend (no JAX init at import).
 _SCAN_UNROLL = None
 
 
 def scan_unroll() -> int:
     global _SCAN_UNROLL
     if _SCAN_UNROLL is None:
-        try:
-            n = int(_os.environ.get("KUBERNETES_TPU_SCAN_UNROLL", "0"))
-        except ValueError:
-            n = 0
-        if n <= 0:
-            n = 4 if jax.default_backend() == "cpu" else 16
-        _SCAN_UNROLL = max(1, n)
+        _SCAN_UNROLL = 4 if jax.default_backend() == "cpu" else 16
     return _SCAN_UNROLL
 
 
@@ -129,22 +122,8 @@ def scan_unroll() -> int:
 # lax.cond skips the work of rounds past convergence (the body is
 # idempotent at its fixed point, so an extra executed round is a no-op).
 # Auctions converge in a handful of rounds, so a small U covers most
-# drains in ONE iteration. Resolved lazily like scan_unroll;
-# KUBERNETES_TPU_AUCTION_UNROLL overrides (>=1).
-_AUCTION_UNROLL = None
-
-
-def auction_unroll() -> int:
-    global _AUCTION_UNROLL
-    if _AUCTION_UNROLL is None:
-        try:
-            n = int(_os.environ.get("KUBERNETES_TPU_AUCTION_UNROLL", "0"))
-        except ValueError:
-            n = 0
-        if n <= 0:
-            n = 4
-        _AUCTION_UNROLL = max(1, n)
-    return _AUCTION_UNROLL
+# drains in ONE iteration.
+AUCTION_UNROLL = 4
 
 # minFeasibleNodesToFind (schedule_one.go:39-45): below this cluster-wide
 # feasible count the percentageOfNodesToScore early-exit never truncates
@@ -711,7 +690,7 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
     # extra round is a no-op, because at the fixed point the feasible set
     # admits no accept (the body is idempotent), so the final state is
     # bit-identical to the one-round-per-iteration program.
-    unroll = auction_unroll() if unroll is None else max(1, int(unroll))
+    unroll = AUCTION_UNROLL if unroll is None else max(1, int(unroll))
     if unroll == 1:
         fused = body
     else:
@@ -1719,25 +1698,24 @@ def warm_patch_chain(free, nzr, max_bucket: int = 256) -> None:
         cap *= 2
 
 
-def launch_cache_size() -> int | None:
-    """Executable-cache entries behind the fused launch (schedule_batch_jit
-    plus the state-extraction seed): the DeviceProfiler reads this after
-    each dispatch — growth means a real XLA compile happened while
-    tracing that launch. None when this jax build doesn't expose the
-    introspection hook (the profiler then skips compile counting)."""
-    # the gang packer's jit rides the same cache accounting so a
-    # gang-shape recompile is attributed to its launch (imported lazily:
-    # ops.gang traces against this module's static_filters)
+def launch_programs() -> tuple:
+    """The jitted programs behind every scheduling launch:
+    schedule_batch_jit, the state-extraction seed, the chain patch
+    scatters, and the gang packer (so a gang-shape recompile is
+    attributed to its launch)."""
+    # imported lazily: ops.gang traces against this module's
+    # static_filters
     from kubernetes_tpu.ops.gang import pack_gangs_jit
 
-    total = 0
-    for fn in (schedule_batch_jit, extract_state_jit, pack_gangs_jit,
-               _chain_set_rows_jit, _chain_add_rows_jit):
-        size = getattr(fn, "_cache_size", None)
-        if size is None:
-            return None
-        total += size()
-    return total
+    return (schedule_batch_jit, extract_state_jit, pack_gangs_jit,
+            _chain_set_rows_jit, _chain_add_rows_jit)
+
+
+def launch_cache_size() -> int:
+    """Executable-cache entries behind the launch programs: the
+    DeviceProfiler reads this after each dispatch — growth means a real
+    XLA compile happened while tracing that launch."""
+    return sum(fn._cache_size() for fn in launch_programs())
 
 
 def launch_batch(spec, wk, weights, caps, enabled_filters=None,

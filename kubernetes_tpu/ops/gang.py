@@ -10,7 +10,8 @@ Permit path) and a ``need`` count of members to place. Per gang:
 
 1. **Member capacity per node** — the static Filter masks (the same five
    commit-invariant plugins the main pipeline runs, via
-   ``pipeline.static_filters``) AND a floored free/request division give
+   ``pipeline.static_filters``) AND a floored free/request division
+   (``floor_div`` — exact where the TPU's f32 division is not) give
    ``cap_n`` = how many members node n can still hold, with nominated
    reservations subtracted exactly like the batched fit predicate.
 2. **All-or-nothing feasibility reduction** — ``sum(cap_n) >= need`` is
@@ -58,6 +59,20 @@ _UNBOUNDED = 2.0 ** 20
 # the composite node sort key packs (domain rank, density) into one i32:
 # rank * _KEY_STRIDE + (_KEY_STRIDE - 1 - clipped capacity)
 _KEY_STRIDE = 4096
+
+
+def floor_div(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """How many whole ``b`` fit in ``a`` (a >= 0, b > 0, f32): the largest
+    q with q * b <= a. NOT ``floor(a / b)``: f32 division on the TPU is
+    not correctly rounded, and a quotient that is an exact integer in
+    real arithmetic lands one ulp low there — floor(33 / 33) = 0, wrong
+    in 15% of the exact pairs up to 4096 x 128 on a v5e — which strands
+    a gang that fits. One multiply-and-compare step each way around the
+    approximate quotient is exact, and it is the same q * b the commit
+    subtracts from free."""
+    q = jnp.floor(a / b)
+    q = jnp.where((q + 1.0) * b <= a, q + 1.0, q)
+    return jnp.where(q * b > a, q - 1.0, q)
 
 
 @jax.tree_util.register_dataclass
@@ -143,7 +158,7 @@ def pack_gangs(cblobs, gblobs, wk, caps, need, tk,
                 + onom.astype(free.dtype)[:, None] * req[None, :], 0.0)
             active_col = req > 0.0
             safe_req = jnp.where(active_col, req, 1.0)
-            per_col = jnp.floor(eff / safe_req)
+            per_col = floor_div(eff, safe_req)
             per_col = jnp.where(active_col[None, :], per_col,
                                 jnp.float32(_UNBOUNDED))
             cap_f = jnp.min(per_col, axis=1)              # [N]
@@ -196,20 +211,12 @@ def pack_gangs_jit(cblobs, gblobs, wk, caps, need, tk, d_cap=8,
                       own_nom)
 
 
-def pack_cache_size() -> int | None:
-    """Executable-cache entries behind the gang packer (the DeviceProfiler
-    folds this into ``pipeline.launch_cache_size`` so a gang-shape
-    recompile is attributed, not "unattributed")."""
-    size = getattr(pack_gangs_jit, "_cache_size", None)
-    return None if size is None else size()
-
-
 @jax.jit
 def _capacity(free: jnp.ndarray, req: jnp.ndarray) -> jnp.ndarray:
     """[N, R] free x [R] request -> scalar i32 member-capacity bound."""
     active = req > 0.0
     safe_req = jnp.where(active, req, 1.0)
-    per_col = jnp.floor(jnp.maximum(free, 0.0) / safe_req)
+    per_col = floor_div(jnp.maximum(free, 0.0), safe_req)
     per_col = jnp.where(active[None, :], per_col, jnp.float32(2 ** 30))
     per_node = jnp.min(per_col, axis=1)
     # a request with NO active columns fits anywhere: cap at a big count

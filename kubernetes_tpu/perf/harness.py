@@ -202,6 +202,28 @@ class WorkloadStuck(Exception):
     """A phase did not finish within its timeout (pods stayed pending)."""
 
 
+class DeviceFallback(RuntimeError):
+    """The run left the device path: the scheduler's containment ladder
+    swallowed a device fault and carried the batch down the serial host
+    path. Right for a daemon; on a measurement path it is a silent CPU
+    fallback, so the run is refused."""
+
+
+def assert_device_path(sched: Scheduler) -> None:
+    """Raise DeviceFallback (naming the contained exception) when any
+    batch or gang degraded off the device or a pod was quarantined."""
+    counts = {
+        "device_fallbacks": sched.stats["device_fallbacks"],
+        "gang_fallbacks{reason=device_fault}": int(
+            sched.metrics.gang_fallbacks.value(reason="device_fault")),
+        "quarantined": sched.stats["quarantined"],
+    }
+    if any(counts.values()):
+        raise DeviceFallback(
+            f"run left the device path: {counts}; contained exception: "
+            f"{sched.last_device_fault}")
+
+
 def run_workload(w: Workload, now: Callable[[], float] = time.time,
                  sleep: Callable[[float], None] = time.sleep,
                  scale: float = 1.0,
@@ -343,6 +365,7 @@ def run_workload(w: Workload, now: Callable[[], float] = time.time,
 
     finally:
         sched.close()  # binder threads released even on failure
+    assert_device_path(sched)
     m = sched.metrics
     # scheduling-quality outcomes for the A/B scorer harness (bench.py
     # --ab-scorer): preemption count, end-state per-node bound-pod

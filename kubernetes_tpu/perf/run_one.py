@@ -32,11 +32,10 @@ def main() -> None:
     scale = 1.0
     if "--scale" in sys.argv:
         scale = float(sys.argv[sys.argv.index("--scale") + 1])
-    repo = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
     from kubernetes_tpu.utils import jaxsetup
 
-    jaxsetup.setup(os.path.join(repo, ".jax_cache"))
+    jaxsetup.setup()
+    meter = jaxsetup.CompileMeter()
     import time
 
     from kubernetes_tpu.perf import workloads as W
@@ -91,7 +90,10 @@ def main() -> None:
         # the measured run's regret summary must not include the warm
         # pass's placements
         open(config.trace_export_path, "w").close()
-    from kubernetes_tpu.models.pipeline import launch_cache_size
+    from kubernetes_tpu.models.pipeline import (
+        launch_cache_size,
+        launch_programs,
+    )
 
     t0 = time.time()
     # zero-recompile gate: the warm pass (and the chain-patch warmup it
@@ -107,6 +109,16 @@ def main() -> None:
         shutil.rmtree(regret_dir, ignore_errors=True)
     r["warm_s"] = round(t_warm, 1)
     r["run_s"] = round(time.time() - t0, 1)
+    # the device the row was measured on, and whether the run stayed on
+    # it (run_workload already refused a run that fell back)
+    r.update(jaxsetup.device_info())
+    r["device_fallbacks"] = r["stats"]["device_fallbacks"]
+    # persistent-cache verdicts over the whole process: a second process
+    # of the same workload must hit on every launch program
+    r["compile_cache"] = {
+        **meter.totals(),
+        "launch_misses": meter.misses(
+            tuple(fn.__name__ for fn in launch_programs()))}
     print(json.dumps(r))
 
 

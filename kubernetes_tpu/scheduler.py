@@ -158,6 +158,10 @@ class Scheduler:
         # chaos seam: a DeviceChaos (kubernetes_tpu.chaos) hooks the
         # pack/launch path here to provoke the fallback ladder under test
         self.fault_injector = None
+        # repr of the newest exception the containment ladder swallowed:
+        # measurement paths (perf/harness, chip_smoke) refuse a run that
+        # left the device path and print this instead of carrying on
+        self.last_device_fault: Optional[str] = None
         self.nominator = Nominator()
         self.preemption = Evaluator(
             hub, lambda: self.mirror, lambda: self.caps,
@@ -1125,10 +1129,11 @@ class Scheduler:
         chain, and degrade the survivors to the host path."""
         self.stats["device_fallbacks"] += 1
         self.metrics.device_fallbacks.inc()
+        self.last_device_fault = repr(exc)
         self._invalidate_chain()
         logger.warning(
             "device path failed for a %d-pod batch (%r); degrading to "
-            "the host fallback path", len(runnable), exc)
+            "the host fallback path", len(runnable), exc, exc_info=exc)
         telemetry.incident(self, "device_fallback",
                            reason=repr(exc), pods=len(runnable))
         pending = self._still_pending(runnable)
@@ -2048,11 +2053,13 @@ class Scheduler:
                 # the Permit-quorum path still schedules these gangs
                 self.stats["device_fallbacks"] += 1
                 self.metrics.device_fallbacks.inc()
+                self.last_device_fault = repr(e)
                 self._invalidate_chain()
                 degraded = chunk + later
                 logger.warning(
                     "gang device path failed for %d unit(s) (%r); "
-                    "degrading to the host Permit path", len(degraded), e)
+                    "degrading to the host Permit path", len(degraded), e,
+                    exc_info=e)
                 for _key, _qps in degraded:
                     self.stats["gang_fallbacks"] += 1
                     self.metrics.gang_fallbacks.inc(reason="device_fault")

@@ -96,7 +96,7 @@ class DeviceProfiler:
         self._now = now or time.time
         # baseline BEFORE any of this scheduler's launches: warm cache
         # entries from an earlier run in this process are not ours
-        self._last_cache: Optional[int] = cache_size_fn()
+        self._last_cache: int = cache_size_fn()
         self._last_shape: Optional[tuple] = None
         self.launches = 0
         self.compiles = 0
@@ -120,8 +120,7 @@ class DeviceProfiler:
                                         "walltime_s": 0.0, "max_s": 0.0}
         rec["launches"] += 1
         cache = self._cache_size_fn()
-        compiled = (cache is not None and self._last_cache is not None
-                    and cache > self._last_cache)
+        compiled = cache > self._last_cache
         if compiled:
             # a NEW shape's compile attributes to the transition that
             # produced it (re-bucket / batch bucket / flags); a compile
@@ -142,8 +141,7 @@ class DeviceProfiler:
             del self.compile_events[:-self.MAX_COMPILE_EVENTS]
             if self._metrics is not None:
                 self._metrics.device_compiles.inc(cause=cause)
-        if cache is not None:
-            self._last_cache = cache
+        self._last_cache = cache
         self._last_shape = shape
         if self._metrics is not None:
             self._metrics.device_launch_shapes.set(

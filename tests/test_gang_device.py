@@ -96,6 +96,26 @@ def _pack(mirror, caps, reps, needs, g_bucket=4):
         state=extract_state_jit(cblobs, caps))
 
 
+def test_floor_div_counts_whole_requests_exactly():
+    """Member capacity is "how many whole requests fit", exact at integer
+    quotients and on both f32 neighbours of each. On the CPU plain
+    floor(a / b) already is; the TPU's f32 division lands exact
+    quotients one ulp low (floor(33 / 33) = 0), which chip_smoke.py's
+    leg B checks on the chip with the same grid."""
+    import jax
+
+    from kubernetes_tpu.ops.gang import floor_div
+
+    b = np.broadcast_to(
+        np.arange(1, 513, dtype=np.float32)[:, None], (512, 64))
+    exact = b * np.arange(1, 65, dtype=np.float32)[None, :]
+    a = np.stack([np.nextafter(exact, np.float32(0)), exact,
+                  np.nextafter(exact, np.float32(np.inf)),
+                  np.zeros_like(exact)])
+    want = np.floor(a.astype(np.float64) / b)
+    assert np.array_equal(np.asarray(jax.jit(floor_div)(a, b)), want)
+
+
 def test_packer_all_or_nothing():
     """A gang past total capacity places NOTHING; a fitting one places
     exactly `need` members."""

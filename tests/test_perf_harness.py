@@ -366,6 +366,116 @@ def test_gang_topology_packing_validate_rejects_scatter():
         _colocation_validate(hub, {})
 
 
+def test_measurement_path_refuses_a_device_fallback():
+    """The containment ladder is right for a daemon; on a measurement
+    path it is a silent CPU fallback. A run in which a batch degraded to
+    the host path still binds every pod — and must be refused, naming
+    the contained exception."""
+    from kubernetes_tpu.chaos import DeviceChaos, DeviceChaosConfig
+    from kubernetes_tpu.config.types import default_config
+    from kubernetes_tpu.hub import Hub
+    from kubernetes_tpu.ops.features import Capacities
+    from kubernetes_tpu.perf.harness import DeviceFallback, assert_device_path
+    from kubernetes_tpu.scheduler import Scheduler
+
+    hub = Hub()
+    cfg = default_config()
+    cfg.batch_size = 16
+    sched = Scheduler(hub, cfg, caps=Capacities(nodes=16, pods=64))
+    try:
+        for i in range(4):
+            hub.create_node(_node(i))
+        pods = [_pod(f"p-{i}") for i in range(8)]
+        for p in pods:
+            hub.create_pod(p)
+        sched.fault_injector = DeviceChaos(DeviceChaosConfig(
+            seed=1, launch_error_rate=1.0))
+        sched.run_until_idle()
+    finally:
+        sched.close()
+    # the host path carried the batch: nothing LOOKS wrong from outside
+    assert all(hub.get_pod(p.metadata.uid).spec.node_name for p in pods)
+    assert sched.stats["device_fallbacks"] >= 1
+    with pytest.raises(DeviceFallback) as e:
+        assert_device_path(sched)
+    assert sched.last_device_fault in str(e.value)
+    # a clean run passes
+    clean = Scheduler(Hub(), cfg, caps=Capacities(nodes=16, pods=64))
+    clean.close()
+    assert_device_path(clean)
+
+
+def _stub_run_one(rows: dict):
+    """A subprocess.run stand-in for bench.py's per-workload run_one
+    children: ``rows`` maps a workload fn to a result dict (exit 0), an
+    int (that exit code, no row) or "timeout"."""
+    import json
+    import subprocess
+
+    def run(cmd, **kw):
+        fn = cmd[cmd.index("kubernetes_tpu.perf.run_one") + 1]
+        row = rows[fn]
+        if row == "timeout":
+            raise subprocess.TimeoutExpired(cmd, kw.get("timeout"))
+        if isinstance(row, int):
+            return subprocess.CompletedProcess(cmd, row, "", "Traceback: x")
+        return subprocess.CompletedProcess(
+            cmd, 0, "noise\n" + json.dumps(row) + "\n", "")
+
+    return run
+
+
+_BASIC_ROW = {
+    "name": "SchedulingBasic/5000Nodes_10000Pods", "pods_per_sec": 12.5,
+    "threshold": 270, "vs_baseline": 0.05, "passed": False,
+    "pods_scheduled": 10000, "elapsed_s": 800.0,
+    "stats": {"device_fallbacks": 0}, "measured_compiles": 0,
+    "warm_s": 1.0, "run_s": 2.0,
+    "platform": "cpu", "device_kind": "cpu", "device_count": 8,
+    "device_fallbacks": 0}
+
+
+def test_bench_rows_keep_the_device_and_fallback_columns():
+    bench = _load_bench()
+    results, headline, failed = bench.run_workloads(
+        ("scheduling_basic",), ["--scale", "1.0"], {},
+        run=_stub_run_one({"scheduling_basic": _BASIC_ROW}))
+    assert failed == [] and headline == _BASIC_ROW
+    row = results["SchedulingBasic"]
+    for key in bench.ROW_DEVICE_KEYS:
+        assert row[key] == _BASIC_ROW[key]
+    assert row["measured_compiles"] == 0
+    assert "stats" not in row           # still a whitelist
+
+
+def test_bench_exits_nonzero_naming_failed_and_timed_out_workloads(
+        monkeypatch, capsys):
+    """A failed or timed-out workload no longer vanishes from a green
+    bench: the rest are measured first, then the exit code is non-zero
+    and stderr names the holes."""
+    import json
+    import sys
+
+    bench = _load_bench()
+    monkeypatch.setattr(bench, "BENCH_WORKLOAD_FNS",
+                        ("boom", "scheduling_basic", "wedged"))
+    monkeypatch.setattr(bench.subprocess, "run", _stub_run_one(
+        {"boom": 1, "scheduling_basic": _BASIC_ROW, "wedged": "timeout"}))
+    monkeypatch.setattr(sys, "argv", ["bench.py", "--no-test-gate"])
+    with pytest.raises(SystemExit) as e:
+        bench.main()
+    assert e.value.code == 1
+    out = capsys.readouterr()
+    assert "FAILED or TIMED OUT: boom, wedged" in out.err
+    # the surviving row was still measured and published, device named
+    summary = json.loads(out.out.strip().splitlines()[-1])
+    assert summary["platform"] == "cpu" and summary["device_count"] == 8
+    assert list(summary["workloads"]) == ["SchedulingBasic"]
+    # all green: main() returns normally
+    monkeypatch.setattr(bench, "BENCH_WORKLOAD_FNS", ("scheduling_basic",))
+    bench.main()
+
+
 # suite-tier discipline (tests/test_markers.py): area marker
 import pytest  # noqa: E402
 pytestmark = pytest.mark.perf
