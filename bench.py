@@ -207,7 +207,7 @@ def _ab_scorer_run(workdir: str, smoke: bool, scale: float,
     from kubernetes_tpu.learn.train import TrainConfig, train
     from kubernetes_tpu.perf.harness import run_workload
     from kubernetes_tpu.perf import workloads as W
-    from kubernetes_tpu.utils.tracing import VIEW_PHASES
+    from kubernetes_tpu.utils.tracing import LOOP_PHASES, VIEW_PHASES
 
     tie_seed = 2026_0801
 
@@ -290,7 +290,7 @@ def _ab_scorer_run(workdir: str, smoke: bool, scale: float,
         return sum(p["total_s"]
                    for ph, p in res.get("flight", {})
                    .get("phases", {}).items()
-                   if ph not in VIEW_PHASES)
+                   if ph not in VIEW_PHASES and ph not in LOOP_PHASES)
 
     def arm(res: dict) -> dict:
         return {

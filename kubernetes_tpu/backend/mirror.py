@@ -120,6 +120,7 @@ def _round_row_f32(row64: np.ndarray, up: bool) -> np.ndarray:
 _unpack_pods_jit = jax.jit(unpack_pods, static_argnums=1)
 
 
+@jax.named_scope("scatter_rows")
 def _scatter_rows(buf, idx, rows):
     return buf.at[idx].set(rows)
 

@@ -126,6 +126,14 @@ class PriorityQueue:
         self._in_flight: dict[str, int] = {}        # uid -> start seq
         self._events: list[tuple[int, ClusterEvent, object, object]] = []
         self._next_seq = 0
+        # what _trim_events cost, counted by the queue itself at that one
+        # boundary: calls, calls that scanned the in-flight set, seconds
+        # in those scans (this queue's clock) and the event log's
+        # high-water length (the flight recorder's queue_done view)
+        self.trim_calls = 0
+        self.trim_scans = 0
+        self.trim_scan_s = 0.0
+        self.events_high_water = 0
         self._moved_cycle = 0
         # event-burst coalescing window (ISSUE 15): non-None while a
         # caller batches requeue reaction across a burst (an eviction
@@ -294,13 +302,31 @@ class PriorityQueue:
     def _trim_events(self) -> None:
         """Drop log entries no in-flight pod can still replay. The min() scan
         is amortized: only when the log is empty-able or has grown past the
-        trim threshold."""
+        trim threshold. Only the scanning branch reads the clock."""
+        self.trim_calls += 1
+        n = len(self._events)
+        if n > self.events_high_water:
+            self.events_high_water = n
         if not self._in_flight:
             self._events.clear()
-        elif len(self._events) > 8192:
+        elif n > 8192:
+            t0 = self._now()
             low = min(self._in_flight.values())
             keep = [e for e in self._events if e[0] >= low]
             self._events = keep
+            self.trim_scans += 1
+            self.trim_scan_s += self._now() - t0
+
+    def event_log_len(self) -> int:
+        return len(self._events)
+
+    def trim_stats(self) -> dict:
+        """The event log's counts, for /debug/trace."""
+        return {"entries": self.event_log_len(),
+                "high_water": self.events_high_water,
+                "trim_calls": self.trim_calls,
+                "trim_scans": self.trim_scans,
+                "trim_scan_s": round(self.trim_scan_s, 6)}
 
     def in_flight_count(self) -> int:
         return len(self._in_flight)

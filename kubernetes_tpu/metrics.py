@@ -240,6 +240,18 @@ class SchedulerMetrics:
             "Per-plugin execution latency by extension point (host "
             "plugins; device plugins are fused into one launch)",
             FINE_DURATION_BUCKETS, ("plugin", "extension_point")))
+        self.gc_pause = r.register(Histogram(
+            "scheduler_gc_pause_seconds",
+            "Collector pauses by generation, from the gc.callbacks hook "
+            "of utils/gcguard (whichever thread the collector ran on)",
+            FINE_DURATION_BUCKETS, ("generation",)))
+        self.queue_event_log_entries = r.register(Gauge(
+            "scheduler_queue_event_log_entries",
+            "Length of the scheduling queue's in-flight event log at the "
+            "last binder drain (past 8192 every done() scans it)"))
+        self.queue_event_trims = r.register(Counter(
+            "scheduler_queue_event_trims_total",
+            "Scans of the in-flight set by PriorityQueue._trim_events"))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

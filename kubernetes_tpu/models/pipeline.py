@@ -244,6 +244,15 @@ def _guard_reduction(scores: jnp.ndarray, free: jnp.ndarray) -> jnp.ndarray:
             | (jnp.any(jnp.isnan(free)).astype(jnp.int32) << 1))
 
 
+# jax.named_scope on the device kernels: the names reach the HLO metadata
+# (and from there a profiler trace's operation details); they change no
+# program and no cache key. KERNEL_SCOPES lists them for whoever reads
+# per-kernel device time.
+KERNEL_SCOPES = ("static_filters", "auction_rounds", "soft_topology_auction",
+                 "commit_scan", "patch_chain", "scatter_rows")
+
+
+@jax.named_scope("static_filters")
 def static_filters(ct: ClusterTensors, pod: PodFeatures,
                    wk: dict[str, jnp.ndarray],
                    enabled: tuple[bool, ...],
@@ -699,7 +708,9 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
             for _ in range(unroll - 1):
                 state = jax.lax.cond(state[4], body, lambda s: s, state)
             return state
-    free, nzr, placed, win, _ = jax.lax.while_loop(cond, fused, init)
+    with jax.named_scope("auction_rounds" if soft is None
+                         else "soft_topology_auction"):
+        free, nzr, placed, win, _ = jax.lax.while_loop(cond, fused, init)
 
     # diagnostics from the final state (unplaced pods' reject attribution)
     fit = fit_all(free)
@@ -1547,8 +1558,9 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
     # unroll: the body is many small fused kernels; per-iteration dispatch
     # overhead (not FLOPs) is a real cost at these shapes, so unrolling
     # amortizes it
-    (carry_out, ys_out) = jax.lax.scan(body, init, xs,
-                                       unroll=scan_unroll())
+    with jax.named_scope("commit_scan"):
+        (carry_out, ys_out) = jax.lax.scan(body, init, xs,
+                                           unroll=scan_unroll())
     (rows, win_scores, feas, port_rejects, fit_rejects, sp_rejects,
      ipa_rejects) = ys_out[:7]
     extra = list(ys_out[7:])
@@ -1622,11 +1634,13 @@ def extract_state_jit(cblobs, caps):
 
 
 @jax.jit
+@jax.named_scope("patch_chain")
 def _chain_set_rows_jit(free, nzr, idx, free_rows, nzr_rows):
     return free.at[idx].set(free_rows), nzr.at[idx].set(nzr_rows)
 
 
 @jax.jit
+@jax.named_scope("patch_chain")
 def _chain_add_rows_jit(free, nzr, idx, free_rows, nzr_rows):
     return free.at[idx].add(free_rows), nzr.at[idx].add(nzr_rows)
 

@@ -239,7 +239,8 @@ class Scheduler:
             capacity=getattr(self.config, "flight_recorder_capacity", 256),
             export_path=getattr(self.config, "trace_export_path", None),
             export_max_bytes=getattr(self.config,
-                                     "trace_export_max_bytes", 0))
+                                     "trace_export_max_bytes", 0),
+            now=now, gc_pause_hist=self.metrics.gc_pause)
         self.timelines = PodTimelines(
             capacity=getattr(self.config, "timelines_capacity", 4096),
             now=now)
@@ -254,10 +255,12 @@ class Scheduler:
         # existing per-cycle pull
         self._export_alts = (self.flight.exporting and getattr(
             self.config, "trace_export_alts", False))
-        self._last_pop_s = 0.0
         if self.flight.enabled:
             for fw in self.frameworks.values():
                 fw.plugin_timer = self.flight.plugin_observe
+            # every collector pause, from whichever thread it ran on,
+            # as scheduler_gc_pause_seconds and the gc_pause view
+            gc_guard.watch(self.flight)
         # the device-launch profiler (telemetry.profiler): XLA compiles
         # per bucket shape, recompile attribution to re-bucket churn,
         # per-shape walltime, live HBM buffer bytes. Rides the flight
@@ -1065,7 +1068,7 @@ class Scheduler:
         # only a flush with queued work is a measurable phase (this runs
         # every cycle; an empty flush is a couple of attribute reads)
         busy = self.preemption.has_pending()
-        t0 = self.now() if busy else 0.0
+        sp = self.flight.span("eviction_flush") if busy else None
         try:
             if busy:
                 # evictions fire only over durably-bound state: a victim
@@ -1085,9 +1088,8 @@ class Scheduler:
         except Unavailable:
             self._note_hub_down()
         finally:
-            if busy:
-                self.flight.observe_phase("eviction_flush",
-                                          self.now() - t0)
+            if sp is not None:
+                sp.end()
 
     # ------------- fault containment (the self-healing ladder) -------------
     #
@@ -1170,12 +1172,8 @@ class Scheduler:
         # the fallback's serial host-path cost feeds the host_fallback
         # phase histogram: scheduler_device_fallbacks_total says how
         # OFTEN the ladder fired, this says what each firing COST
-        t_fb0 = self.now()
-        try:
+        with self.flight.span("host_fallback"):
             self._host_fallback_batch_inner(qps)
-        finally:
-            self.flight.observe_phase("host_fallback",
-                                      self.now() - t_fb0)
 
     def _host_fallback_batch_inner(self, qps: list[QueuedPodInfo]) -> None:
         # the fallback evaluates on host: re-enable the host DRA filter
@@ -1507,12 +1505,15 @@ class Scheduler:
 
     # ------------- the batched scheduling cycle -------------
 
-    def _pop_runnable(self) -> tuple[int, list[QueuedPodInfo]]:
+    def _pop_runnable(self) -> tuple:
         """Pop up to batch_size pods and apply skipPodSchedule
         (schedule_one.go:380: deleted or already assumed). Pods deferred
         from the previous batch (host-serial volume conflicts) go first —
-        they are still in flight from their original pop."""
-        t_pop0 = self.now()
+        they are still in flight from their original pop. A pop that
+        yields runnable pods opens their cycle's trace (returned third)
+        and reports its own queue_pop span on it the moment it ends; an
+        empty pop is no phase."""
+        sp = self.flight.span("queue_pop")
         deferred, self._deferred = self._deferred, []
         batch = deferred + self.queue.pop_batch(
             self._effective_batch() - len(deferred))
@@ -1527,7 +1528,8 @@ class Scheduler:
                 self._note_hub_down()
                 for rest in runnable + batch[i:]:
                     self._park_unreachable(rest)
-                return len(batch), []
+                sp.end(report=False)
+                return len(batch), [], None
             if stored is None or stored.metadata.deletion_timestamp:
                 self.queue.done(qp.uid)
                 continue
@@ -1543,16 +1545,19 @@ class Scheduler:
                         "faults")
                 continue
             runnable.append(qp)
-        t_pop1 = self.now()
-        # consumed by _dispatch into the cycle's queue_pop phase (one
-        # shared clock read stamps the whole batch's pop events)
-        self._last_pop_s = t_pop1 - t_pop0
-        if self.flight.enabled and runnable:
+        if not runnable:
+            sp.end(report=False)
+            return len(batch), runnable, None
+        tr = self.flight.begin(sp.t0, len(runnable))
+        sp.end(tr=tr)
+        if self.flight.enabled:
+            # the span's closing clock read stamps the whole batch's
+            # pop events
             tl = self.timelines
             for qp in runnable:
                 tl.event(qp.pod, "popped", f"attempt {qp.attempts}",
-                         t=t_pop1)
-        return len(batch), runnable
+                         t=sp.t1)
+        return len(batch), runnable, tr
 
     def _chain_eligible(self, pods: list[Pod]) -> bool:
         """Can this batch launch against the device-resident usage chain
@@ -1632,24 +1637,25 @@ class Scheduler:
         return True
 
     def _dispatch(self, runnable: list[QueuedPodInfo], chained: bool,
-                  flush_pending=None) -> Optional[tuple]:
+                  tr, flush_pending=None) -> Optional[tuple]:
         """Pack + launch one batch (async dispatch; no host<->device block).
         Returns (runnable, BatchResult) or None if every pod was routed to
-        the failure path during packing. ``flush_pending`` commits a
-        still-in-flight previous launch before any fallback re-sync, so a
-        chained dispatch that has to re-bucket never syncs a cache missing
-        the previous batch's placements."""
+        the failure path during packing. ``tr`` is the cycle's trace, open
+        since its pop (_pop_runnable); _finish records it (the dispatched
+        tuple carries it through the pipelined drain). ``flush_pending``
+        commits a still-in-flight previous launch before any fallback
+        re-sync, so a chained dispatch that has to re-bucket never syncs a
+        cache missing the previous batch's placements."""
+        span = self.flight.span
         t_cycle0 = self.now()
         # chain-surviving churn: fold pending informer patches into the
         # live chain BEFORE this launch packs against it. On fallback
         # (patch set too large, mirror capacity overflow) the chain is
         # invalidated and this dispatch takes the full-sync path.
-        t_patch = 0.0
         if chained and (self._chain_dirty or self._chain_deltas):
-            t_p0 = self.now()
-            if not self._apply_chain_patches(flush_pending):
-                chained = False
-            t_patch = self.now() - t_p0
+            with span("chain_patch", tr):
+                if not self._apply_chain_patches(flush_pending):
+                    chained = False
         epoch = self._chain_epoch
         if len(self.frameworks) > 1:
             # one profile per launch: enabled filters / weights / scoring
@@ -1676,14 +1682,10 @@ class Scheduler:
             self.fault_injector.on_pack([qp.pod for qp in runnable])
         self.stats["batches"] += 1
         self.stats["attempts"] += len(runnable)
-        # flight recorder: this cycle's trace opens here and is recorded
-        # by _finish (the dispatched tuple carries it through the
-        # pipelined drain)
-        tr = self.flight.begin(t_cycle0, len(runnable), chained)
-        tr.add("queue_pop", self._last_pop_s)
-        self._last_pop_s = 0.0
-        if t_patch:
-            tr.add("chain_patch", t_patch)
+        # what the pop could not know: the pods left after the profile
+        # and host-conflict splits, and whether the launch chains
+        tr.pods = len(runnable)
+        tr.chained = chained
         state = self._chain if chained else None
         need_sync = not chained
         for attempt in range(16):  # one capacity field may grow per attempt
@@ -1692,20 +1694,22 @@ class Scheduler:
                     if flush_pending is not None:
                         flush_pending()
                         flush_pending = None
-                    t_sync0 = self.now()
-                    self.cache.update_snapshot(self.snapshot)
-                    self.mirror.sync(self.snapshot)
-                    # a full sync subsumes every pending chain patch:
-                    # handlers mutate the cache synchronously before
-                    # registering, and the sync read that cache
-                    self._chain_dirty.clear()
-                    self._chain_deltas.clear()
-                    tr.add("snapshot_sync", self.now() - t_sync0)
-                t_pack0 = self.now()
-                self.mirror.set_nominated(self.nominator.by_node())
-                spec = self.mirror.prepare_launch(
-                    [qp.pod for qp in runnable], self.config.batch_size)
-                tr.add("pack", self.now() - t_pack0)
+                    # snapshot_sync in its two pieces, each also the
+                    # view that names it
+                    with span("snapshot_sync", tr, view="snapshot_cache"):
+                        self.cache.update_snapshot(self.snapshot)
+                    with span("snapshot_sync", tr, view="mirror_sync"):
+                        self.mirror.sync(self.snapshot)
+                        # a full sync subsumes every pending chain patch:
+                        # handlers mutate the cache synchronously before
+                        # registering, and the sync read that cache
+                        self._chain_dirty.clear()
+                        self._chain_deltas.clear()
+                with span("pack", tr):
+                    self.mirror.set_nominated(self.nominator.by_node())
+                    spec = self.mirror.prepare_launch(
+                        [qp.pod for qp in runnable],
+                        self.config.batch_size)
                 break
             except CapacityError as e:
                 if flush_pending is not None:
@@ -1729,10 +1733,9 @@ class Scheduler:
         learned_params = None
         mgr = pcfg["learned"]
         if mgr is not None:
-            t_l0 = self.now()
-            mgr.maybe_reload()
-            learned_params = mgr.params()
-            tr.add("learned_score", self.now() - t_l0)
+            with span("learned_score", tr):
+                mgr.maybe_reload()
+                learned_params = mgr.params()
             # reloads = swaps AFTER the initial load (the manager's
             # count); errors delta-mirrored like other external counts
             # the generation label rides the delta at reload time:
@@ -1764,11 +1767,10 @@ class Scheduler:
             # in-flight binding cycles write allocations (PreBind), so
             # land them before the in-use mask packs
             self._drain_bind_results(wait=True)
-            t_dra0 = self.now()
-            dra_batch, dra_stats = self._dra.build_device_batch(
-                [qp.pod for qp in runnable], self.mirror.row_of,
-                self.caps.nodes, spec.pblobs.f32.shape[0])
-            t_dra1 = self.now()
+            with span("pack", tr) as dra_pack:
+                dra_batch, dra_stats = self._dra.build_device_batch(
+                    [qp.pod for qp in runnable], self.mirror.row_of,
+                    self.caps.nodes, spec.pblobs.f32.shape[0])
             spec.dra = dra_batch
             for qp in runnable:
                 if qp.pod.spec.resource_claims:
@@ -1778,11 +1780,11 @@ class Scheduler:
             # dra_mask_compile = selector compilation + inventory
             # refresh; dra_device_eval = the per-cycle claim/in-use
             # tensor pack. Both are VIEWS (excluded from the cycle-total
-            # arithmetic); the wall time itself lands in `pack`.
+            # arithmetic) whose seconds the allocator measured; the wall
+            # time itself is the `pack` span above.
             tr.add("dra_mask_compile", dra_stats["compile_s"])
             tr.add("dra_device_eval",
-                   (t_dra1 - t_dra0) - dra_stats["compile_s"])
-            tr.add("pack", t_dra1 - t_dra0)
+                   dra_pack.secs - dra_stats["compile_s"])
 
         # commit mode: the parallel-rounds auction whenever the launch has
         # no topology work and no batch pod carries host ports (in-batch
@@ -1816,9 +1818,8 @@ class Scheduler:
         host_ok = host_score = None
         if self._has_host_filters or self._has_host_scores \
                 or self._extenders:
-            t_host0 = self.now()
-            host_ok, host_score = self._run_host_plugins(runnable)
-            tr.add("host_plugins", self.now() - t_host0)
+            with span("host_plugins", tr):
+                host_ok, host_score = self._run_host_plugins(runnable)
         fit_strategy, fit_shape = pcfg["fit"]
         # export-pull flags captured ONCE: the launch compiles against
         # them and the commit thread pulls against them, so they must be
@@ -1832,7 +1833,7 @@ class Scheduler:
             # launch carries explicit state: one jit signature for chained
             # and unchained dispatches (see pipeline.extract_state_jit)
             state = extract_state_jit(spec.cblobs, self.caps)
-        t_disp0 = self.now()
+        disp = span("device_dispatch", tr)
         out: BatchResult = launch_batch(
             spec, self.mirror.well_known(), pcfg["weights"], self.caps,
             pcfg["filters"], serial_scan=not use_auction, state=state,
@@ -1865,8 +1866,8 @@ class Scheduler:
                 # trigger an XLA compile mid-drain
                 self._patch_warmed = True
                 warm_patch_chain(out.free, out.nzr, CHAIN_PATCH_MAX)
-        t_done = self.now()
-        tr.add("device_dispatch", t_done - t_disp0)
+        disp.end()
+        t_done = disp.t1
         # device-launch profiler: the jit call above traced (and, on a
         # new bucket shape, COMPILED) synchronously before dispatching,
         # so reading the executable-cache size here attributes any
@@ -1902,7 +1903,7 @@ class Scheduler:
         # list matches its outputs.
         flags = (learned_params is not None, exporting,
                  want_feats, want_alts)
-        fut = (self._commit_pool.submit(self._pull_launch, out, flags)
+        fut = (self._commit_pool.submit(self._pull_launch, out, flags, tr)
                if self._commit_pool is not None else None)
         return (runnable, out, t_done, t_done - t_cycle0, tr,
                 flags, pshape, compiled, fut)
@@ -2086,15 +2087,14 @@ class Scheduler:
         from kubernetes_tpu.ops.features import PodBlobs
         from kubernetes_tpu.ops.gang import pack_gangs_jit
 
-        t0 = self.now()
+        launch = self.flight.span("gang_device")
         # chain-surviving churn: pending patches fold in before the pack
         # reads the chain (the caller already flushed the pipeline, so
         # no flush closure is needed for absolute repacks)
         if self._chain is not None \
                 and (self._chain_dirty or self._chain_deltas):
-            t_p0 = self.now()
-            self._apply_chain_patches()
-            self.flight.observe_phase("chain_patch", self.now() - t_p0)
+            with self.flight.span("chain_patch"):
+                self._apply_chain_patches()
         epoch = self._chain_epoch
         state = self._chain
         need_sync = state is None
@@ -2170,15 +2170,14 @@ class Scheduler:
         ok_arr, alloc_arr, cap_arr, spans_arr, guard = vals[:5]
         for (ckey, ctok, _arr), v in zip(cap_pulls, vals[5:]):
             self._gang.resolve_cap(ckey, ctok, int(v))
-        launch_s = self.now() - t0
-        self.flight.observe_phase("gang_device", launch_s)
+        launch_s = launch.end()
         if prof is not None and pshape is not None:
             prof.observe_walltime(pshape, launch_s)
         if int(guard):
             raise DeviceFault(
                 f"gang pack guard tripped (mask {int(guard):#x}): "
                 "poisoned usage state")
-        t_commit0 = self.now()
+        commit = self.flight.span("gang_commit")
         fallback: list[QueuedPodInfo] = []
         alloc_np = np.asarray(alloc_arr)
         try:
@@ -2231,8 +2230,7 @@ class Scheduler:
                     qp.host_reject_counts = {}
                     self._park_unschedulable(qp, {"GangScheduling"}, msg)
         finally:
-            self.flight.observe_phase("gang_commit",
-                                      self.now() - t_commit0)
+            commit.end()
         # the chain advances to the launch's post-batch state unless a
         # rollback/park above invalidated it (epoch check, like
         # _dispatch); parked/fallback units were never debited on device
@@ -2518,7 +2516,8 @@ class Scheduler:
                         host_score[i, row] += sc
         return host_ok, host_score
 
-    def _pull_launch(self, out: BatchResult, flags: tuple) -> tuple:
+    def _pull_launch(self, out: BatchResult, flags: tuple,
+                     tr=None) -> tuple:
         """The commit-thread half of _finish: ONE blocking D2H pull of the
         launch's verdict tensors (rows + guard + the flag-gated
         learned-magnitude / export tensors — a second device_get would be
@@ -2529,11 +2528,18 @@ class Scheduler:
         mutation stays on the loop thread) and takes no locks; exceptions
         (including the chaos commit_pull seam) surface in _finish via
         fut.result() and ride the normal containment ladder. Returns
-        (vals, t_ready, pull_s) — t_ready timestamps verdict
-        availability (the honest end of the device span); pull_s is this
-        thread's own wall inside the pull, booked by _finish as the
-        overlapped commit_pull phase when it ran off-thread."""
-        t_pull0 = self.now()
+        (vals, t_ready) — t_ready timestamps verdict availability (the
+        honest end of the device span). With ``tr`` (off-thread) this
+        thread's own wall inside the pull is the cycle's overlapped
+        commit_pull span, reported HERE, on this thread, when the pull
+        ends; inline, the caller's device_launch span covers it."""
+        if tr is None:
+            return self._pull_verdicts(out, flags), self.now()
+        with self.flight.span("commit_pull", tr) as pull:
+            vals = self._pull_verdicts(out, flags)
+        return vals, pull.t1
+
+    def _pull_verdicts(self, out: BatchResult, flags: tuple) -> tuple:
         learned_on, exporting, want_feats, want_alts = flags
         fi = self.fault_injector
         if fi is not None:
@@ -2550,9 +2556,7 @@ class Scheduler:
             if want_alts:
                 pull.append(out.alt_row)
                 pull.append(out.alt_score)
-        vals = jax.device_get(tuple(pull))
-        t_ready = self.now()
-        return vals, t_ready, t_ready - t_pull0
+        return jax.device_get(tuple(pull))
 
     def _finish(self, inflight: tuple) -> None:
         """Pull one dispatched launch's results and commit/fail each pod."""
@@ -2562,20 +2566,19 @@ class Scheduler:
         # re-attach the cycle's trace: the pipelined drain may have
         # dispatched k+1 (opening its trace) before finishing k
         self.flight.resume(tr)
+        span = self.flight.span
         n = len(runnable)
-        t0 = self.now()
-        if fut is not None:
-            # off-thread commit: the pull has been running on the commit
-            # thread since dispatch; a commit-thread exception re-raises
-            # HERE and rides the same _finish_contained blast-radius
-            # ladder an inline fault would. wait_s is the loop thread's
-            # ACTUAL blocked time — the wave's serial cost; the commit
-            # thread's pull span (pull_s) overlapped loop-thread work.
-            vals, t_ready, pull_s = fut.result()
-            wait_s = max(self.now() - t0, 0.0)
-        else:
-            vals, t_ready, pull_s = self._pull_launch(out, flags)
-            wait_s = None
+        # device_launch is the loop thread's ACTUAL blocked time — the
+        # wave's serial cost — reported the moment the verdicts are in
+        # hand, before the commit loop. Off-thread commit: the pull has
+        # been running on the commit thread since dispatch (its own
+        # commit_pull span overlapped loop-thread work); a commit-thread
+        # exception re-raises HERE and rides the same _finish_contained
+        # blast-radius ladder an inline fault would. Pipelining off: the
+        # pull runs inline and the loop is blocked for all of it.
+        with span("device_launch", tr):
+            vals, t_ready = (fut.result() if fut is not None
+                             else self._pull_launch(out, flags))
         # PreFilter gang-capacity reductions cannot ride the commit
         # thread's pull (they register on the loop thread, possibly
         # after dispatch); rare — gang PreFilter only — so they get
@@ -2675,9 +2678,12 @@ class Scheduler:
         fail_is = [i for i in range(n) if rows[i] < 0]
         rejects = None
         if fail_is:
-            t_pull0 = self.now()
-            rejects, dra_rej = jax.device_get((out.reject_counts,
-                                               out.dra_reject))
+            # the rows/guard pull above is inseparable from the device
+            # wait (folded into device_launch); this one is a pure
+            # post-compute transfer — the honest D2H measurement
+            with span("d2h_pull", tr):
+                rejects, dra_rej = jax.device_get((out.reject_counts,
+                                                   out.dra_reject))
             rejects = np.asarray(rejects)
             # fused DRA rejections fold into host_reject_counts so
             # diagnosis, requeue hints, and the preemption fast-path
@@ -2686,35 +2692,17 @@ class Scheduler:
                 c = int(dra_rej[i])
                 if c:
                     runnable[i].host_reject_counts["DynamicResources"] = c
-            # the rows/guard pull above is inseparable from the device
-            # wait (folded into device_launch); this one is a pure
-            # post-compute transfer — the honest D2H measurement
-            tr.add("d2h_pull", self.now() - t_pull0)
-        t_commit0 = self.now()
-        for qp, row in zip(runnable, rows):
-            if row >= 0:
-                self._commit(qp, self.mirror.name_of_row(row))
-        t_commit1 = self.now()
-        tr.add("commit", t_commit1 - t_commit0)
+        with span("commit", tr) as done:
+            for qp, row in zip(runnable, rows):
+                if row >= 0:
+                    self._commit(qp, self.mirror.name_of_row(row))
         n_fail = len(fail_is)
         if fail_is:
-            self._handle_failures([(runnable[i], rejects[i].tolist())
-                                   for i in fail_is])
-            tr.add("failure_handling", self.now() - t_commit1)
-        commit_s = self.now() - t1
+            with span("failure_handling", tr) as done:
+                self._handle_failures([(runnable[i], rejects[i].tolist())
+                                       for i in fail_is])
+        commit_s = done.t1 - t1
         cycle_s = pack_s + launch_s + commit_s
-        if wait_s is None:
-            # inline pull (pipelining off): the loop thread was blocked
-            # for the whole device span — all of it is serial cost
-            tr.add("device_launch", launch_s)
-        else:
-            # pipelined arm: only the harvest wait serialized the loop
-            # thread; the commit thread's pull span is recorded as the
-            # overlapped commit_pull view (excluded from totals/host-tail
-            # like VIEW_PHASES) so /debug/trace keeps the attribution
-            # without booking concurrent wall time as if serial
-            tr.add("device_launch", wait_s)
-            tr.add("commit_pull", pull_s)
         if self.profiler is not None and pshape is not None:
             self.profiler.observe_walltime(pshape, launch_s)
             if compiled:
@@ -2727,8 +2715,8 @@ class Scheduler:
         # device occupancy: launch-in-flight fraction of this cycle's
         # wall (dispatch open -> commit done). 1.0 = the device never
         # sat idle waiting on host work — the pipelining headline.
-        tr.occupancy = max(0.0, min(
-            1.0, launch_s / max(self.now() - tr.start, 1e-9)))
+        tr.occupancy = max(0.0, min(1.0, launch_s / max(
+            self.now() - (t_dispatched - pack_s), 1e-9)))
         self.flight.record(tr)
         m = self.metrics
         m.algorithm_duration.observe(launch_s)
@@ -2763,7 +2751,7 @@ class Scheduler:
             self._process_waiting()
             if self.jobqueue.active:
                 self.jobqueue.release(self.queue, self._effective_batch())
-            popped, runnable = self._pop_runnable()
+            popped, runnable, tr = self._pop_runnable()
             if popped == 0:
                 self._drain_bind_results(wait=True)
                 self._flush_evictions_safe()
@@ -2777,7 +2765,7 @@ class Scheduler:
                 try:
                     inflight = self._dispatch(
                         runnable, self._chain_eligible(
-                            [qp.pod for qp in runnable]))
+                            [qp.pod for qp in runnable]), tr)
                 except Unavailable:
                     self._park_batch_unreachable(runnable)
                     inflight = None
@@ -3047,7 +3035,9 @@ class Scheduler:
         self._submit_bind_backlog()
         if not self._inflight_binds:
             return
-        t_drain0 = self.now()
+        queue = self.queue
+        scan_s0 = queue.trim_scan_s
+        sp = self.flight.span("binder_drain")
         drained = False
         still: list[tuple] = []
         for item in self._inflight_binds:
@@ -3061,9 +3051,18 @@ class Scheduler:
             else:
                 still.append(item)
         self._inflight_binds = still
-        if drained:
-            self.flight.observe_phase("binder_drain",
-                                      self.now() - t_drain0)
+        if drained and self.flight.enabled:
+            # the queue's own view of this drain, reported just before
+            # its parent: seconds its done() calls spent scanning the
+            # in-flight event log (0.0 = none scanned), and the log's
+            # two metrics
+            self.flight.observe_view("queue_done",
+                                     queue.trim_scan_s - scan_s0)
+            self.metrics.queue_event_log_entries.set(
+                float(queue.event_log_len()))
+            self._mirror_count("queue_trims", queue.trim_scans,
+                               self.metrics.queue_event_trims)
+        sp.end(report=drained)
 
     def _finish_binding(self, qp: QueuedPodInfo, state: CycleState,
                         assumed: Pod, node_name: str, s) -> None:
@@ -3738,13 +3737,19 @@ class Scheduler:
             return ok
 
         crash_bo = Backoff(base=0.5, cap=30.0)
+        span = self.flight.span
         try:
             while not stop.is_set():
+                # one loop turn: its loop-level spans (LOOP_PHASES) carry
+                # this id and, with the cycles' phases, tile the thread
+                self.flight.turn += 1
                 if elector is not None and not tick_gate():
-                    stop.wait(min(elector.retry_period, 0.5))
+                    with span("idle_wait"):
+                        stop.wait(min(elector.retry_period, 0.5))
                     continue
                 try:
-                    self.run_maintenance()
+                    with span("maintenance"):
+                        self.run_maintenance()
                     # the drain renews the lease every batch and aborts the
                     # moment leadership is lost (the reference renews on a
                     # background goroutine; a long drain must not outlive
@@ -3752,13 +3757,15 @@ class Scheduler:
                     on_step = (None if elector is None
                                else (lambda: not tick_gate()))
                     if self.run_until_idle(on_step=on_step) == 0:
-                        stop.wait(idle_sleep)
+                        with span("idle_wait"):
+                            stop.wait(idle_sleep)
                     crash_bo.reset()
                 except Exception as e:  # noqa: BLE001 — keep daemon alive
                     logger.exception("scheduling loop error: %s", e)
                     self.daemon_error = e
                     self.metrics.cycle_crashes.inc()
-                    stop.wait(crash_bo.next())
+                    with span("idle_wait"):
+                        stop.wait(crash_bo.next())
         finally:
             if elector is not None:
                 elector.release()
@@ -3794,6 +3801,7 @@ class Scheduler:
         if self._commit_pool is not None:
             self._commit_pool.shutdown(wait=True)
             self._commit_pool = None
+        gc_guard.unwatch(self.flight)
         self.flight.close()
 
     # ------------- driving -------------
@@ -3814,8 +3822,17 @@ class Scheduler:
         (scheduler_perf.go:819 churnOp). A truthy return stops the drain
         (pending work is still committed): with a churn feed the queue may
         never go idle, so the harness signals "measured phase done" here."""
-        with self._lock, gc_guard:
-            return self._run_until_idle_locked(max_batches, on_step)
+        span = self.flight.span
+        with span("lock_wait"):
+            self._lock.acquire()
+        try:
+            with gc_guard:
+                total = self._run_until_idle_locked(max_batches, on_step)
+                sweep = span("gc_sweep")   # the guard's exit collection
+            sweep.end()
+            return total
+        finally:
+            self._lock.release()
 
     def _run_until_idle_locked(self, max_batches, on_step) -> int:
         total = 0
@@ -3834,33 +3851,37 @@ class Scheduler:
             while len(pending) > depth:
                 self._finish_contained(pending.popleft())
 
+        span = self.flight.span
         for _ in range(max_batches):
-            self._process_deferred_events()
-            self._process_waiting()
+            with span("event_intake") as intake:
+                self._process_deferred_events()
+                self._process_waiting()
             self._drain_bind_results()
             # the 1s backoff flush must tick DURING a busy drain too (the
             # reference runs it as a goroutine): under continuous load the
             # idle branch never runs and backoff pods would starve
-            now = self.now()
-            if now - self._last_backoff_flush >= 1.0:
-                self._last_backoff_flush = now
-                self.queue.flush_backoff_completed()
+            if intake.t1 - self._last_backoff_flush >= 1.0:
+                self._last_backoff_flush = intake.t1
+                with span("event_intake"):
+                    self.queue.flush_backoff_completed()
                 # once-a-second young-gen sweep keeps deferred cyclic
                 # garbage bounded during long drains (see utils.gcguard)
-                gc_guard.idle_sweep()
+                with span("gc_sweep"):
+                    gc_guard.idle_sweep()
             if on_step is not None and on_step():
                 break
             if self.jobqueue.active:
                 # admit tenant/gang work by DRR + quota before the pop
                 self.jobqueue.release(self.queue, self._effective_batch())
-            popped, runnable = self._pop_runnable()
+            popped, runnable, tr = self._pop_runnable()
             if popped == 0:
                 flush_all()
                 # the flush may have completed a gang quorum (Permit
                 # allowed the waiting peers): harvest them into the
                 # binding cycle BEFORE deciding the queue is idle, or a
                 # drain ends with allowed pods stranded in the wait room
-                self._process_waiting()
+                with span("event_intake"):
+                    self._process_waiting()
                 if self._pipelined:
                     # the flush may also have planned evictions (the
                     # failed wave's PostFilter ran in _finish): fire them
@@ -3869,13 +3890,14 @@ class Scheduler:
                     # into the next one (its nominated reservation holds
                     # the freed slot either way)
                     self._flush_evictions_safe()
-                self.queue.flush_backoff_completed()
+                with span("event_intake"):
+                    self.queue.flush_backoff_completed()
                 # a drained wait room or a churn event may have refilled
                 # the job queue mid-iteration
                 if self.jobqueue.active:
                     self.jobqueue.release(self.queue,
                                           self._effective_batch())
-                popped, runnable = self._pop_runnable()
+                popped, runnable, tr = self._pop_runnable()
                 if popped == 0:
                     break
             total += popped
@@ -3894,7 +3916,7 @@ class Scheduler:
                 # pipelining resumes at full depth after the host-path
                 # batch commits
                 try:
-                    nxt = self._dispatch(runnable, chained,
+                    nxt = self._dispatch(runnable, chained, tr,
                                          flush_pending=flush_all)
                 except Unavailable:
                     self._park_batch_unreachable(runnable)
@@ -3929,6 +3951,7 @@ class Scheduler:
         flush_all()
         self._drain_bind_results(wait=True)
         self._flush_evictions_safe()
-        self._process_deferred_events()
-        self.recorder.flush()
+        with span("drain_tail"):
+            self._process_deferred_events()
+            self.recorder.flush()
         return total

@@ -114,7 +114,16 @@ class ServingEndpoints:
                     prof = getattr(sched, "profiler", None)
                     body = json.dumps({
                         "enabled": flight.enabled,
+                        # each cycle carries its "spans": [name, start,
+                        # end, thread] of every phase, in ending order
                         "cycles": flight.last(n),
+                        # the daemon loop's own spans between the cycles
+                        # (LOOP_PHASES + views): [name, start, end,
+                        # thread, loop turn]
+                        "loop_spans": flight.last_loop_spans(n * 8),
+                        # the queue's in-flight event log: entries,
+                        # high-water, trims that scanned and their seconds
+                        "queue": sched.queue.trim_stats(),
                         "phases": flight.phase_percentiles(),
                         "host_tail_share": round(
                             flight.host_tail_share(), 4),

@@ -10,12 +10,20 @@ known-idle points where a bounded pause is invisible.
 
 Reference-counting still reclaims the (acyclic) bulk of per-cycle garbage
 immediately; what the guard defers is only cycle detection.
+
+The guard also WATCHES the collector it manages: one ``gc.callbacks`` hook
+for the life of the process times every pause and hands (seconds,
+generation) to the flight recorders that asked (``watch``), on whichever
+thread the collector ran — ``scheduler_gc_pause_seconds`` and the
+``gc_pause`` view phase.
 """
 
 from __future__ import annotations
 
 import gc
 import threading
+import time
+import weakref
 
 
 class GCGuard:
@@ -32,6 +40,40 @@ class GCGuard:
         self._lock = threading.Lock()
         self._depth = 0
         self._managed = False
+        # pause watch: weak references to the recorders, swapped whole
+        # (never mutated) so the hook reads them without a lock — it can
+        # fire inside any allocation, also one made under self._lock
+        self._watchers: tuple = ()
+        self._hooked = False
+        self._t0 = 0.0
+
+    def watch(self, recorder) -> None:
+        """Report every collector pause to ``recorder.gc_pause(secs,
+        generation)`` until it is unwatched or collected."""
+        with self._lock:
+            live = tuple(r for r in self._watchers if r() is not None)
+            self._watchers = live + (weakref.ref(recorder),)
+            if not self._hooked:
+                gc.callbacks.append(self._on_gc)
+                self._hooked = True
+
+    def unwatch(self, recorder) -> None:
+        with self._lock:
+            self._watchers = tuple(
+                r for r in self._watchers
+                if r() is not None and r() is not recorder)
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        # collections never overlap (the interpreter runs one at a time),
+        # so one start instant is enough
+        if phase == "start":
+            self._t0 = time.perf_counter()
+            return
+        secs = time.perf_counter() - self._t0
+        for ref in self._watchers:
+            recorder = ref()
+            if recorder is not None:
+                recorder.gc_pause(secs, info.get("generation", -1))
 
     def __enter__(self) -> "GCGuard":
         with self._lock:

@@ -15,10 +15,15 @@ Two generations of tracing live here:
   fallback) into a bounded ring buffer, and each phase feeds a
   per-phase histogram in the metrics Registry — the continuous
   per-stage latency attribution Kant (arxiv 2510.01256) argues
-  large-cluster schedulers need, instead of sampling-on-slow. The
-  recorder's overhead budget is <2% of p50 cycle time (enforced by
-  ``bench.py --trace-overhead``): recording a phase is two clock reads
-  plus one dict write, and the ring is a deque append.
+  large-cluster schedulers need, instead of sampling-on-slow. ONE
+  entry records a phase, ``FlightRecorder.span``: it keeps the span's
+  instants (name, start, end, thread, cycle or loop turn) and delivers
+  ``(phase, secs)`` through ``CycleTrace.add`` / ``observe_phase``
+  exactly once, at the instant the span ends, on the thread it ran on.
+  The recorder's overhead budget is <2% of p50 cycle time (enforced by
+  ``bench.py --trace-overhead``): a span is two clock reads, one dict
+  write and one list append; while a JAX profiler trace is being taken
+  it is also a ``jax.profiler.TraceAnnotation`` of the same name.
 
 - ``PodTimelines``: per-pod lifecycle stamps (enqueue, pop/attempt,
   assume, bind, parks) plus the last unschedulable diagnosis (which
@@ -32,6 +37,7 @@ import collections
 import json
 import logging
 import os
+import threading
 import time
 from contextlib import contextmanager
 from typing import Callable, Optional
@@ -81,12 +87,42 @@ CYCLE_PHASES = (
     "gang_commit",        # host commit of device-placed gang units
                           # (reserve-all -> bind-all, atomic rollback)
     "commit_pull",        # pipelined waves only: the commit thread's
-                          # device pull, measured on the commit thread
-                          # (overlap view: that wall time runs CONCURRENT
-                          # with the loop thread's next dispatch, so it is
-                          # excluded from totals/host-tail — the loop
-                          # thread's actual blocked wait lands in
-                          # device_launch)
+                          # device pull, measured AND reported on the
+                          # commit thread (overlap view: that wall time
+                          # runs CONCURRENT with the loop thread's next
+                          # dispatch, so it is excluded from totals/
+                          # host-tail — the loop thread's actual blocked
+                          # wait lands in device_launch)
+    "snapshot_cache",     # cache.update_snapshot: the first of the two
+                          # pieces snapshot_sync is timed in (view of
+                          # that piece, reported just ahead of it)
+    "mirror_sync",        # mirror.sync: the second piece (view)
+)
+
+# exclusive phases of the DAEMON LOOP around the cycles, each reported at
+# its end through observe_phase; with the cycle phases they tile the loop
+# thread's wall time. Left out of cycle totals and the host-tail share,
+# so no headline changed its meaning when they arrived.
+LOOP_PHASES = (
+    "idle_wait",          # stop.wait(idle_sleep) and the elector's wait
+    "maintenance",        # run_maintenance
+    "lock_wait",          # acquiring the scheduler lock for a drain
+    "event_intake",       # deferred informer events, the Permit wait
+                          # room, the backoff flush
+    "gc_sweep",           # gc_guard's exit collection and idle_sweep
+    "drain_tail",         # closing a drain: deferred events + the async
+                          # event recorder's flush
+)
+
+# loop-level views: seconds another component measured, reported by the
+# loop beside the exclusive phase they sit in
+LOOP_VIEW_PHASES = (
+    "queue_done",         # PriorityQueue.done() -> _trim_events scans,
+                          # the queue's own clock; reported with (and
+                          # inside) binder_drain, 0.0 when none scanned
+    "gc_pause",           # one collector pause (utils/gcguard's
+                          # gc.callbacks hook), on whichever thread
+                          # the collector ran
 )
 
 # the dra_* attribution views, excluded from total/host-tail arithmetic
@@ -97,7 +133,8 @@ DRA_VIEW_PHASES = ("dra_mask_compile", "dra_device_eval", "dra_commit")
 # NOTE: learned_score is NOT here — its time is exclusive (nothing else
 # measures the checkpoint poll), so hiding it would let a slow reload
 # path pass the --ab-scorer parity gate unseen
-VIEW_PHASES = DRA_VIEW_PHASES + ("device_compile",)
+VIEW_PHASES = DRA_VIEW_PHASES + (
+    "device_compile", "snapshot_cache", "mirror_sync") + LOOP_VIEW_PHASES
 
 # phases measured on the commit thread, CONCURRENT with loop-thread
 # work. Counting them in totals/host-tail would book overlapped wall
@@ -109,6 +146,10 @@ OVERLAP_PHASES = ("commit_pull",)
 # everything excluded from the serial-cycle-time arithmetic
 EXCLUDED_PHASES = VIEW_PHASES + OVERLAP_PHASES
 
+# what CycleTrace.total(), host_tail_share() and bench.py's phase totals
+# leave out: the views, the overlap and the loop-level phases
+UNCOUNTED_PHASES = frozenset(EXCLUDED_PHASES + LOOP_PHASES)
+
 # trace-export JSON-lines format version (CycleTrace.to_dict "v"):
 # v2 added per-pod placement rows (pod, chosen node, aggregate score,
 # chosen-node learned-feature vector) — the replay-dataset substrate;
@@ -116,7 +157,9 @@ EXCLUDED_PHASES = VIEW_PHASES + OVERLAP_PHASES
 # ("alt": [[node, score], ...], trace_export_alts) — the counterfactual
 # substrate behind per-placement regret (learn/regret.py). Additive:
 # v2 rows remain valid replay input (learn/replay.py reads >= 2).
-EXPORT_VERSION = 3
+# v4 adds "spans": every phase span of the cycle as [name, start, end,
+# thread] on the recorder's clock (phases_ms stays: it is their sums).
+EXPORT_VERSION = 4
 
 # phases that are host-side Python work (the "host tail" the ROADMAP's
 # sub-10x offenders ask us to attribute); device_launch is device +
@@ -182,7 +225,7 @@ class CycleTrace:
     phase histogram when the cycle is recorded."""
 
     __slots__ = ("cycle", "start", "pods", "scheduled", "failed",
-                 "chained", "phases", "plugins", "placements",
+                 "chained", "phases", "spans", "plugins", "placements",
                  "occupancy", "depth")
 
     def __init__(self, cycle: int, start: float, pods: int,
@@ -202,6 +245,9 @@ class CycleTrace:
         # (how many waves were in flight, the stall detector)
         self.depth = 0
         self.phases: dict[str, float] = {}
+        # (name, start, end, thread ident) of every span of this cycle,
+        # in the order they ended (FlightRecorder.span appends)
+        self.spans: list[tuple[str, float, float, int]] = []
         self.plugins: dict[str, float] = {}   # "plugin/point" -> secs
         # per-pod placement rows (export v2+): {"pod", "uid", "node",
         # "score"[, "feat"][, "alt"]} — node None for failed attempts,
@@ -216,9 +262,10 @@ class CycleTrace:
         # view phases double-count time inside the real phases; overlap
         # phases ran on the commit thread concurrent with the loop
         return sum(v for k, v in self.phases.items()
-                   if k not in EXCLUDED_PHASES)
+                   if k not in UNCOUNTED_PHASES)
 
-    def to_dict(self) -> dict:
+    def to_dict(self, thread_names: Optional[dict] = None) -> dict:
+        names = thread_names or {}
         d = {
             "v": EXPORT_VERSION,
             "cycle": self.cycle,
@@ -231,6 +278,8 @@ class CycleTrace:
             "total_ms": round(self.total() * 1e3, 3),
             "phases_ms": {k: round(v * 1e3, 3)
                           for k, v in self.phases.items()},
+            "spans": [[n, round(a, 6), round(b, 6), names.get(t, t)]
+                      for n, a, b, t in self.spans],
         }
         if self.occupancy is not None:
             d["occupancy"] = round(self.occupancy, 4)
@@ -256,24 +305,97 @@ class _NullTrace(CycleTrace):
 _NULL_TRACE = _NullTrace()
 
 
+class Span:
+    """One timed interval, handed out STARTED by ``FlightRecorder.span``;
+    ``end()`` (or leaving the ``with``) reads the clock and reports it.
+    ``t0``/``t1``/``secs`` stay readable afterwards, so the caller needs
+    no clock pair of its own. ``view`` names a view phase that is this
+    very interval (one half of a phase timed in two pieces): it is
+    reported with the same seconds just AHEAD of the phase, so a reader
+    that rebuilds spans as (now - secs, now) and gives shared time to
+    the earlier one always sees the view."""
+
+    __slots__ = ("_fl", "name", "view", "_tr", "_ann", "t0", "t1")
+
+    def __init__(self, fl: "FlightRecorder", name: str,
+                 tr: Optional[CycleTrace], view: Optional[str] = None):
+        self._fl = fl
+        self.name = name
+        self.view = view
+        self._tr = tr
+        self._ann = None
+        ann = fl._annotation
+        if ann is not None and ann.is_enabled():
+            # a profiler trace is being taken: the span shows over the
+            # device's operations on the profiler's own clock
+            self._ann = ann(name if view is None else view)
+            self._ann.__enter__()
+        self.t1 = None
+        self.t0 = fl._now()
+
+    @property
+    def secs(self) -> float:
+        return self.t1 - self.t0
+
+    def end(self, report: bool = True,
+            tr: Optional[CycleTrace] = None) -> float:
+        """Close the span; returns its seconds. ``report=False`` closes
+        it unrecorded (a drain that collected nothing is no phase);
+        ``tr`` hands it to a cycle opened after the span began (the
+        pop that opens its cycle)."""
+        if tr is not None:
+            self._tr = tr
+        self.t1 = self._fl._now()
+        if report:
+            self._fl._report(self)
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        return self.t1 - self.t0
+
+    def __enter__(self) -> "Span":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
 class FlightRecorder:
     """Always-on, low-overhead cycle recorder: a bounded ring of
     CycleTraces + per-phase / per-plugin histograms feeding the metrics
     Registry, with an optional JSON-lines export for offline analysis.
 
-    Thread model: begin/record/observe_phase/plugin_observe run on the
-    scheduling-loop thread only (binder-thread observations go through
-    the scheduler's AsyncRecorder instead); readers (``/debug/trace``)
-    take cheap snapshots of the deque."""
+    Thread model: begin/record/plugin_observe run on the scheduling-
+    loop thread only (binder-thread observations go through the
+    scheduler's AsyncRecorder instead). ``span`` may run on any thread,
+    given that one thread at a time writes one phase: a cycle's span
+    lands on its CycleTrace (the commit thread's ``commit_pull`` is
+    harvested before the loop records the cycle), a loop-level span in
+    its own series of the phase histogram. Readers (``/debug/trace``)
+    take cheap snapshots of the deques."""
 
     def __init__(self, phase_hist=None, plugin_hist=None,
                  capacity: int = 256, export_path: Optional[str] = None,
-                 enabled: bool = True, export_max_bytes: int = 0):
+                 enabled: bool = True, export_max_bytes: int = 0,
+                 now: Callable[[], float] = time.monotonic,
+                 gc_pause_hist=None):
         self.enabled = enabled and capacity > 0
         self.phase_hist = phase_hist
         self.plugin_hist = plugin_hist
+        self.gc_pause_hist = gc_pause_hist
+        self._now = now
         self.ring: collections.deque = collections.deque(
             maxlen=max(1, capacity))
+        # loop-level spans (LOOP_PHASES and their views): (name, start,
+        # end, thread ident, loop turn), bounded like the ring
+        self.loop_spans: collections.deque = collections.deque(
+            maxlen=max(1, capacity) * 16)
+        self.turn = 0                # Scheduler.run's loop turn
+        self._thread_names: dict[int, str] = {}
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:          # a control-plane process without JAX
+            TraceAnnotation = None
+        self._annotation = TraceAnnotation if self.enabled else None
         # device-occupancy ring (floats, same capacity): record() copies
         # each finished cycle's occupancy here so occupancy_stats() needn't
         # walk CycleTrace objects under the readers' snapshot
@@ -301,7 +423,52 @@ class FlightRecorder:
         offline replay consumer needs."""
         return self._export_file is not None
 
-    # ------------- recording (loop thread) -------------
+    # ------------- recording -------------
+
+    def span(self, name: str, tr: Optional[CycleTrace] = None,
+             view: Optional[str] = None) -> Span:
+        """THE entry for timing a phase: returns a started Span; use it
+        as a context manager or call ``end()``. With ``tr`` the span
+        belongs to that cycle and is delivered through ``tr.add``;
+        without, it is loop-level (stamped with the loop turn) and goes
+        through ``observe_phase``. A disabled recorder still times the
+        span (callers read ``secs``) and records nothing."""
+        return Span(self, name, tr, view)
+
+    def _report(self, sp: Span) -> None:
+        if not self.enabled:
+            return
+        tid = threading.get_ident()
+        if tid not in self._thread_names:
+            self._thread_names[tid] = threading.current_thread().name
+        tr = sp._tr
+        secs = sp.t1 - sp.t0
+        for name in ((sp.name,) if sp.view is None else (sp.view, sp.name)):
+            if tr is None:
+                self.loop_spans.append((name, sp.t0, sp.t1, tid, self.turn))
+                self.observe_phase(name, secs)
+            elif tr is not _NULL_TRACE:
+                tr.spans.append((name, sp.t0, sp.t1, tid))
+                tr.add(name, secs)
+
+    def observe_view(self, phase: str, secs: float) -> None:
+        """A loop-level view whose seconds another component measured
+        (LOOP_VIEW_PHASES), reported the instant it ended: kept as a
+        span ending now, delivered through ``observe_phase``."""
+        if not self.enabled:
+            return
+        end = self._now()
+        self.loop_spans.append((phase, end - secs, end,
+                                threading.get_ident(), self.turn))
+        self.observe_phase(phase, secs)
+
+    def gc_pause(self, secs: float, generation: int) -> None:
+        """One collector pause (utils/gcguard's hook), on whichever
+        thread the collector ran. Collections never overlap, so each of
+        the two series written here has one writer at a time."""
+        if self.enabled and self.gc_pause_hist is not None:
+            self.gc_pause_hist.observe(secs, generation=str(generation))
+        self.observe_view("gc_pause", secs)
 
     def begin(self, start: float, pods: int,
               chained: bool = False) -> CycleTrace:
@@ -333,7 +500,7 @@ class FlightRecorder:
             for phase, secs in tr.phases.items():
                 h.observe(secs, phase=phase)
         if self._export_file is not None:
-            line = json.dumps(tr.to_dict()) + "\n"
+            line = json.dumps(tr.to_dict(self._thread_names)) + "\n"
             if self._export_max_bytes \
                     and self._export_bytes + len(line) \
                     > self._export_max_bytes \
@@ -422,7 +589,17 @@ class FlightRecorder:
     def last(self, n: int = 32) -> list[dict]:
         if n <= 0:        # [-0:] would be the WHOLE ring, not none of it
             return []
-        return [tr.to_dict() for tr in list(self.ring)[-n:]]
+        return [tr.to_dict(self._thread_names)
+                for tr in list(self.ring)[-n:]]
+
+    def last_loop_spans(self, n: int = 256) -> list[list]:
+        """The newest loop-level spans as [name, start, end, thread,
+        turn] (``/debug/trace``'s ``loop_spans``)."""
+        if n <= 0:
+            return []
+        names = self._thread_names
+        return [[nm, round(a, 6), round(b, 6), names.get(t, t), turn]
+                for nm, a, b, t, turn in list(self.loop_spans)[-n:]]
 
     def phase_percentiles(self) -> dict:
         """{phase: {p50_ms, p90_ms, p99_ms, count, total_s}} from the
@@ -480,7 +657,7 @@ class FlightRecorder:
         host = total = 0.0
         for k in list(h._series):
             phase = dict(k).get("phase", "?")
-            if phase in EXCLUDED_PHASES:
+            if phase in UNCOUNTED_PHASES:
                 continue
             s = h._series.get(k)
             if not s:

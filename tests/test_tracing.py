@@ -429,3 +429,241 @@ def test_debug_trace_and_pod_endpoints_authz():
 
 # suite-tier discipline (tests/test_markers.py): area marker
 pytestmark = pytest.mark.observability
+
+
+# ------------------------- spans: instants, delivered where they end
+
+
+class TickClock:
+    """A fake clock that moves one tick per read, for every thread, and
+    remembers each thread's last reading: what a wrap of CycleTrace.add
+    sees as "now" when nothing read the clock since the span ended."""
+
+    def __init__(self, tick=1e-4):
+        import threading
+
+        self.t = 1000.0
+        self.tick = tick
+        self._lock = threading.Lock()
+        self._last = threading.local()
+
+    def __call__(self):
+        with self._lock:
+            self.t += self.tick
+            self._last.t = self.t
+            return self.t
+
+    def last(self):
+        return self._last.t
+
+
+def _install_wrap(monkeypatch, clock):
+    """benchmark/cell.py::PhaseSpans's wrap of the two delivery methods,
+    on the fake clock, every thread kept: [(phase, start, end, thread
+    ident, cycle or None)] reconstructed as (now - secs, now)."""
+    import threading
+
+    got = []
+    add, observe = CycleTrace.add, FlightRecorder.observe_phase
+
+    def traced_add(tr, phase, secs):
+        t = clock.last()
+        got.append((phase, t - secs, t, threading.get_ident(), tr.cycle))
+        add(tr, phase, secs)
+
+    def traced_observe(fl, phase, secs):
+        t = clock.last()
+        got.append((phase, t - secs, t, threading.get_ident(), None))
+        observe(fl, phase, secs)
+
+    monkeypatch.setattr(CycleTrace, "add", traced_add)
+    monkeypatch.setattr(FlightRecorder, "observe_phase", traced_observe)
+    return got
+
+
+def _pipelined_drain(monkeypatch, pods=96, batch=8):
+    """A pipelined drain on the CPU under the tick clock and the wrap;
+    returns (scheduler, reconstructed spans, loop thread ident)."""
+    import threading
+
+    clock = TickClock()
+    got = _install_wrap(monkeypatch, clock)
+    hub = Hub()
+    for i in range(8):
+        hub.create_node(mknode(i))
+    cfg = default_config()
+    cfg.batch_size = batch
+    cfg.pipelined_waves = True
+    sched = Scheduler(hub, cfg, caps=Capacities(nodes=16, pods=256),
+                      now=clock)
+    for i in range(pods):
+        hub.create_pod(mkpod(f"p{i}"))
+    sched.run_until_idle()
+    return sched, got, threading.get_ident()
+
+
+def _exclusive(name):
+    from kubernetes_tpu.utils.tracing import OVERLAP_PHASES, VIEW_PHASES
+
+    return name not in VIEW_PHASES and name not in OVERLAP_PHASES
+
+
+def test_exclusive_phases_are_delivered_where_they_end(monkeypatch):
+    """(a) every exclusive phase of the loop thread reaches the two
+    patched methods at the instant its span ends, so the benchmark's
+    reconstruction (now - secs, now) IS the recorded span."""
+    sched, got, me = _pipelined_drain(monkeypatch)
+    try:
+        fl = sched.flight
+        recorded = [(n, a, b) for tr in fl.ring
+                    for n, a, b, t in tr.spans if t == me and _exclusive(n)]
+        recorded += [(n, a, b) for n, a, b, t, _turn in fl.loop_spans
+                     if t == me and _exclusive(n)]
+        rebuilt = {(n, round(a, 9), round(b, 9))
+                   for n, a, b, t, _c in got if t == me and _exclusive(n)}
+        names = {n for n, _a, _b in recorded}
+        for want in ("queue_pop", "pack", "device_dispatch", "device_launch",
+                     "commit", "binder_drain", "event_intake", "drain_tail",
+                     "lock_wait", "gc_sweep"):
+            assert want in names, want
+        for n, a, b in recorded:
+            assert (n, round(a, 9), round(b, 9)) in rebuilt, (n, a, b)
+        # exactly once per span: no phase is delivered a second time
+        assert len([1 for n, _a, _b, t, _c in got
+                    if t == me and _exclusive(n)]) == len(recorded)
+    finally:
+        sched.close()
+
+
+def test_commit_is_not_laid_under_its_own_pull_or_wait(monkeypatch):
+    """(b) the reconstructed commit span of a cycle shares under 5% of
+    its length with the same cycle's commit_pull and device_launch: both
+    are reported before the commit loop starts, the pull from the commit
+    thread."""
+    sched, got, me = _pipelined_drain(monkeypatch)
+    try:
+        by_cycle = {}
+        for n, a, b, t, cyc in got:
+            if cyc is not None and cyc > 0:
+                by_cycle.setdefault(cyc, {}).setdefault(n, []).append(
+                    (a, b, t))
+        checked = 0
+        for cyc, ph in by_cycle.items():
+            if "commit" not in ph or "commit_pull" not in ph:
+                continue
+            (ca, cb, _t), = ph["commit"]
+            assert all(t != me for _a, _b, t in ph["commit_pull"])
+            for other in ph["commit_pull"] + ph["device_launch"]:
+                shared = min(cb, other[1]) - max(ca, other[0])
+                assert shared < 0.05 * (cb - ca), (cyc, ph)
+            checked += 1
+        assert checked >= 4
+    finally:
+        sched.close()
+
+
+def test_loop_turns_are_tiled_by_exclusive_spans():
+    """(c) over twenty turns of Scheduler.run the exclusive spans of the
+    loop thread cover at least 95% of its wall time and never overlap."""
+    import time
+
+    hub = Hub()
+    for i in range(8):
+        hub.create_node(mknode(i))
+    sched = _sched(hub)
+    try:
+        hub.create_pod(mkpod("warm"))
+        sched.run_until_idle()              # the compile, outside the turns
+        sched.start()
+        fl = sched.flight
+        time.sleep(0.05)
+        first = fl.turn
+        k = 0
+        while fl.turn < first + 22 and k < 400:
+            hub.create_pod(mkpod(f"p{k}"))
+            k += 1
+            time.sleep(0.01)
+        me = sched._daemon.ident
+        last = fl.turn
+        sched.stop()
+        assert last >= first + 20
+        loop = [(a, b, n) for n, a, b, t, turn in fl.loop_spans
+                if t == me and _exclusive(n) and first < turn < last]
+        t0, t1 = min(a for a, _b, _n in loop), max(b for _a, b, _n in loop)
+        spans = loop + [(a, b, n) for tr in fl.ring
+                        for n, a, b, t in tr.spans
+                        if t == me and _exclusive(n) and t0 <= a and b <= t1]
+        spans.sort()
+        assert {"idle_wait", "maintenance", "lock_wait", "event_intake",
+                "gc_sweep", "drain_tail"} <= {n for _a, _b, n in spans}
+        for (_a, b, n), (a2, _b2, n2) in zip(spans, spans[1:]):
+            assert a2 >= b, (n, n2)
+        covered = sum(b - a for a, b, _n in spans)
+        assert covered >= 0.95 * (t1 - t0), covered / (t1 - t0)
+    finally:
+        sched.close()
+
+
+def test_loop_phases_and_new_views_leave_the_headlines_alone():
+    """(d) LOOP_PHASES and the new views change neither CycleTrace.total()
+    nor host_tail_share()."""
+    from kubernetes_tpu.utils.tracing import (
+        CYCLE_PHASES,
+        LOOP_PHASES,
+        LOOP_VIEW_PHASES,
+        UNCOUNTED_PHASES,
+        VIEW_PHASES,
+    )
+
+    assert not set(LOOP_PHASES) & set(CYCLE_PHASES)
+    assert not set(LOOP_PHASES) & set(HOST_PHASES)
+    assert {"queue_done", "snapshot_cache", "mirror_sync",
+            "gc_pause"} <= set(VIEW_PHASES)
+    assert set(LOOP_PHASES) | set(LOOP_VIEW_PHASES) <= UNCOUNTED_PHASES
+    phase, _ = _hists()
+    rec = FlightRecorder(phase_hist=phase)
+    tr = rec.begin(start=0.0, pods=1)
+    tr.add("host_plugins", 0.03)
+    tr.add("device_launch", 0.06)
+    tr.add("commit", 0.01)
+    before = tr.total()
+    tr.add("snapshot_cache", 0.02)
+    tr.add("mirror_sync", 0.02)
+    rec.record(tr)
+    share = rec.host_tail_share()
+    for p in LOOP_PHASES + LOOP_VIEW_PHASES:
+        rec.observe_phase(p, 0.5)
+    assert tr.total() == before
+    assert abs(share - 0.4) < 1e-9
+    assert rec.host_tail_share() == share
+
+
+def test_span_export_v4_and_disabled_recorder(tmp_path):
+    """A cycle's spans ride its export line as [name, start, end, thread]
+    beside phases_ms (their sums); a disabled recorder still times a span
+    for its caller and records nothing."""
+    from kubernetes_tpu.utils.tracing import EXPORT_VERSION
+
+    clock = TickClock(tick=0.5)
+    path = str(tmp_path / "t.jsonl")
+    rec = FlightRecorder(capacity=4, export_path=path, now=clock)
+    tr = rec.begin(start=clock(), pods=1)
+    with rec.span("pack", tr) as sp:
+        pass
+    half = rec.span("queue_pop")
+    half.end(tr=tr)                      # handed to a cycle opened later
+    with rec.span("idle_wait"):
+        pass
+    rec.record(tr)
+    rec.close()
+    line = json.loads(open(path).read().splitlines()[0])
+    assert line["v"] == EXPORT_VERSION == 4
+    assert [s[0] for s in line["spans"]] == ["pack", "queue_pop"]
+    assert line["spans"][0][1:3] == [sp.t0, sp.t1] and sp.secs == 0.5
+    assert line["phases_ms"] == {"pack": 500.0, "queue_pop": 500.0}
+    (name, a, b, _thread, turn), = rec.last_loop_spans()
+    assert (name, b - a, turn) == ("idle_wait", 0.5, 0)
+    off = FlightRecorder(capacity=0, now=clock)
+    with off.span("commit", off.begin(0.0, 1)) as sp:
+        pass
+    assert sp.secs == 0.5 and not off.ring and not off.loop_spans
