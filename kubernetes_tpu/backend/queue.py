@@ -21,6 +21,7 @@ of them in-flight with concurrent-event replay per pod.
 from __future__ import annotations
 
 import time
+from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -122,14 +123,27 @@ class PriorityQueue:
         # in-flight machinery (active_queue.go:147-169): ONE shared event log
         # (seq, event, old, new) + per-pod start seq — appending an event is
         # O(1) regardless of how many pods are in flight (the reference's
-        # shared inFlightEvents list, not a per-pod copy)
+        # shared inFlightEvents list, not a per-pod copy). The log is only
+        # ever appended at its tail and dropped from its head.
         self._in_flight: dict[str, int] = {}        # uid -> start seq
-        self._events: list[tuple[int, ClusterEvent, object, object]] = []
+        self._events: deque[tuple[int, ClusterEvent, object, object]] = \
+            deque()
         self._next_seq = 0
+        # the reference's pod markers, one per START SEQ rather than per
+        # pod (a pop_batch shares one): the distinct start seqs in pop
+        # order, which is ascending, and how many in-flight pods hold
+        # each. done() arrives out of pop order, so a seq whose last
+        # holder left stays in _starts until it reaches the front; the
+        # front live one is the oldest event anybody can still replay.
+        # _starts is never longer than the log + 1: seqs only advance
+        # with an appended event.
+        self._starts: deque[int] = deque()
+        self._start_holders: dict[int, int] = {}    # start seq -> pods
         # what _trim_events cost, counted by the queue itself at that one
-        # boundary: calls, calls that scanned the in-flight set, seconds
-        # in those scans (this queue's clock) and the event log's
-        # high-water length (the flight recorder's queue_done view)
+        # boundary: calls, calls that dropped entries from the log's head
+        # with pods still in flight, seconds in those drops (this queue's
+        # clock) and the event log's high-water length (the flight
+        # recorder's queue_done view)
         self.trim_calls = 0
         self.trim_scans = 0
         self.trim_scan_s = 0.0
@@ -280,7 +294,17 @@ class PriorityQueue:
         qp.attempts += 1
         if qp.initial_attempt_timestamp is None:
             qp.initial_attempt_timestamp = self._now()
-        self._in_flight[qp.uid] = self._next_seq
+        uid = qp.uid
+        if uid in self._in_flight:
+            # re-added and re-popped before its done(): the newer start
+            # replaces the older one, which may have been the oldest
+            self._release(uid)
+            self._trim_events()
+        seq = self._next_seq
+        self._in_flight[uid] = seq
+        self._start_holders[seq] = self._start_holders.get(seq, 0) + 1
+        if not self._starts or self._starts[-1] != seq:
+            self._starts.append(seq)        # the newest seq is the tail
         return qp
 
     def pop_batch(self, n: int) -> list[QueuedPodInfo]:
@@ -296,24 +320,45 @@ class PriorityQueue:
     def done(self, uid: str) -> None:
         """Scheduling (+binding) finished; release in-flight events
         (schedule_one.go:305 via active_queue.go done)."""
-        self._in_flight.pop(uid, None)
+        self._release(uid)
         self._trim_events()
 
+    def _release(self, uid: str) -> Optional[int]:
+        """Take a pod out of the in-flight set; its start seq, if it was
+        in flight."""
+        start = self._in_flight.pop(uid, None)
+        if start is not None:
+            holders = self._start_holders
+            n = holders[start] - 1
+            if n:
+                holders[start] = n
+            else:
+                del holders[start]
+        return start
+
     def _trim_events(self) -> None:
-        """Drop log entries no in-flight pod can still replay. The min() scan
-        is amortized: only when the log is empty-able or has grown past the
-        trim threshold. Only the scanning branch reads the clock."""
+        """Drop, from the log's head, the entries no in-flight pod can
+        still replay: those older than the oldest start seq still held,
+        all of them once nothing is in flight. Every start seq and every
+        log entry is dropped once, so a call costs O(1) amortised. Only
+        the branch that walks the head reads the clock."""
         self.trim_calls += 1
-        n = len(self._events)
-        if n > self.events_high_water:
-            self.events_high_water = n
+        events = self._events
+        if len(events) > self.events_high_water:
+            self.events_high_water = len(events)
+        starts = self._starts
         if not self._in_flight:
-            self._events.clear()
-        elif n > 8192:
+            events.clear()
+            starts.clear()
+            return
+        holders = self._start_holders
+        while starts[0] not in holders:
+            starts.popleft()
+        low = starts[0]
+        if events and events[0][0] < low:
             t0 = self._now()
-            low = min(self._in_flight.values())
-            keep = [e for e in self._events if e[0] >= low]
-            self._events = keep
+            while events and events[0][0] < low:
+                events.popleft()
             self.trim_scans += 1
             self.trim_scan_s += self._now() - t0
 
@@ -322,8 +367,9 @@ class PriorityQueue:
 
     def trim_stats(self) -> dict:
         """The event log's counts, for /debug/trace."""
-        return {"entries": self.event_log_len(),
-                "high_water": self.events_high_water,
+        entries = self.event_log_len()
+        return {"entries": entries,
+                "high_water": max(self.events_high_water, entries),
                 "trim_calls": self.trim_calls,
                 "trim_scans": self.trim_scans,
                 "trim_scan_s": round(self.trim_scan_s, 6)}
@@ -348,20 +394,22 @@ class PriorityQueue:
         that arrived while in flight; if any hints QUEUE, skip the
         unschedulable pool and go straight to backoff/active."""
         uid = qp.uid
-        start = self._in_flight.pop(uid, None)
+        start = self._release(uid)
         qp.timestamp = self._now()
         if uid in self._active or uid in self._backoff \
                 or uid in self._unschedulable or uid in self._gated:
             self._trim_events()
             return
-        if start is not None:
-            for seq, event, old_obj, new_obj in self._events:
-                if seq >= start and self._worth_requeuing(qp, event, old_obj,
-                                                          new_obj):
-                    self._trim_events()
-                    self._requeue(qp)
-                    return
+        # the log still holds everything from this pod's start on: it is
+        # trimmed only after the replay
+        replayed = start is not None and any(
+            seq >= start and self._worth_requeuing(qp, event, old_obj,
+                                                   new_obj)
+            for seq, event, old_obj, new_obj in self._events)
         self._trim_events()
+        if replayed:
+            self._requeue(qp)
+            return
         if qp.consecutive_errors_count > 0 and not qp.unschedulable_plugins:
             # error-class failure (apiserver hiccup, bind conflict): no
             # cluster event will "fix" it — retry after backoff

@@ -248,10 +248,12 @@ class SchedulerMetrics:
         self.queue_event_log_entries = r.register(Gauge(
             "scheduler_queue_event_log_entries",
             "Length of the scheduling queue's in-flight event log at the "
-            "last binder drain (past 8192 every done() scans it)"))
+            "last binder drain: the events since the oldest pod still in "
+            "flight was popped"))
         self.queue_event_trims = r.register(Counter(
             "scheduler_queue_event_trims_total",
-            "Scans of the in-flight set by PriorityQueue._trim_events"))
+            "PriorityQueue._trim_events calls that dropped entries from "
+            "the event log's head while pods stayed in flight"))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

@@ -117,9 +117,10 @@ LOOP_PHASES = (
 # loop-level views: seconds another component measured, reported by the
 # loop beside the exclusive phase they sit in
 LOOP_VIEW_PHASES = (
-    "queue_done",         # PriorityQueue.done() -> _trim_events scans,
-                          # the queue's own clock; reported with (and
-                          # inside) binder_drain, 0.0 when none scanned
+    "queue_done",         # PriorityQueue.done() -> _trim_events dropping
+                          # entries from the event log's head, the
+                          # queue's own clock; reported with (and inside)
+                          # binder_drain, 0.0 when none dropped any
     "gc_pause",           # one collector pause (utils/gcguard's
                           # gc.callbacks hook), on whichever thread
                           # the collector ran

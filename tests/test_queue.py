@@ -3,6 +3,11 @@
 queueing hints, in-flight event replay, gates, flush timers. Virtual clock
 throughout (the reference uses testingclock the same way)."""
 
+import random
+from collections import deque
+
+import pytest
+
 from kubernetes_tpu.api.objects import (
     ObjectMeta,
     Pod,
@@ -176,6 +181,224 @@ def test_error_backoff_separate_counter():
     assert q.backoff_remaining(qp) == 4.0
 
 
+# ------------- the in-flight event log against a log that is never trimmed
+
+NODE_TAINT = ClusterEvent(EventResource.NODE, ActionType.UPDATE_NODE_TAINT)
+
+
+def _index_hint(pod, old, new):
+    """QUEUE for one event in four, a different one per pod: losing or
+    replaying the wrong event changes where the pod lands."""
+    if new is not None and new % 4 == int(pod.name[1:]) % 4:
+        return QueueingHint.QUEUE
+    return QueueingHint.SKIP
+
+
+MODEL_HINTS = {
+    "Hinted": [ClusterEventWithHint(NODE_ADD, _index_hint)],
+    "Unhinted": [ClusterEventWithHint(POD_DELETE)],
+}
+
+
+class NeverTrimmedQueue(PriorityQueue):
+    """The plain reference: every event ever seen in one list, never
+    trimmed; a pod that comes back replays log[its pop's length:]."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.log = []
+        self.started = {}
+
+    def pop(self):
+        qp = self._active.pop()
+        if qp is None:
+            return None
+        qp.attempts += 1
+        if qp.initial_attempt_timestamp is None:
+            qp.initial_attempt_timestamp = self._now()
+        self.started[qp.uid] = len(self.log)
+        return qp
+
+    def done(self, uid):
+        self.started.pop(uid, None)
+
+    def move_all_to_active_or_backoff(self, event, old_obj=None,
+                                      new_obj=None):
+        self.log.append((event, old_obj, new_obj))
+        return super().move_all_to_active_or_backoff(event, old_obj, new_obj)
+
+    def add_unschedulable_if_not_present(self, qp, pod_scheduling_cycle=0):
+        start = self.started.pop(qp.uid, None)
+        qp.timestamp = self._now()
+        if self.is_parked(qp.uid):
+            return
+        if start is not None and any(
+                self._worth_requeuing(qp, *e) for e in self.log[start:]):
+            self._requeue(qp)
+        elif qp.consecutive_errors_count > 0 \
+                and not qp.unschedulable_plugins:
+            self._requeue(qp)
+        else:
+            self._park(qp, self._unschedulable)
+
+
+def _pool_of(q, uid, in_flight):
+    for name in ("_active", "_backoff", "_unschedulable", "_gated"):
+        if uid in getattr(q, name):
+            return name
+    return "in_flight" if uid in in_flight else "gone"
+
+
+def _model_step(rng, clock, q, ref, pods, flying):
+    """One random operation on both queues. ``flying`` holds, per uid in
+    flight, the queue's QueuedPodInfo and the reference's."""
+    clock.tick(rng.choice((0.0, 0.01, 0.4)))
+    op = rng.choices(
+        ("add", "pop", "event", "burst", "done", "fail", "flush", "re_add"),
+        (5, 6, 8, 1, 3, 5, 2, 1))[0]
+
+    def event():
+        ev = rng.choice((NODE_ADD, POD_DELETE, NODE_TAINT))
+        new = rng.randrange(8)
+        assert q.move_all_to_active_or_backoff(ev, None, new) \
+            == ref.move_all_to_active_or_backoff(ev, None, new)
+
+    if op == "add":
+        pod = mkpod(f"p{len(pods)}", priority=rng.randrange(3))
+        pods.append(pod)
+        q.add(pod)
+        ref.add(pod)
+    elif op == "pop":
+        for _ in range(rng.randrange(1, 4)):    # a batch shares its start
+            a, b = q.pop(), ref.pop()
+            assert (a and a.uid) == (b and b.uid)
+            if a is None:
+                break
+            flying[a.uid] = (a, b)
+    elif op == "event":
+        event()
+    elif op == "burst":
+        with q.coalescing(), ref.coalescing():
+            for _ in range(rng.randrange(1, 6)):
+                event()
+    elif op == "flush":
+        clock.tick(rng.choice((1.0, 11.0)))
+        assert q.flush_backoff_completed() == ref.flush_backoff_completed()
+    elif not flying:
+        return op
+    elif op == "done":
+        uid = rng.choice(sorted(flying))
+        del flying[uid]
+        q.done(uid)
+        ref.done(uid)
+    elif op == "fail":
+        a, b = flying.pop(rng.choice(sorted(flying)))
+        plugins = rng.choice(({"Hinted"}, {"Unhinted"}, {"Hinted"},
+                              {"Hinted", "Unhinted"}, set()))
+        errors = int(not plugins and rng.random() < 0.5)
+        for qp in (a, b):
+            qp.unschedulable_plugins = set(plugins)
+            qp.unschedulable_count += 1
+            qp.consecutive_errors_count = errors
+        q.add_unschedulable_if_not_present(a)
+        ref.add_unschedulable_if_not_present(b)
+    elif op == "re_add":
+        # a relist re-delivers a pod whose cycle is still running: it can
+        # be popped again, under a newer start, before its done()
+        pod = flying[rng.choice(sorted(flying))][0].pod
+        q.add(pod)
+        ref.add(pod)
+    return op
+
+
+@pytest.mark.parametrize("seed", range(24))
+def test_trimmed_log_replays_like_a_log_never_trimmed(seed):
+    """Random interleavings of add, pop, hinted and unhinted events,
+    coalesced bursts, done, failed cycles, re-pops and backoff flushes:
+    every pod sits in the same pool as under the reference at every step,
+    and the log holds exactly the events from the oldest in-flight start
+    on (none once nothing is in flight)."""
+    rng = random.Random(seed)
+    clock = Clock()
+    kw = dict(less_fn=less, pre_enqueue=gate_fn,
+              queueing_hints=MODEL_HINTS, now=clock.now)
+    q, ref = PriorityQueue(**kw), NeverTrimmedQueue(**kw)
+    pods, flying, ops = [], {}, set()
+    for _ in range(600):
+        ops.add(_model_step(rng, clock, q, ref, pods, flying))
+        assert set(q._in_flight) == set(ref.started)
+        for pod in pods:
+            uid = pod.metadata.uid
+            assert _pool_of(q, uid, q._in_flight) \
+                == _pool_of(ref, uid, ref.started), pod.name
+        seqs = [e[0] for e in q._events]
+        if q._in_flight:
+            oldest = min(q._in_flight.values())
+            assert seqs == list(range(oldest, q._next_seq))
+        else:
+            assert not seqs
+    assert len(ops) == 8 and q.trim_scans > 0   # every operation ran
+    assert q.pending_counts() == ref.pending_counts()
+
+
+class Examined(deque):
+    """A deque that counts the entries its owner looks at or drops."""
+
+    examined = 0
+
+    def __getitem__(self, i):
+        self.examined += 1
+        return super().__getitem__(i)
+
+    def popleft(self):
+        self.examined += 1
+        return super().popleft()
+
+    def __iter__(self):
+        for entry in super().__iter__():
+            self.examined += 1
+            yield entry
+
+    def clear(self):
+        self.examined += len(self)
+        super().clear()
+
+
+class NeverWalked(dict):
+    """The in-flight set answers by uid only."""
+
+    def _walked(self, *a):
+        raise AssertionError("the in-flight set was iterated")
+
+    __iter__ = keys = values = items = _walked
+
+
+def test_done_examines_each_entry_a_bounded_number_of_times():
+    """20,000 pods in flight at 20,000 distinct starts, 40,000 events,
+    done() in shuffled order: the log entries and start markers the queue
+    looks at stay within a small multiple of events + pods (a min() over
+    the in-flight set and a rebuilt log per done() would look at over
+    10^8). A count of work, not a wall-clock time."""
+    pods_n, events_n = 20_000, 40_000
+    q, _ = mkq()
+    q._events, q._starts = Examined(), Examined()
+    q._in_flight = NeverWalked()
+    uids = []
+    for i in range(pods_n):
+        q.add(mkpod(f"p{i}"))
+        uids.append(q.pop().uid)
+        for _ in range(events_n // pods_n):
+            q.move_all_to_active_or_backoff(POD_DELETE)
+    assert q.event_log_len() == events_n and len(q._starts) == pods_n
+    random.Random(26).shuffle(uids)
+    for uid in uids:
+        q.done(uid)
+        assert len(q._starts) <= len(q._events) + 1
+    assert q.event_log_len() == 0 and not q._starts
+    assert q.trim_calls == pods_n and q.events_high_water == events_n
+    examined = q._events.examined + q._starts.examined
+    assert examined <= 6 * (events_n + pods_n), examined
+
+
 # suite-tier discipline (tests/test_markers.py): area marker
-import pytest  # noqa: E402
 pytestmark = pytest.mark.core

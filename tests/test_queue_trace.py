@@ -1,6 +1,7 @@
 """What the queue and the collector guard count about themselves, and how
-the flight recorder shows it: PriorityQueue._trim_events (calls, scans,
-scan seconds, the event log's high-water length -> the queue_done view and
+the flight recorder shows it: PriorityQueue._trim_events (calls, calls that
+dropped entries from the log's head, the seconds in those, the event log's
+high-water length -> the queue_done view and
 the two scheduler_queue_event_* metrics) and utils/gcguard's gc.callbacks
 hook (scheduler_gc_pause_seconds{generation}, the gc_pause view)."""
 
@@ -36,47 +37,125 @@ class Ticking:
         return self.t
 
 
-def _in_flight(q, n):
-    for i in range(n):
+# pod i is popped after STARTS[i] events and LOGGED events arrive in all:
+# three pods in flight at distinct start seqs, the log 15 long
+STARTS = (0, 5, 12)
+LOGGED = 15
+
+
+def _staggered(q):
+    pods, seen = [], 0
+    for i, start in enumerate(STARTS):
+        for _ in range(start - seen):
+            q.move_all_to_active_or_backoff(POD_DELETE)
+        seen = start
         q.add(mkpod(f"p{i}"))
-    return [q.pop() for _ in range(n)]
+        pods.append(q.pop())
+    for _ in range(LOGGED - seen):
+        q.move_all_to_active_or_backoff(POD_DELETE)
+    return pods
 
 
-@pytest.mark.parametrize("events, in_flight, scans, high_water", [
-    (8200, 3, 1, 8200),     # past 8,192 with pods still in flight: a scan
-    (8192, 3, 0, 8192),     # at the mark: none yet
-    (9000, 1, 0, 9000),     # the last pod leaves: the log clears unscanned
-    (0, 2, 0, 0),
+@pytest.mark.parametrize("order", [
+    (0, 1, 2),      # oldest first: every done() but the last drops its span
+    (0, 2, 1),
+    (1, 0, 2),      # a younger pod first: nothing to drop until the oldest
+    (1, 2, 0),      # leaves, then the log jumps to the oldest survivor
+    (2, 0, 1),
+    (2, 1, 0),      # youngest first: the log waits whole for the oldest
 ])
-def test_trim_counts_at_the_queue_boundary(events, in_flight, scans,
-                                           high_water):
-    q, _clock = mkq(clock=Ticking())
-    pods = _in_flight(q, in_flight)
-    for _ in range(events):
+def test_done_trims_the_log_to_the_oldest_survivor(order):
+    """Whatever order done() arrives in, the log starts at the oldest
+    start seq still in flight; a done() that moves nothing reads no clock;
+    the last pod out leaves it empty through the unclocked clear."""
+    clock = Ticking()
+    q, _ = mkq(clock=clock)
+    pods = _staggered(q)
+    assert q.event_log_len() == LOGGED
+    left = set(range(len(STARTS)))
+    entries, scans = LOGGED, 0
+    for n, i in enumerate(order, 1):
+        before = clock.t
+        q.done(pods[i].uid)
+        left.discard(i)
+        want = LOGGED - min(STARTS[j] for j in left) if left else 0
+        walked = bool(left) and want < entries
+        scans += walked
+        entries = want
+        st = q.trim_stats()
+        assert st["entries"] == want
+        assert [e[0] for e in q._events] == list(range(LOGGED - want, LOGGED))
+        assert st["trim_calls"] == n and st["trim_scans"] == scans
+        # the clock is read twice by a call that walked the head, else not
+        assert clock.t - before == pytest.approx(0.002 if walked else 0.0)
+        assert st["trim_scan_s"] == pytest.approx(0.001 * scans)
+        assert st["high_water"] == LOGGED
+    assert q.in_flight_count() == 0 and not q._starts \
+        and not q._start_holders
+
+
+@pytest.mark.parametrize("first", [0, 1])
+def test_pods_popped_together_hold_one_start(first):
+    """A pop_batch shares one start seq: the log moves only when the last
+    of the batch is done."""
+    clock = Ticking()
+    q, _ = mkq(clock=clock)
+    for name in ("a", "b"):
+        q.add(mkpod(name))
+    batch = q.pop_batch(2)
+    for _ in range(4):
         q.move_all_to_active_or_backoff(POD_DELETE)
+    q.add(mkpod("c"))
+    q.pop()
+    for _ in range(2):
+        q.move_all_to_active_or_backoff(POD_DELETE)
+    assert len(q._starts) == 2
+    before = clock.t
+    q.done(batch[first].uid)
+    assert q.event_log_len() == 6 and q.trim_scans == 0
+    assert clock.t == before
+    q.done(batch[1 - first].uid)
+    assert q.event_log_len() == 2 and q.trim_scans == 1
+    assert [e[0] for e in q._events] == [4, 5]
+
+
+@pytest.mark.parametrize("events_after, high_water", [
+    (0, 15),        # nothing more arrives: the length before the trim
+    (4, 15),        # 10 kept + 4: still under the longest it was
+    (9, 19),        # grows past it with no done() in between: still seen
+])
+def test_high_water_is_the_longest_the_log_ever_was(events_after,
+                                                    high_water):
+    q, _clock = mkq(clock=Ticking())
+    pods = _staggered(q)
     q.done(pods[0].uid)
+    assert q.event_log_len() == LOGGED - STARTS[1]
+    for _ in range(events_after):
+        q.move_all_to_active_or_backoff(POD_DELETE)
+    assert q.trim_stats()["high_water"] == high_water
+    q.done(pods[2].uid)
+    q.done(pods[1].uid)
     st = q.trim_stats()
-    assert st["trim_calls"] == 1
-    assert st["trim_scans"] == scans
-    assert st["high_water"] == high_water
-    assert (st["trim_scan_s"] > 0) == bool(scans)
-    if in_flight == 1:
-        assert st["entries"] == 0
+    assert st["entries"] == 0 and st["high_water"] == high_water
 
 
-def test_trim_scan_seconds_accumulate_only_while_scanning():
+def test_a_requeued_pod_restarts_at_its_new_pop():
+    """add_unschedulable_if_not_present releases the pod's start like
+    done(); popped again, the pod holds the seq of its new pop and the
+    log no longer waits for its old one."""
     q, _clock = mkq(clock=Ticking())
-    pods = _in_flight(q, 3)
-    for _ in range(8300):
-        q.move_all_to_active_or_backoff(POD_DELETE)
-    q.done(pods[0].uid)
-    q.done(pods[1].uid)                  # still past the mark: scans again
-    two = q.trim_scan_s
-    assert q.trim_scans == 2 and two == pytest.approx(0.002)
-    q.done(pods[2].uid)                  # empty in-flight set: clear, no scan
-    assert q.trim_scans == 2 and q.trim_scan_s == two
-    assert q.trim_calls == 3 and q.event_log_len() == 0
-    assert q.events_high_water == 8300
+    pods = _staggered(q)
+    pods[0].unschedulable_plugins = {"NoSuchEvent"}
+    q.add_unschedulable_if_not_present(pods[0])     # no registration: any
+    assert q.pending_counts()["active"] == 1        # logged event requeues
+    assert q.event_log_len() == LOGGED - STARTS[1]
+    again = q.pop()
+    assert again is pods[0] and q._in_flight[again.uid] == LOGGED
+    q.done(pods[1].uid)
+    assert q.event_log_len() == LOGGED - STARTS[2]
+    q.done(pods[2].uid)
+    assert q.event_log_len() == 0 and q.in_flight_count() == 1
+    assert q.trim_scans == 3 and q.trim_calls == 3
 
 
 def test_scheduler_reports_queue_done_with_every_drain():
