@@ -202,10 +202,20 @@ def check_device(chips: int, rehearse: bool) -> dict:
             "count": len(devs)}
 
 
-def build_cluster(cfg: dict, seed: int, fault=None):
-    """Hub + production Scheduler, nodes in the seed's order, init pods
-    already bound on the seed's nodes. `fault` is a module of
+def init_groups(cfg: dict) -> list[dict]:
+    """The configuration's init pods as groups {"count", "template"}, in
+    the order they are created; one group may stand as a dictionary."""
+    init = cfg["init_pods"]
+    return [init] if isinstance(init, dict) else list(init)
+
+
+def build_cluster(cfg: dict, seed: int, fault=None, pod_templates=()):
+    """Hub + production Scheduler, nodes in the seed's order, the
+    namespaces that the init pods' templates and `pod_templates` (the
+    mix's) name, the init pods group by group, already bound on the seed's
+    nodes in one continued round over them. `fault` is a module of
     benchmark/faults/, for the control. Returns (hub, sched, token)."""
+    from kubernetes_tpu.api.objects import Namespace, ObjectMeta
     from kubernetes_tpu.config.types import default_config
     from kubernetes_tpu.hub import Hub
     from kubernetes_tpu.ops.features import Capacities
@@ -233,14 +243,23 @@ def build_cluster(cfg: dict, seed: int, fault=None):
     rng.shuffle(order)
     for i in order:
         hub.create_node(nodes[i])
-    init = cfg["init_pods"]
-    maker = objects.PodMaker(objects.load_template(init["template"]))
+    groups = [(int(g["count"]), objects.load_template(g["template"]))
+              for g in init_groups(cfg)]
+    spaces = {ns for tmpl in [*(t for _n, t in groups), *pod_templates]
+              for ns in objects.template_namespaces(tmpl)}
+    for ns in sorted(spaces):
+        hub.create_namespace(Namespace(
+            metadata=ObjectMeta(name=ns, uid=f"ns-{ns}")))
     homes = list(range(n_nodes))
     rng.shuffle(homes)
-    for i in range(int(init["count"])):
-        hub.create_pod(maker.make(
-            f"init-{token}-{i}",
-            node_name=nodes[homes[i % n_nodes]].metadata.name))
+    made = 0
+    for count, tmpl in groups:
+        maker = objects.PodMaker(tmpl)
+        for i in range(made, made + count):
+            hub.create_pod(maker.make(
+                f"init-{token}-{i}",
+                node_name=nodes[homes[i % n_nodes]].metadata.name))
+        made += count
     return hub, sched, token
 
 
@@ -288,7 +307,8 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
     check_tmpl = objects.load_template(mix["pod_template"])
     pod_tmpl = objects.load_template(fault_mod.pod_template(mix)) \
         if hasattr(fault_mod, "pod_template") else check_tmpl
-    hub, sched, token = build_cluster(cfg, seed, fault_mod)
+    hub, sched, token = build_cluster(cfg, seed, fault_mod,
+                                      [check_tmpl, pod_tmpl])
     t_cluster = clock()
     maker = objects.PodMaker(pod_tmpl)
     watcher = BindWatcher(clock)
@@ -439,6 +459,10 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
             if t0 <= d < t1 and u in feeder.sent)
         values["bind_p95_ms"] = stats.percentile(samples, 95)
         values["bind_p50_ms"] = stats.percentile(samples, 50)
+        # completed: pods due in the window whose bind landed inside it
+        values["pods_per_s"] = stats.rate_in_window(
+            [watcher.first[u] for u, d in feeder.due.items()
+             if t0 <= d < t1 and u in watcher.first], t0, seconds)
         depth = feeder.depth_samples
         in_win = [(o, d) for o, d in depth if o >= 0]
         period = float(mix["burst_period_s"])

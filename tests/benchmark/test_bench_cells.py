@@ -101,6 +101,9 @@ def test_rehearsal_command_prints_the_contracts_last_line():
     assert list(line) == KEYS
     assert line["correct"] is True
     assert all(k.endswith(cell.NOT_DEVICE) for k in line["metrics"])
+    assert {"bind_p50_ms" + cell.NOT_DEVICE, "setup_s" + cell.NOT_DEVICE} \
+        <= set(line["metrics"])
+    assert "pods_per_s" + cell.NOT_DEVICE not in line["metrics"]
     assert set(line["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     # each number compared, beside its limit, closes standard error
@@ -110,10 +113,10 @@ def test_rehearsal_command_prints_the_contracts_last_line():
                for ln in p.stderr.splitlines()) == 1
 
 
-def test_a_toy_config_mix_cell_check_and_layer_metric_are_new_files_only(
-        tmp_path):
-    """A later PR adds entries and files and edits none: copy the benchmark
-    as it stands, add a toy of each kind beside it, run the toy cell."""
+def _toy_checkout(tmp_path):
+    """The benchmark as it stands, copied, with a toy of each kind beside
+    it: a configuration, templates, mixes, a check, a per-layer metric and
+    three cells. Returns (root, the copied files' bytes)."""
     root = tmp_path / "checkout"
     shutil.copytree(os.path.join(REPO, "benchmark"), root / "benchmark",
                     ignore=shutil.ignore_patterns("__pycache__"))
@@ -141,36 +144,128 @@ def test_a_toy_config_mix_cell_check_and_layer_metric_are_new_files_only(
         "grace_seconds": 60.0, "checks": []}))
     (root / "benchmark/layer_metrics/toy.launches.py").write_text(
         "def read(obs):\n    return float(obs['launches']) or None\n")
-    manifest["configs"].append({
-        "name": "toy-2zone", "source": "a toy for the tests",
-        "file": "benchmark/configs/toy-2zone.json", "reduced": [],
-        "why": "toy"})
-    manifest["workloads"].append({
-        "name": "toy.cell", "config": "toy-2zone", "traffic": "toy-mix",
-        "chips": 1, "why": "toy"})
+    # upstream's other pod shapes: two init groups, the second and the
+    # measured pods in a namespace of their own, with a priority and a
+    # required affinity term over two namespaces
+    (root / "benchmark/templates/pod-toy-ns.json").write_text(json.dumps({
+        "kind": "pod", "requests": {"cpu": "10m", "memory": "10Mi"},
+        "labels": {"toy": "yes"}, "namespace": "toy-ns", "priority": 5,
+        "pod_affinity": {"required": [{
+            "topology_key": "topology.kubernetes.io/zone",
+            "match_labels": {"toy": "yes"},
+            "namespaces": ["toy-ns", "toy-other"]}]}}))
+    groups = dict(toy, name="toy-groups", init_pods=[
+        {"count": 200, "template": "pod-toy"},
+        {"count": 250, "template": "pod-toy-ns"}])
+    groups["checks"] = toy["checks"] + ["toy_groups"]
+    (root / "benchmark/configs/toy-groups.json").write_text(
+        json.dumps(groups))
+    (root / "benchmark/checks/toy_groups.py").write_text(
+        "import collections\n"
+        "def check(end):\n"
+        "    offered = set(end.offered)\n"
+        "    mine = [p for p in end.bound if p.metadata.uid in offered]\n"
+        "    init = [p for p in end.bound\n"
+        "            if p.metadata.name.startswith('init-')]\n"
+        "    per_node = collections.Counter(p.spec.node_name for p in init)\n"
+        "    spaces = {n.metadata.name for n in end.hub.list_namespaces()}\n"
+        "    return {\n"
+        "        'toy_namespaces_missing':\n"
+        "            len({'toy-ns', 'toy-other'} - spaces),\n"
+        "        'toy_init_pods_missing': abs(450 - len(init)) + abs(250 - sum(\n"
+        "            p.metadata.namespace == 'toy-ns' for p in init)),\n"
+        "        # one continued round: 450 pods over 300 nodes are two on\n"
+        "        # 150 nodes and one on the rest, not three anywhere\n"
+        "        'toy_round_restarted': sum(\n"
+        "            n > 2 for n in per_node.values())\n"
+        "            + abs(300 - len(per_node)),\n"
+        "        'toy_pod_shape_lost': sum(\n"
+        "            p.metadata.namespace != 'toy-ns' or p.spec.priority != 5\n"
+        "            or not p.spec.affinity.pod_affinity.required\n"
+        "            for p in mine) + (not mine)}\n")
+    (root / "benchmark/traffic/toy-ns-mix.json").write_text(json.dumps({
+        "kind": "backlog", "pod_template": "pod-toy-ns", "depth": 96,
+        "slab": 32, "warm_pods_batches": 1, "warm_seconds": 0.2,
+        "grace_seconds": 60.0, "checks": []}))
+    # an arrivals mix judged on the pods it completes: steady, no bursts
+    (root / "benchmark/traffic/toy-steady.json").write_text(json.dumps({
+        "kind": "arrivals", "pod_template": "pod-toy", "base_rate": 100,
+        "group_ms": 100, "burst_pods": 0, "burst_period_s": 1.0,
+        "warm_periods": 1, "prewarm_pods": 8, "grace_seconds": 60.0,
+        "checks": []}))
+    for name in ("toy-2zone", "toy-groups"):
+        manifest["configs"].append({
+            "name": name, "source": "a toy for the tests",
+            "file": f"benchmark/configs/{name}.json", "reduced": [],
+            "why": "toy"})
+    manifest["workloads"] += [
+        {"name": "toy.cell", "config": "toy-2zone", "traffic": "toy-mix",
+         "chips": 1, "why": "toy"},
+        {"name": "toy.groups", "config": "toy-groups",
+         "traffic": "toy-ns-mix", "chips": 1, "why": "toy"},
+        {"name": "toy.steady", "config": "toy-2zone",
+         "traffic": "toy-steady", "chips": 1, "why": "toy"}]
     for m in manifest["end_to_end"]:
         if m["name"] == "pods_per_s":
-            m["workloads"].append("toy.cell")
+            m["workloads"] += ["toy.cell", "toy.groups", "toy.steady"]
     manifest["per_layer"].append({
         "name": "toy.launches", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "scheduling loop",
-        "moves": "pods_per_s", "workloads": ["toy.cell"]})
+        "moves": "pods_per_s",
+        "workloads": ["toy.cell", "toy.groups", "toy.steady"]})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
+    return root, before
+
+
+def _rehearse(root, workload, trace):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
-    lines = []
-    for trace in ("0", "1"):
-        p = subprocess.run(
-            [sys.executable, str(root / "benchmark/run.py"), "--workload",
-             "toy.cell", "--seed", "5", "--seconds", "1", "--trace", trace,
-             "--rehearse"],
-            cwd=root, env=env, capture_output=True, text=True, timeout=600)
-        assert p.returncode == 0, p.stderr[-3000:]
-        lines.append(json.loads(p.stdout.strip().splitlines()[-1]))
-    assert lines[0]["compared"]["unbound"]["value"] == 0, lines[0]
-    assert lines[0]["compared"]["toy_label_missing"] == {"value": 0,
-                                                         "limit": 0}
-    assert lines[0]["correct"] is True
-    assert "pods_per_s" + cell.NOT_DEVICE in lines[0]["metrics"]
-    assert lines[1]["metrics"]["toy.launches" + cell.NOT_DEVICE]["value"] > 0
+    p = subprocess.run(
+        [sys.executable, str(root / "benchmark/run.py"), "--workload",
+         workload, "--seed", "5", "--seconds", "1", "--trace", trace,
+         "--rehearse"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("workload", ["toy.cell", "toy.groups",
+                                      "toy.steady"])
+def test_a_toy_config_mix_cell_check_and_layer_metric_are_new_files_only(
+        tmp_path, workload):
+    """A later PR adds entries and files and edits none: copy the benchmark
+    as it stands, add a toy of each kind beside it, run the toy cells. One
+    is a plain backlog; one has two init groups, namespaces, a priority and
+    a required affinity term; one is an arrivals mix listed under
+    `pods_per_s`."""
+    root, before = _toy_checkout(tmp_path)
+    line = _rehearse(root, workload, "0")
+    assert line["compared"]["unbound"]["value"] == 0, line
+    assert line["compared"]["toy_label_missing"] == {"value": 0, "limit": 0}
+    assert line["correct"] is True, line["compared"]
+    assert line["metrics"]["pods_per_s" + cell.NOT_DEVICE]["value"] > 0
+    if workload == "toy.groups":
+        assert {"toy_namespaces_missing", "toy_init_pods_missing",
+                "toy_round_restarted", "toy_pod_shape_lost"} \
+            <= set(line["compared"])
+    elif workload == "toy.steady":
+        # 100 pods/s offered for 1 s, all bound inside the window or just
+        # past it: the rate is of the pods due in the window, no others
+        assert 0 < line["metrics"]["pods_per_s" + cell.NOT_DEVICE]["value"] \
+            <= 100.0
+        assert line["attempted"] == 200        # one warm period, the window
+    else:
+        traced = _rehearse(root, workload, "1")
+        assert traced["metrics"]["toy.launches" + cell.NOT_DEVICE][
+            "value"] > 0
     after = {p: p.read_bytes() for p in before}
     assert after == before
+
+
+def test_arrivals_cell_reports_the_metric_names_it_reported():
+    """`pods_per_s` is computed for an arrivals mix too; which cells print
+    it stays what the metric's `workloads` list says."""
+    manifest = cell.load_manifest(REPO)
+    names = [m["name"] for m in cell.metrics_of(
+        manifest, "end_to_end", "basic-5k.arrivals")]
+    assert names[:2] == ["bind_p50_ms", "setup_s"]
+    assert "pods_per_s" not in names

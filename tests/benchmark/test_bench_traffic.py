@@ -65,6 +65,20 @@ def test_arrival_schedule_counts_bursts_and_groups():
                                  30)
 
 
+@pytest.mark.parametrize("rate,group_ms,seconds", [(50, 100, 30),
+                                                   (400, 100, 30),
+                                                   (1000, 50, 10)])
+def test_no_burst_pods_is_a_steady_schedule(rate, group_ms, seconds):
+    mix = {**traffic.load_mix("arrivals"), "base_rate": rate,
+           "group_ms": group_ms, "burst_pods": 0}
+    sched = traffic.arrival_schedule(mix, seconds)
+    per_group = rate * group_ms // 1000
+    assert {n for _o, n in sched} == {per_group}
+    assert sum(n for o, n in sched if o >= 0) == rate * seconds
+    gaps = {round(b[0] - a[0], 9) for a, b in zip(sched, sched[1:])}
+    assert gaps == {group_ms / 1000.0}
+
+
 def test_each_pod_is_timed_from_its_due_instant_not_from_its_creation():
     _s, gen, _h = _generated(5)
     # the generator ran 1,000 s late on its clock: lateness shows there,
