@@ -1,23 +1,34 @@
-"""Every cell end to end at its rehearsal size on the CPU, in this process:
-the same path as a chip run, without the look for a chip. One file, so that
-one worker compiles each tiny program once."""
+"""Every cell of BENCHMARK.json end to end at its rehearsal size on the CPU,
+in this process: the same path as a chip run, without the look for a chip.
+One file, so that one worker compiles each tiny program once. Then the
+benchmark copied with one addition of every kind a later PR makes: the toy
+cells run, and every invariant of bench_invariants.py holds on the copy."""
 
 import json
 import os
-import shutil
 import subprocess
 import sys
+import types
 
 import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_invariants as inv  # noqa: E402
 from benchmark import cell  # noqa: E402
 
-CELLS = ["basic-5k.backlog", "basic-5k.arrivals", "topology-5k.required",
-         "topology-5k.preferred"]
+# read at collection: a cell that a later PR adds is rehearsed without that
+# PR writing the test
+CELLS = [w["name"] for w in cell.load_manifest(REPO)["workloads"]]
+# numbers a cell is held to exactly when its configuration or its mix names
+# the check that gives them
+GIVEN_BY = {"skew_excess": "required_skew",
+            "required_rules_missing": "serial_scan",
+            "scan_launches_missing": "serial_scan",
+            "soft_launches_missing": "soft_auction"}
 KEYS = ["correct", "attempted", "failed", "metrics", "device", "compared"]
 
 
@@ -26,8 +37,24 @@ def _quiet(_msg):
 
 
 @pytest.mark.parametrize("workload", CELLS)
-def test_cell_rehearses_correct_and_reports_its_end_to_end_metrics(workload):
+def test_cell_rehearses_correct_and_reports_its_end_to_end_metrics(
+        workload, monkeypatch):
     manifest = cell.load_manifest(REPO)
+    gave = {}       # check -> the numbers it returned, in the order called
+    load = cell.compare_mod.load_by_name
+
+    def recording(kind, name, *root):
+        mod = load(kind, name, *root)
+        if kind != "checks":
+            return mod
+
+        def check(end):
+            assert name not in gave, f"check {name} ran twice"
+            gave[name] = mod.check(end)
+            return gave[name]
+        return types.SimpleNamespace(check=check)
+
+    monkeypatch.setattr(cell.compare_mod, "load_by_name", recording)
     r = cell.run_cell(workload, 3_000_000_017, 2, False, rehearse=True,
                       log=_quiet)
     assert r["correct"] is True, r["compared"]
@@ -42,16 +69,19 @@ def test_cell_rehearses_correct_and_reports_its_end_to_end_metrics(workload):
     assert all(set(c) == {"value", "limit"} for c in r["compared"].values())
     assert {"unbound", "double_binds", "acknowledged_binds_missing",
             "overpacked_nodes", "left_device_path"} <= set(r["compared"])
-    # the required cell is held to its skew and its scan launches, the
-    # preferred cell to its soft launches, and neither to the other's
-    extra = set(r["compared"]) & {"skew_excess", "required_rules_missing",
-                                  "scan_launches_missing",
-                                  "soft_launches_missing"}
-    assert extra == {
-        "topology-5k.required": {"skew_excess", "required_rules_missing",
-                                 "scan_launches_missing"},
-        "topology-5k.preferred": {"skew_excess", "soft_launches_missing"},
-    }.get(workload, set())
+    # the checks that ran are those the cell's configuration and its mix
+    # name, each once; a number is compared exactly when the check that
+    # gives it is named, and no check takes another's number
+    w, entry = cell.find_cell(manifest, workload)
+    cfg = cell.load_config(entry, True, REPO)
+    mix = cell.traffic_mod.load_mix(w["traffic"], True)
+    named = list(dict.fromkeys(cfg["checks"] + mix.get("checks", [])))
+    assert list(gave) == named
+    numbers = [n for got in gave.values() for n in got]
+    assert len(numbers) == len(set(numbers)) and all(gave.values())
+    assert set(r["compared"]) == set(numbers)
+    for number, check in GIVEN_BY.items():
+        assert (number in r["compared"]) == (check in named), number
     json.dumps(r)
 
 
@@ -114,15 +144,19 @@ def test_rehearsal_command_prints_the_contracts_last_line():
 
 
 def _toy_checkout(tmp_path):
-    """The benchmark as it stands, copied, with a toy of each kind beside
-    it: a configuration, templates, mixes, a check, a per-layer metric and
-    three cells. Returns (root, the copied files' bytes)."""
+    """The benchmark as it stands, copied, with one addition of every kind
+    a later PR makes beside it: configurations (one with init groups on new
+    templates), templates, mixes (one on a template no cell used), checks,
+    a fault with its control's case, a per-layer metric appended with its
+    reader, and four cells, one of them under every `.drain` metric.
+    Returns (root, the copied files' bytes)."""
     root = tmp_path / "checkout"
-    shutil.copytree(os.path.join(REPO, "benchmark"), root / "benchmark",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = {p: p.read_bytes() for p in (root / "benchmark").rglob("*")
-              if p.is_file()}
-    manifest = cell.load_manifest(REPO)
+    manifest, before = inv.copy_benchmark(root)
+
+    def add(path, text):
+        assert not (root / path).exists(), f"{path}: not a toy's name"
+        (root / path).write_text(text)
+
     src = json.loads((root / "benchmark/configs/sched-perf-basic-5k.json")
                      .read_text())
     toy = dict(src, name="toy-2zone", source="a toy for the tests",
@@ -130,24 +164,24 @@ def _toy_checkout(tmp_path):
     toy["nodes"] = dict(toy["nodes"], zones=["a", "b"], count=300)
     toy["rehearse"] = {}
     toy["checks"] = src["checks"] + ["toy_labels"]
-    (root / "benchmark/checks/toy_labels.py").write_text(
+    add("benchmark/checks/toy_labels.py",
         "def check(end):\n    return {'toy_label_missing': sum(\n"
         "        p.metadata.labels.get('toy') != 'yes' for p in end.bound\n"
         "        if p.metadata.uid in set(end.offered))}\n")
-    (root / "benchmark/configs/toy-2zone.json").write_text(json.dumps(toy))
-    (root / "benchmark/templates/pod-toy.json").write_text(json.dumps({
+    add("benchmark/configs/toy-2zone.json", json.dumps(toy))
+    add("benchmark/templates/pod-toy.json", json.dumps({
         "kind": "pod", "requests": {"cpu": "10m", "memory": "10Mi"},
         "labels": {"toy": "yes"}, "spread": []}))
-    (root / "benchmark/traffic/toy-mix.json").write_text(json.dumps({
+    add("benchmark/traffic/toy-mix.json", json.dumps({
         "kind": "backlog", "pod_template": "pod-toy", "depth": 96,
         "slab": 32, "warm_pods_batches": 1, "warm_seconds": 0.2,
         "grace_seconds": 60.0, "checks": []}))
-    (root / "benchmark/layer_metrics/toy.launches.py").write_text(
+    add("benchmark/layer_metrics/toy.launches.py",
         "def read(obs):\n    return float(obs['launches']) or None\n")
     # upstream's other pod shapes: two init groups, the second and the
     # measured pods in a namespace of their own, with a priority and a
     # required affinity term over two namespaces
-    (root / "benchmark/templates/pod-toy-ns.json").write_text(json.dumps({
+    add("benchmark/templates/pod-toy-ns.json", json.dumps({
         "kind": "pod", "requests": {"cpu": "10m", "memory": "10Mi"},
         "labels": {"toy": "yes"}, "namespace": "toy-ns", "priority": 5,
         "pod_affinity": {"required": [{
@@ -158,9 +192,8 @@ def _toy_checkout(tmp_path):
         {"count": 200, "template": "pod-toy"},
         {"count": 250, "template": "pod-toy-ns"}])
     groups["checks"] = toy["checks"] + ["toy_groups"]
-    (root / "benchmark/configs/toy-groups.json").write_text(
-        json.dumps(groups))
-    (root / "benchmark/checks/toy_groups.py").write_text(
+    add("benchmark/configs/toy-groups.json", json.dumps(groups))
+    add("benchmark/checks/toy_groups.py",
         "import collections\n"
         "def check(end):\n"
         "    offered = set(end.offered)\n"
@@ -183,17 +216,47 @@ def _toy_checkout(tmp_path):
         "            p.metadata.namespace != 'toy-ns' or p.spec.priority != 5\n"
         "            or not p.spec.affinity.pod_affinity.required\n"
         "            for p in mine) + (not mine)}\n")
-    (root / "benchmark/traffic/toy-ns-mix.json").write_text(json.dumps({
+    add("benchmark/traffic/toy-ns-mix.json", json.dumps({
         "kind": "backlog", "pod_template": "pod-toy-ns", "depth": 96,
         "slab": 32, "warm_pods_batches": 1, "warm_seconds": 0.2,
         "grace_seconds": 60.0, "checks": []}))
     # an arrivals mix judged on the pods it completes: steady, no bursts
-    (root / "benchmark/traffic/toy-steady.json").write_text(json.dumps({
+    add("benchmark/traffic/toy-steady.json", json.dumps({
         "kind": "arrivals", "pod_template": "pod-toy", "base_rate": 100,
         "group_ms": 100, "burst_pods": 0, "burst_period_s": 1.0,
         "warm_periods": 1, "prewarm_pods": 8, "grace_seconds": 60.0,
         "checks": []}))
-    for name in ("toy-2zone", "toy-groups"):
+    # the next deployment's shape, SchedulingPodAffinity: one zone, init
+    # groups (a list) on a new `-init` sibling in sched-0, the measured pods
+    # of a template that no cell used, and a check of its own
+    measured = json.loads(
+        (root / "benchmark/templates/pod-with-pod-affinity.json").read_text())
+    add("benchmark/templates/pod-toy-affinity-init.json",
+        json.dumps(dict(measured, namespace="sched-0")))
+    affinity = dict(toy, name="toy-affinity", init_pods=[
+        {"count": 120, "template": "pod-toy-affinity-init"}])
+    affinity["nodes"] = dict(toy["nodes"], zones=["zone1"])
+    affinity["checks"] = src["checks"] + ["toy_affinity"]
+    add("benchmark/configs/toy-affinity.json", json.dumps(affinity))
+    add("benchmark/checks/toy_affinity.py",
+        "def check(end):\n"
+        "    offered = set(end.offered)\n"
+        "    mine = [p for p in end.bound if p.metadata.uid in offered]\n"
+        "    return {'toy_affinity_lost': sum(\n"
+        "        p.metadata.namespace != 'sched-1'\n"
+        "        or not p.spec.affinity.pod_affinity.required\n"
+        "        for p in mine) + (not mine)}\n")
+    add("benchmark/traffic/toy-affinity-mix.json", json.dumps({
+        "kind": "backlog", "pod_template": "pod-with-pod-affinity",
+        "depth": 96, "slab": 32, "warm_pods_batches": 1, "warm_seconds": 0.2,
+        "grace_seconds": 60.0, "checks": []}))
+    # a fault as a new file, its control's case as a new file
+    add("benchmark/faults/toy_unlabelled.py",
+        "def pod_template(mix):\n    return 'pod-default'\n")
+    add(os.path.join(inv.CONTROLS, "toy.json"), json.dumps([
+        {"cell": "toy.cell", "fault": "toy_unlabelled",
+         "caught_by": "toy_label_missing"}]))
+    for name in ("toy-2zone", "toy-groups", "toy-affinity"):
         manifest["configs"].append({
             "name": name, "source": "a toy for the tests",
             "file": f"benchmark/configs/{name}.json", "reduced": [],
@@ -204,43 +267,55 @@ def _toy_checkout(tmp_path):
         {"name": "toy.groups", "config": "toy-groups",
          "traffic": "toy-ns-mix", "chips": 1, "why": "toy"},
         {"name": "toy.steady", "config": "toy-2zone",
-         "traffic": "toy-steady", "chips": 1, "why": "toy"}]
+         "traffic": "toy-steady", "chips": 1, "why": "toy"},
+        {"name": "toy.affinity", "config": "toy-affinity",
+         "traffic": "toy-affinity-mix", "chips": 1, "why": "toy"}]
+    toys = [w["name"] for w in manifest["workloads"]
+            if w["name"].startswith("toy.")]
     for m in manifest["end_to_end"]:
         if m["name"] == "pods_per_s":
-            m["workloads"] += ["toy.cell", "toy.groups", "toy.steady"]
+            m["workloads"] += toys
+    for m in manifest["per_layer"]:     # every `.drain` reader, PR 25's too
+        if m["name"].endswith(".drain"):
+            m["workloads"].append("toy.affinity")
     manifest["per_layer"].append({
         "name": "toy.launches", "unit": "count", "better": "higher",
         "source": "program_counter", "layer": "scheduling loop",
-        "moves": "pods_per_s",
-        "workloads": ["toy.cell", "toy.groups", "toy.steady"]})
+        "moves": "pods_per_s", "workloads": toys})
     (root / "BENCHMARK.json").write_text(json.dumps(manifest))
     return root, before
 
 
-def _rehearse(root, workload, trace):
+def _run(root, script, *args):
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
-    p = subprocess.run(
-        [sys.executable, str(root / "benchmark/run.py"), "--workload",
-         workload, "--seed", "5", "--seconds", "1", "--trace", trace,
-         "--rehearse"],
+    return subprocess.run(
+        [sys.executable, str(root / "benchmark" / script), *args,
+         "--seed", "5", "--seconds", "1", "--rehearse"],
         cwd=root, env=env, capture_output=True, text=True, timeout=600)
+
+
+def _rehearse(root, workload, trace):
+    p = _run(root, "run.py", "--workload", workload, "--trace", trace)
     assert p.returncode == 0, p.stderr[-3000:]
     return json.loads(p.stdout.strip().splitlines()[-1])
 
 
-@pytest.mark.parametrize("workload", ["toy.cell", "toy.groups",
-                                      "toy.steady"])
+@pytest.mark.parametrize("workload", ["toy.cell", "toy.groups", "toy.steady",
+                                      "toy.affinity"])
 def test_a_toy_config_mix_cell_check_and_layer_metric_are_new_files_only(
         tmp_path, workload):
     """A later PR adds entries and files and edits none: copy the benchmark
     as it stands, add a toy of each kind beside it, run the toy cells. One
-    is a plain backlog; one has two init groups, namespaces, a priority and
-    a required affinity term; one is an arrivals mix listed under
-    `pods_per_s`."""
+    is a plain backlog, with a fault of its own planted from its case file;
+    one has two init groups, namespaces, a priority and a required affinity
+    term; one is an arrivals mix listed under `pods_per_s`; one has the next
+    deployment's shape and reports every `.drain` metric."""
     root, before = _toy_checkout(tmp_path)
     line = _rehearse(root, workload, "0")
     assert line["compared"]["unbound"]["value"] == 0, line
-    assert line["compared"]["toy_label_missing"] == {"value": 0, "limit": 0}
+    own = "toy_affinity_lost" if workload == "toy.affinity" \
+        else "toy_label_missing"
+    assert line["compared"][own] == {"value": 0, "limit": 0}
     assert line["correct"] is True, line["compared"]
     assert line["metrics"]["pods_per_s" + cell.NOT_DEVICE]["value"] > 0
     if workload == "toy.groups":
@@ -253,12 +328,44 @@ def test_a_toy_config_mix_cell_check_and_layer_metric_are_new_files_only(
         assert 0 < line["metrics"]["pods_per_s" + cell.NOT_DEVICE]["value"] \
             <= 100.0
         assert line["attempted"] == 200        # one warm period, the window
-    else:
+    elif workload == "toy.affinity":
         traced = _rehearse(root, workload, "1")
+        for name in ("toy.launches", "queue.done_ms_per_kpod.drain",
+                     "mirror.snapshot_cache_ms_per_kpod.drain",
+                     "mirror.sync_ms_per_kpod.drain"):
+            assert traced["metrics"][name + cell.NOT_DEVICE]["value"] >= 0
         assert traced["metrics"]["toy.launches" + cell.NOT_DEVICE][
             "value"] > 0
-    after = {p: p.read_bytes() for p in before}
-    assert after == before
+    else:
+        (case,) = [c for c in inv.load_controls(root)
+                   if c["cell"] == workload]
+        p = _run(root, "control.py", "--workload", workload, "--fault",
+                 case["fault"])
+        assert p.returncode == 0, p.stderr[-3000:]
+        verdict = json.loads(p.stdout.strip().splitlines()[-1])
+        assert verdict["correct"] is False
+        assert verdict["failed_by"][case["caught_by"]] > 0
+    assert inv.unchanged(before)
+
+
+@pytest.fixture(scope="module")
+def toy_checkout(tmp_path_factory):
+    return _toy_checkout(tmp_path_factory.mktemp("invariants"))
+
+
+@pytest.mark.parametrize("invariant", inv.INVARIANTS,
+                         ids=lambda f: f.__name__)
+def test_every_invariant_holds_with_one_addition_of_every_kind(
+        toy_checkout, invariant):
+    """What the tests hold of the repo, they hold of the copy with the
+    additions too: a test that pins today's inventory fails here, in the PR
+    that writes it, not in the PR that brings the next deployment."""
+    root, before = toy_checkout
+    manifest = cell.load_manifest(root)
+    added = {w["name"] for w in manifest["workloads"]} - set(CELLS)
+    assert added == {"toy.cell", "toy.groups", "toy.steady", "toy.affinity"}
+    invariant(manifest, root)
+    assert inv.unchanged(before)
 
 
 def test_arrivals_cell_reports_the_metric_names_it_reported():

@@ -1,8 +1,10 @@
 """The comparison has to fail what it should: the plain reference on planted
 end states, and a whole rehearsal run (`rehearse=True`: the tiny size on
 the CPU, metrics renamed) with the timed path broken underneath, once for
-each fault a cell can have."""
+each fault a cell can have. The cases are data: every file of
+tests/benchmark/controls/ is a list of them, and a later PR adds a file."""
 
+import json
 import os
 import sys
 
@@ -11,8 +13,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from benchmark import cell, compare, reference  # noqa: E402
+import bench_invariants as inv  # noqa: E402
+from benchmark import cell, reference  # noqa: E402
 
 NODES = [("n0", "4", "32Gi", "110"), ("n1", "4", "32Gi", "110")]
 
@@ -64,49 +68,80 @@ def test_skew_is_held_over_every_domain_even_an_empty_one():
         dict(rule, when_unsatisfiable="ScheduleAnyway")]}) == []
 
 
-FAULTS = [
-    # (cell, fault planted under the timed path, a number that must catch it)
-    # state left unchanged; half of each batch left out
-    ("basic-5k.backlog", "no_bind", "acknowledged_binds_missing"),
-    ("basic-5k.backlog", "half_batch", "acknowledged_binds_missing"),
-    ("basic-5k.backlog", "overpack", "overpacked_nodes"),   # answer altered
-    ("basic-5k.backlog", "move_bound", "double_binds"),
-    ("basic-5k.backlog", "device_fault", "left_device_path"),
-    ("basic-5k.backlog", "lost_in_queue", "unbound"),      # lost, never bound
-    ("topology-5k.preferred", "lost_in_queue", "unbound"),
-    ("basic-5k.arrivals", "drop_bind", "unbound"),
-    ("basic-5k.arrivals", "overpack", "overpacked_nodes"),
-    ("topology-5k.required", "skew", "skew_excess"),
-    ("topology-5k.required", "plain_pods", "scan_launches_missing"),
-    ("topology-5k.preferred", "plain_pods", "soft_launches_missing"),
-    ("topology-5k.preferred", "half_batch", "acknowledged_binds_missing"),
-]
+# (cell, fault planted under the timed path, a number that must catch it):
+# a state left unchanged, half of each batch left out, an answer altered
+# where it is produced, a pod lost and never bound
+CONTROLS = inv.load_controls()
 
 
-@pytest.mark.parametrize("workload,fault,caught_by", FAULTS)
-def test_a_run_with_the_timed_path_broken_is_not_correct(
-        workload, fault, caught_by, monkeypatch):
-    if fault in ("drop_bind", "lost_in_queue"):
-        # 1 in 997: give the tiny size enough pods to lose one
+@pytest.mark.parametrize("case", CONTROLS, ids=lambda c: "-".join(
+    (c["cell"], c["fault"], c["caught_by"])))
+def test_a_run_with_the_timed_path_broken_is_not_correct(case, monkeypatch):
+    if "mix" in case:
+        # a fault of 1 in 997: give the tiny size enough pods to lose one
         load = cell.traffic_mod.load_mix
         monkeypatch.setattr(
             cell.traffic_mod, "load_mix", lambda name, rehearse: dict(
-                load(name, rehearse), burst_pods=700, depth=1100))
-    r = cell.run_cell(workload, 41, 1, False, rehearse=True, fault=fault,
-                      log=lambda _m: None)
+                load(name, rehearse), **case["mix"]))
+    r = cell.run_cell(case["cell"], 41, 1, False, rehearse=True,
+                      fault=case["fault"], log=lambda _m: None)
     assert r["correct"] is False
-    c = r["compared"][caught_by]
+    assert case["caught_by"] in r["compared"], sorted(r["compared"])
+    c = r["compared"][case["caught_by"]]
     assert c["value"] > c["limit"], r["compared"]
-    if caught_by == "unbound":
+    if case["caught_by"] == "unbound":
         assert r["failed"] > 0
 
 
 def test_every_fault_file_is_planted_in_some_cell_and_every_check_is_a_file():
-    assert {f for _w, f, _c in FAULTS} == set(compare.names_in("faults"))
-    manifest = cell.load_manifest(REPO)
-    for w in manifest["workloads"]:
-        _c, entry = cell.find_cell(manifest, w["name"])
-        cfg = cell.load_config(entry, False, REPO)
-        mix = cell.traffic_mod.load_mix(w["traffic"])
-        for name in cfg["checks"] + mix.get("checks", []):
-            assert callable(compare.load_by_name("checks", name).check)
+    inv.every_fault_is_planted_and_every_check_is_a_file(
+        cell.load_manifest(REPO))
+
+
+def _orphan_fault(root):
+    (root / "benchmark/faults/orphan.py").write_text(
+        "def wrap_hub(hub, node_names, zone_of):\n    pass\n")
+
+
+def _fault_without_a_hook(root):
+    (root / "benchmark/faults/skew.py").write_text("def plant():\n    pass\n")
+
+
+def _case(**keys):
+    case = dict(CONTROLS[0], **keys)
+    return lambda root: (root / inv.CONTROLS / "more.json").write_text(
+        json.dumps([case]))
+
+
+def _unknown_check(root):
+    path = root / "benchmark/traffic/preferred.json"
+    mix = json.loads(path.read_text())
+    path.write_text(json.dumps(dict(mix, checks=["soft_auction", "gone"])))
+
+
+INVENTORY_BREACHES = {
+    "a fault file that no case plants": (AssertionError, _orphan_fault),
+    "a fault file with none of the three hooks":
+        (AssertionError, _fault_without_a_hook),
+    "a case for a cell that is not in the manifest":
+        (AssertionError, _case(cell="no-such.cell")),
+    "a case for a fault that is no file":
+        (AssertionError, _case(fault="no_such_fault")),
+    "a case that is there twice": (AssertionError, _case()),
+    "a case with a key that nothing reads":
+        (AssertionError, _case(fault="skew", seconds=9)),
+    "a mix names a check that is no file":
+        (FileNotFoundError, _unknown_check),
+}
+
+
+@pytest.mark.parametrize("breach", sorted(INVENTORY_BREACHES))
+def test_a_breach_of_the_controls_inventory_fails_the_invariant(
+        tmp_path, breach):
+    error, plant = INVENTORY_BREACHES[breach]
+    manifest, _before = inv.copy_benchmark(tmp_path)
+    inv.every_fault_is_planted_and_every_check_is_a_file(manifest, tmp_path)
+    plant(tmp_path)
+    with pytest.raises(error):
+        inv.every_fault_is_planted_and_every_check_is_a_file(manifest,
+                                                             tmp_path)

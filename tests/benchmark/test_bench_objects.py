@@ -1,8 +1,10 @@
 """The pod builder against the program's own transcriptions of upstream's
 templates, and against a copy of the builder as it stood before it learned
-namespace, priority and affinity. No JAX here."""
+namespace, priority and affinity; and every template that a cell names
+against what bench_invariants.py holds of it. No JAX here."""
 
 import copy
+import json
 import os
 import sys
 
@@ -11,8 +13,10 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
-from benchmark import objects  # noqa: E402
+import bench_invariants as inv  # noqa: E402
+from benchmark import cell, objects  # noqa: E402
 from kubernetes_tpu.perf import workloads  # noqa: E402
 
 # template -> the function of perf/workloads.py that transcribes its YAML
@@ -82,18 +86,62 @@ def test_new_template_builds_the_pod_the_program_transcribes(name):
     assert got.spec.node_name == ""
 
 
-def test_no_configuration_mix_or_cell_uses_the_new_templates_yet():
-    from benchmark import cell, traffic
+def test_every_template_a_cell_names_exists_and_builds():
+    inv.every_template_named_exists_and_builds(cell.load_manifest(REPO))
 
-    manifest = cell.load_manifest(REPO)
-    used = set()
-    for w in manifest["workloads"]:
-        used.add(traffic.load_mix(w["traffic"])["pod_template"])
-    for c in manifest["configs"]:
-        for rehearse in (False, True):
-            cfg = cell.load_config(c, rehearse, REPO)
-            used |= {g["template"] for g in cell.init_groups(cfg)}
-    assert used == set(BEFORE)
+
+def _edit_json(path, **keys):
+    with open(path) as f:
+        data = json.load(f)
+    with open(path, "w") as f:
+        json.dump(dict(data, **keys), f)
+
+
+TEMPLATE_BREACHES = {
+    "a mix names a template that does not exist": (
+        FileNotFoundError, "benchmark/traffic/required.json",
+        {"pod_template": "pod-gone"}),
+    "an init group names a template that does not exist": (
+        FileNotFoundError, "benchmark/configs/sched-perf-basic-5k.json",
+        {"init_pods": [{"count": 10, "template": "pod-default"},
+                       {"count": 10, "template": "pod-gone"}]}),
+    "a rehearsal size names a pod as its node template": (
+        AssertionError, "benchmark/configs/sched-perf-basic-5k.json",
+        {"rehearse": {"nodes": {"count": 8, "template": "pod-default"}}}),
+    "a mix offers a node": (
+        AssertionError, "benchmark/traffic/backlog.json",
+        {"pod_template": "node-default"}),
+    "a template carries a key the builder does not know": (
+        ValueError, "benchmark/templates/pod-default.json",
+        {"tolerations": []}),
+}
+
+
+@pytest.mark.parametrize("breach", sorted(TEMPLATE_BREACHES))
+def test_a_breach_among_the_templates_fails_the_invariant(tmp_path, breach):
+    error, path, keys = TEMPLATE_BREACHES[breach]
+    manifest, _before = inv.copy_benchmark(tmp_path)
+    inv.every_template_named_exists_and_builds(manifest, tmp_path)
+    _edit_json(tmp_path / path, **keys)
+    with pytest.raises(error):
+        inv.every_template_named_exists_and_builds(manifest, tmp_path)
+
+
+def test_a_namespace_that_nobody_creates_fails_the_invariant(
+        tmp_path, monkeypatch):
+    """The namespaces are read off the built pod, not through
+    objects.template_namespaces, which is what build_cluster creates them
+    from: one that the pod names and that function misses is caught."""
+    manifest, _before = inv.copy_benchmark(tmp_path)
+    tmpl = objects.load_template("pod-with-pod-affinity")
+    _edit_json(tmp_path / "benchmark/templates/pod-spread-required.json",
+               namespace="sched-1", pod_affinity=tmpl["pod_affinity"])
+    inv.every_template_named_exists_and_builds(manifest, tmp_path)
+    real = objects.template_namespaces
+    monkeypatch.setattr(objects, "template_namespaces", lambda t: [
+        ns for ns in real(t) if ns != "sched-0"])
+    with pytest.raises(AssertionError):
+        inv.every_template_named_exists_and_builds(manifest, tmp_path)
 
 
 def test_a_node_template_is_refused_by_the_pod_builder():

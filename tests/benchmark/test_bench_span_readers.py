@@ -13,7 +13,9 @@ import pytest
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_invariants as inv  # noqa: E402
 from benchmark import cell, objects  # noqa: E402
 
 TURN = ("maintenance", "lock_wait", "event_intake", "gc_sweep", "drain_tail")
@@ -57,27 +59,62 @@ def test_per_kpod_reader_has_nothing_to_read_without_binds(name):
 
 
 def test_new_readers_are_in_the_manifest_under_their_cells():
-    manifest = cell.load_manifest(REPO)
-    by_name = {m["name"]: m for m in manifest["per_layer"]}
-    drain = ["basic-5k.backlog", "topology-5k.required",
-             "topology-5k.preferred"]
-    want = {
-        "queue.done_ms_per_kpod.drain": ("queues", "pods_per_s", drain),
-        "mirror.snapshot_cache_ms_per_kpod.drain":
-            ("mirror / pack", "pods_per_s", drain[1:]),
-        "loop.idle_share.arrive":
-            ("scheduling loop", "bind_p50_ms", ["basic-5k.arrivals"]),
-        "loop.turn_overhead_ms_per_kpod.arrive":
-            ("scheduling loop", "bind_p50_ms", ["basic-5k.arrivals"]),
-        "loop.gc_ms_per_kpod.arrive":
-            ("scheduling loop", "bind_p50_ms", ["basic-5k.arrivals"]),
-    }
-    for name, (layer, moves, cells) in want.items():
-        m = by_name[name]
-        assert (m["layer"], m["moves"], m["workloads"]) == (layer, moves,
-                                                            cells)
-    # appended: what was there keeps its place
-    assert list(by_name)[-5:] == list(want)
+    """Each under its layer, moving its metric, with at least the cells it
+    came with at the head of its list; what was in `per_layer` keeps its
+    order. A new entry and a new cell's name are appended."""
+    inv.per_layer_entries_keep_their_order_and_their_cells(
+        cell.load_manifest(REPO))
+
+
+def _entry(manifest, name):
+    return next(m for m in manifest["per_layer"] if m["name"] == name)
+
+
+def _swap(manifest, i, j):
+    per = manifest["per_layer"]
+    per[i], per[j] = per[j], per[i]
+
+
+PER_LAYER_EDITS = {
+    # edit -> whether the invariant still holds after it
+    "a new entry is appended": (True, lambda m: m["per_layer"].append(dict(
+        _entry(m, "queue.done_ms_per_kpod.drain"), name="new.drain"))),
+    "a new entry goes between two that were there": (
+        True, lambda m: m["per_layer"].insert(3, dict(
+            _entry(m, "queue.done_ms_per_kpod.drain"), name="new.drain"))),
+    "a new cell is appended to a reader's list": (
+        True, lambda m: _entry(m, "mirror.snapshot_cache_ms_per_kpod.drain")[
+            "workloads"].append("a-later.cell")),
+    "a reader is moved to another layer": (
+        False, lambda m: _entry(m, "loop.idle_share.arrive").update(
+            layer="queues")),
+    "a reader moves another metric": (
+        False, lambda m: _entry(m, "queue.done_ms_per_kpod.drain").update(
+            moves="setup_s")),
+    "a reader is dropped from one of its cells": (
+        False, lambda m: _entry(m, "queue.done_ms_per_kpod.drain")[
+            "workloads"].remove("topology-5k.required")),
+    "a new cell is put before a reader's own": (
+        False, lambda m: _entry(m, "loop.gc_ms_per_kpod.arrive")[
+            "workloads"].insert(0, "a-later.cell")),
+    "two entries that were there change places": (
+        False, lambda m: _swap(m, 2, 9)),
+    "the last entry is moved to the head": (
+        False, lambda m: m["per_layer"].insert(0, m["per_layer"].pop())),
+    "an entry that was there is taken out": (
+        False, lambda m: m["per_layer"].pop(4)),
+}
+
+
+@pytest.mark.parametrize("edit", sorted(PER_LAYER_EDITS))
+def test_per_layer_takes_additions_and_refuses_a_move(edit):
+    holds, change = PER_LAYER_EDITS[edit]
+    manifest = inv.edited(cell.load_manifest(REPO), change)
+    if holds:
+        inv.per_layer_entries_keep_their_order_and_their_cells(manifest)
+    else:
+        with pytest.raises(AssertionError):
+            inv.per_layer_entries_keep_their_order_and_their_cells(manifest)
 
 
 def test_phase_spans_against_the_real_scheduler():
