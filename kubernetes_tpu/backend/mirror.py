@@ -189,6 +189,22 @@ P1_DEDUP_GROUP_CAP = 8
 # stays flat across the oscillation).
 BUCKET_DECAY_LAUNCHES = 64
 
+# bound of the packed-row cache (Mirror._pack_batch_np), per field set:
+# one more distinct pod shape than this clears it, so a stream of pods
+# that never repeat holds a few MiB at most
+POD_ROW_CACHE_ENTRIES = 4096
+
+
+def _selector_key(sel):
+    """A LabelSelector's content for Mirror._pod_row_key (None stays
+    None: a nil selector packs differently from an empty one)."""
+    if sel is None:
+        return None
+    return (tuple(sel.match_labels.items()),
+            tuple((e.key, e.operator, tuple(e.values))
+                  for e in sel.match_expressions)
+            if sel.match_expressions else ())
+
 
 class Mirror:
     def __init__(self, interner: Interner | None = None,
@@ -250,8 +266,14 @@ class Mirror:
         self._pod_tmpl: tuple[np.ndarray, np.ndarray] | None = None
         self._pod_tmpl_dev = None          # device push of _pod_template
         self._subset_tmpl: dict[tuple, tuple[np.ndarray, np.ndarray]] = {}
-        # plain-pod packed-row cache: fields-tuple -> content-key -> row
-        self._plain_rows: dict[tuple, dict] = {}
+        # packed-row cache: fields-tuple -> content key (_pod_row_key) ->
+        # row, and its counts (totals of the scheduler's life: a fresh
+        # mirror takes them over in adopt_hysteresis)
+        self._pod_rows: dict[tuple, dict] = {}
+        self.row_cache_hits = 0
+        self.row_cache_misses = 0
+        self.row_cache_bypass = 0
+        self.row_cache_clears = 0
         self._table_i32_tmpl: np.ndarray | None = None
         self._row_node_obj: dict[int, object] = {}  # row -> packed Node obj
         # workload-activity tracking for launch_features(): which rows carry
@@ -1041,8 +1063,14 @@ class Mirror:
         capacity re-bucket (scheduler._grow builds a FRESH mirror):
         without this a rebuilt mirror re-derives a smaller bucket from
         its still-empty domain tables and the next churn swing pays the
-        compile again."""
+        compile again. The packed-row cache's counts come along too:
+        they are totals the registry mirrors by delta, and the cache
+        itself starts empty."""
         self._d_hw = prev._d_hw
+        self.row_cache_hits = prev.row_cache_hits
+        self.row_cache_misses = prev.row_cache_misses
+        self.row_cache_bypass = prev.row_cache_bypass
+        self.row_cache_clears = prev.row_cache_clears
 
     def launch_d_cap(self, enable_topology: bool) -> int:
         """The static d_cap for one launch: the domain bucket when the
@@ -1446,25 +1474,74 @@ class Mirror:
                 self.pod_label_col(k)
 
     @staticmethod
-    def _plain_pod_key(pod: Pod):
-        """Content key for the plain-pod packed-row cache, or None when
-        the pod uses any feature beyond (namespace, priority, labels-free
-        containers with resource requests) — deployment-shaped batches are
-        thousands of pods identical up to name/uid, and re-deriving the
-        whole row per pod was the dominant host pack cost."""
+    def _pod_row_key(pod: Pod):
+        """Content key of the packed-row cache: every input that
+        pack_pod(pod, active_only=True) reads except name and uid (those
+        two columns are patched per pod), or None where the row is not a
+        function of the pod's content alone and the pod takes the slow
+        path every time (counted as a bypass):
+
+        * status.nominated_node_name: nominated_row reads _nominated_uids
+          and _row_of, which set_nominated rewrites every cycle;
+        * node_selector and node affinity: label_col_lookup answers NONE
+          until a node brings the key, so the same pod packs differently
+          after a sync (a metadata.name pin is a key of its own a pod, so
+          nothing is lost by leaving it out);
+        * an (anti-)affinity term with a namespace_selector:
+          _resolve_term_namespaces reads the namespace store and
+          _known_pod_ns;
+        * spec.node_name: such a pod is not for scheduling.
+
+        Dicts and lists go into the key in their own order, unsorted: two
+        pods that spell one content in two orders get two keys and two
+        equal rows, which costs an entry and never a wrong row. Deployment
+        -shaped batches are thousands of pods identical up to name and uid,
+        and re-deriving the whole row a pod was the dominant host pack
+        cost; the key has to stay a few microseconds."""
         s = pod.spec
-        if (s.affinity is not None or s.node_selector or s.tolerations
-                or s.topology_spread_constraints or s.init_containers
-                or s.overhead or s.volumes or s.resource_claims
-                or s.scheduling_gates or s.node_name
-                or pod.status.nominated_node_name or pod.metadata.labels):
+        if s.node_name or s.node_selector or pod.status.nominated_node_name:
             return None
-        for c in s.containers:
-            if c.ports:
+        aff = s.affinity
+        aff_key = None
+        if aff is not None:
+            if aff.node_affinity is not None:
                 return None
-        return (pod.metadata.namespace, s.priority,
-                tuple((c.image, tuple(sorted(c.resources.requests.items())))
-                      for c in s.containers))
+            groups = []
+            for grp in (aff.pod_affinity, aff.pod_anti_affinity):
+                if grp is None:
+                    groups.append(None)
+                    continue
+                terms = [(0, t) for t in grp.required]
+                terms += [(w.weight, w.pod_affinity_term)
+                          for w in grp.preferred]
+                for _w, t in terms:
+                    if t.namespace_selector is not None:
+                        return None
+                groups.append((len(grp.required), tuple(
+                    (w, t.topology_key, _selector_key(t.label_selector),
+                     tuple(t.namespaces), tuple(t.match_label_keys),
+                     tuple(t.mismatch_label_keys)) for w, t in terms)))
+            aff_key = tuple(groups)
+        meta = pod.metadata
+        return (
+            meta.namespace, s.priority,
+            tuple(meta.labels.items()) if meta.labels else (),
+            tuple((c.image, tuple(c.resources.requests.items()),
+                   tuple((p.host_ip, p.protocol, p.host_port)
+                         for p in c.ports) if c.ports else ())
+                  for c in s.containers),
+            tuple((c.restart_policy, tuple(c.resources.requests.items()))
+                  for c in s.init_containers) if s.init_containers else (),
+            tuple(s.overhead.items()) if s.overhead else (),
+            tuple((t.max_skew, t.topology_key, t.when_unsatisfiable,
+                   t.min_domains, _selector_key(t.label_selector),
+                   tuple(t.match_label_keys), t.node_affinity_policy,
+                   t.node_taints_policy)
+                  for t in s.topology_spread_constraints)
+            if s.topology_spread_constraints else (),
+            tuple((t.key, t.operator, t.value, t.effect)
+                  for t in s.tolerations) if s.tolerations else (),
+            aff_key)
 
     def _pack_batch_np(self, pods: list[Pod], batch_size: int,
                        fields: tuple[str, ...]
@@ -1472,9 +1549,20 @@ class Mirror:
         """Subset-packed batch rows as host arrays (pack_batch_blobs body;
         prepare_launch also hashes these rows for topology-group dedup).
 
-        Plain pods (no features beyond requests) share a cached packed row
-        per content key; only the identity columns (name_id, uid_id) are
-        patched per pod."""
+        Pods share one cached packed row per content key (_pod_row_key);
+        only the identity columns (name_id, uid_id) are patched per pod.
+        A hit is byte for byte what pack_pod would pack for that pod now.
+
+        INVARIANT: a row in _pod_rows is derived from the pod's content
+        and from registries that only append for the Mirror's lifetime
+        (the interner, pod_label_col, topo_col and its domains, ext_col,
+        _used_tks), and re-bucketing constructs a FRESH Mirror with an
+        empty cache. A hit also skips pack_pod's side effects (registering
+        label columns and topology keys, _used_tks): that is sound only
+        because an earlier miss on this same Mirror ran them, and nothing
+        un-registers. An edit that lets pack_pod read mutable state for a
+        keyed pod has to put that state's version into the key or make
+        _pod_row_key return None for it."""
         self._batch_prepass(pods, batch_size)
         tmpl = self._subset_tmpl.get(fields)
         if tmpl is None:
@@ -1490,11 +1578,13 @@ class Mirror:
         name_ent = i_off.get("name_id")
         uid_ent = i_off.get("uid_id")
         cacheable = name_ent is not None and uid_ent is not None
-        cache = self._plain_rows.setdefault(fields, {})
+        cache = self._pod_rows.setdefault(fields, {})
+        hits = misses = 0
         for b, pod in enumerate(pods):
-            key = self._plain_pod_key(pod) if cacheable else None
+            key = self._pod_row_key(pod) if cacheable else None
             row = cache.get(key) if key is not None else None
             if row is not None:
+                hits += 1
                 f32[b] = row[0]
                 i32[b] = row[1]
             else:
@@ -1502,13 +1592,27 @@ class Mirror:
                     fields, f32[b], i32[b],
                     self.pack_pod(pod, active_only=True))
                 if key is not None:
-                    if len(cache) > 4096:
+                    misses += 1
+                    if len(cache) > POD_ROW_CACHE_ENTRIES:
                         cache.clear()
+                        self.row_cache_clears += 1
                     cache[key] = (f32[b].copy(), i32[b].copy())
             if cacheable:
                 i32[b, name_ent[0]] = self._i(pod.metadata.name)
                 i32[b, uid_ent[0]] = self._i(pod.metadata.uid)
+        self.row_cache_hits += hits
+        self.row_cache_misses += misses
+        self.row_cache_bypass += len(pods) - hits - misses
         return f32, i32
+
+    def row_cache_stats(self) -> dict:
+        """The packed-row cache's counts, for /debug/trace and the
+        registry: pods packed = hits + misses + bypass."""
+        return {"hits": self.row_cache_hits,
+                "misses": self.row_cache_misses,
+                "bypass": self.row_cache_bypass,
+                "clears": self.row_cache_clears,
+                "entries": sum(len(c) for c in self._pod_rows.values())}
 
     # identity fields excluded from the topology-group signature: two pods
     # differing ONLY in these compute identical topology statics (name/uid

@@ -254,6 +254,12 @@ class SchedulerMetrics:
             "scheduler_queue_event_trims_total",
             "PriorityQueue._trim_events calls that dropped entries from "
             "the event log's head while pods stayed in flight"))
+        self.pack_row_cache = r.register(Counter(
+            "scheduler_pack_row_cache_total",
+            "Pods packed for a launch by what the mirror's packed-row "
+            "cache did: hit (a row of the same content copied), miss "
+            "(packed and kept), bypass (a pod whose row is not a function "
+            "of its content alone, packed every time)", ("result",)))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

@@ -3653,6 +3653,12 @@ class Scheduler:
         for src, n in self._dra.cel_error_stats().items():
             self._mirror_count(f"cel:{src}", n, m.dra_cel_errors,
                                source=src)
+        mirror = self.mirror
+        for result, n in (("hit", mirror.row_cache_hits),
+                          ("miss", mirror.row_cache_misses),
+                          ("bypass", mirror.row_cache_bypass)):
+            self._mirror_count(f"pack_row_cache:{result}", n,
+                               m.pack_row_cache, result=result)
         self._mirror_journal_stats()
         if self.jobqueue.active:
             for tenant, st in self.jobqueue.tenant_stats().items():

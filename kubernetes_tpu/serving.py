@@ -124,6 +124,9 @@ class ServingEndpoints:
                         # the queue's in-flight event log: entries,
                         # high-water, trims that scanned and their seconds
                         "queue": sched.queue.trim_stats(),
+                        # the mirror's packed-row cache: pods packed
+                        # = hits + misses + bypass, clears at its bound
+                        "pack_row_cache": sched.mirror.row_cache_stats(),
                         "phases": flight.phase_percentiles(),
                         "host_tail_share": round(
                             flight.host_tail_share(), 4),

@@ -230,6 +230,52 @@ def test_debug_trace_shows_spans_loop_spans_and_queue_counts():
         sched.close()
 
 
+def test_pack_row_cache_counts_reach_the_registry_and_debug_trace():
+    """Mirror.row_cache_* -> scheduler_pack_row_cache_total{result} (by
+    delta, at maintenance) and /debug/trace's "pack_row_cache"; the counts
+    are the scheduler's, so a re-bucketed mirror carries them on."""
+    from kubernetes_tpu.backend.mirror import CapacityError
+
+    hub = Hub()
+    sched = _sched(hub)
+    try:
+        hub.create_node(mknode(0))
+        for i in range(6):
+            hub.create_pod(mk_sched_pod(f"p{i}"))
+        sched.run_until_idle()
+        sched.run_maintenance()
+        m, st = sched.metrics, sched.mirror.row_cache_stats()
+        assert st["hits"] + st["misses"] + st["bypass"] == 6
+        assert st["hits"] >= 4 and st["bypass"] == 0 and st["clears"] == 0
+        for result, key in (("hit", "hits"), ("miss", "misses"),
+                            ("bypass", "bypass")):
+            assert m.pack_row_cache.value(result=result) == st[key]
+        assert (f'scheduler_pack_row_cache_total{{result="hit"}} '
+                f'{st["hits"]}') in m.registry.render_text()
+        sched._grow(CapacityError("pod_labels", sched.caps.pod_labels + 1))
+        assert sched.mirror.row_cache_stats() == {**st, "entries": 0}
+        for i in range(6, 9):
+            hub.create_pod(mk_sched_pod(f"p{i}"))
+        sched.run_until_idle()
+        sched.run_maintenance()
+        st = sched.mirror.row_cache_stats()
+        assert st["hits"] + st["misses"] + st["bypass"] == 9
+        assert m.pack_row_cache.value(result="hit") == st["hits"]
+        assert m.pack_row_cache.value(result="miss") == st["misses"]
+        srv = ServingEndpoints(sched, port=0, debug_auth=token_auth("t"))
+        srv.start()
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{srv.port}/debug/trace?n=1")
+            req.add_header("Authorization", "Bearer t")
+            tr = json.loads(urllib.request.urlopen(req, timeout=5).read())
+        finally:
+            srv.stop()
+        assert tr["pack_row_cache"] == st
+    finally:
+        sched.close()
+
+
 # ------------------------------------------------ the collector's pauses
 
 

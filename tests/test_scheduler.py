@@ -210,6 +210,59 @@ def test_node_deleted_while_pods_pending():
     assert sched.stats["unschedulable"] >= 1
 
 
+def test_spread_drain_places_the_same_with_the_row_cache_cold_or_warm(
+        monkeypatch):
+    """A drain of zone-spreading pods binds the same pods to the same
+    nodes whether the mirror's packed-row cache is cleared before every
+    batch or left warm (ISSUE 29: a hit is the slow path's row), and the
+    required maxSkew holds at the end."""
+    from kubernetes_tpu.backend.mirror import Mirror
+
+    zones = ["z1", "z2", "z3"]
+    prepare = Mirror.prepare_launch
+
+    def cold_prepare(mirror, pods, batch_size):
+        mirror._pod_rows.clear()
+        return prepare(mirror, pods, batch_size)
+
+    def drain(cold):
+        monkeypatch.setattr(Mirror, "prepare_launch",
+                            cold_prepare if cold else prepare)
+        hub = Hub()
+        cfg = default_config()
+        cfg.batch_size = 8
+        cfg.tie_break_seed = 29
+        sched = Scheduler(hub, cfg, caps=Capacities(nodes=16, pods=128))
+        try:
+            for i in range(12):
+                hub.create_node(mknode(i, zone=zones[i % 3]))
+            tsc = [TopologySpreadConstraint(
+                max_skew=1, topology_key=LABEL_ZONE,
+                when_unsatisfiable="DoNotSchedule",
+                label_selector=LabelSelector(match_labels={"app": "web"}))]
+            for i in range(60):
+                hub.create_pod(mkpod(f"p{i}", cpu="100m",
+                                     labels={"app": "web"}, tsc=tsc))
+            sched.run_until_idle()
+            return ({p.metadata.name: p.spec.node_name
+                     for p in hub.list_pods()},
+                    sched.mirror.row_cache_stats())
+        finally:
+            sched.close()
+
+    warm, warm_stats = drain(cold=False)
+    cold, cold_stats = drain(cold=True)
+    assert warm == cold
+    assert all(warm.values()) and len(warm) == 60
+    per_zone = [sum(1 for n in warm.values()
+                    if zones[int(n.split("-")[1]) % 3] == z) for z in zones]
+    assert max(per_zone) - min(per_zone) <= 1
+    # warm: one miss a field set; cold: one a batch at the least
+    assert warm_stats["bypass"] == cold_stats["bypass"] == 0
+    assert warm_stats["misses"] < 8 <= cold_stats["misses"]
+    assert warm_stats["hits"] > cold_stats["hits"] > 0
+
+
 # suite-tier discipline (tests/test_markers.py): area marker
 import pytest  # noqa: E402
 pytestmark = pytest.mark.core
