@@ -105,7 +105,7 @@ class _Tenant:
         self.admitted = 0                   # pods released, lifetime
         # pods released while ANOTHER tenant also had backlog: under
         # contention these track the configured weight ratios (the
-        # fairness number the gang-storm bench publishes — lifetime
+        # fairness number the gang-storm workload reports — lifetime
         # totals converge to 1:1 once the faster tenant drains)
         self.contended_admitted = 0
         self.quota_blocked = 0              # release attempts quota denied

@@ -34,7 +34,7 @@ acceptance storm — device faults + watch cuts + leader kill +
 kill-and-restart over ≥1k pods, every pod bound exactly once.
 ``run_gang_storm()`` kills the leader mid-gang-commit and asserts the
 all-or-nothing ledger: every gang lands fully or not at all.
-``bench.py --chaos-smoke`` runs all four as the red-suite gate.
+``python -m kubernetes_tpu.chaos --storm all`` runs the whole battery.
 """
 
 from __future__ import annotations
@@ -495,7 +495,7 @@ class ChaosProxy:
 
 
 # --------------------------------------------------------------------------
-# chaos smoke scenario (bench.py --chaos-smoke's red-suite gate)
+# chaos smoke scenario (--storm smoke)
 # --------------------------------------------------------------------------
 
 
@@ -2067,8 +2067,7 @@ def main() -> None:
                              "state", "gang", "scaleout", "overload",
                              "scenario", "all"),
                     default="smoke",
-                    help="which storm to run (bench.py --chaos-smoke "
-                         "runs 'all')")
+                    help="which storm to run ('all' = the whole battery)")
     args = ap.parse_args()
     if args.storm == "smoke":
         report: dict = run_smoke(pods=args.pods, nodes=args.nodes,

@@ -53,7 +53,7 @@ def _profile(doc: dict) -> SchedulerProfile:
 
 def config_from_dict(doc: dict) -> SchedulerConfiguration:
     cfg = SchedulerConfiguration()
-    for key in ("parallelism", "percentage_of_nodes_to_score",
+    for key in ("percentage_of_nodes_to_score",
                 "pod_initial_backoff_seconds", "pod_max_backoff_seconds",
                 "async_binding", "binding_workers", "batch_size",
                 "node_capacity", "pod_table_capacity",

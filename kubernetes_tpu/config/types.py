@@ -81,7 +81,6 @@ class SchedulerProfile:
 class SchedulerConfiguration:
     """Top-level component config (types.go:37-97)."""
 
-    parallelism: int = 16
     profiles: list[SchedulerProfile] = field(default_factory=list)
     # percentageOfNodesToScore (schedule_one.go:668): None (default) scores
     # every node — on TPU one fused launch covers the full node set for the
@@ -111,7 +110,8 @@ class SchedulerConfiguration:
     tenants: dict[str, dict] = field(default_factory=dict)
     # flight recorder (always-on per-phase cycle tracing): ring size in
     # cycles; 0 disables the recorder entirely (not recommended — the
-    # overhead budget is <2% of cycle time, see bench.py --trace-overhead)
+    # overhead budget is <2% of cycle time; its share on the chip's host
+    # is not measured yet, ROADMAP S7)
     flight_recorder_capacity: int = 256
     # per-pod lifecycle timelines LRU (utils/tracing.PodTimelines):
     # time-to-bind SLO stats (telemetry/slo.py) walk this, so runs that
@@ -157,19 +157,13 @@ class SchedulerConfiguration:
     # scheduler brownout (overload protection): when the hub answers a
     # sustained run of 429s (flow-control rejections) or queue-wait SLO
     # breaches, the scheduler sheds its own load instead of hammering a
-    # saturated fabric — effective batch shrinks to
-    # max(batch_size // brownout_batch_divisor, brownout_batch_floor),
-    # the drift sentinel stretches its cadence by
-    # brownout_drift_stretch, and best-effort tenants (weight <
-    # brownout_besteffort_weight) are parked in the jobqueue. Exits
-    # after brownout_clear_windows consecutive maintenance windows with
+    # saturated fabric — the effective batch shrinks, the drift sentinel
+    # stretches its cadence and best-effort tenants are parked in the
+    # jobqueue (by how much: the BROWNOUT_* constants of scheduler.py).
+    # Exits after brownout_clear_windows consecutive maintenance windows with
     # no new throttles. brownout_throttle_threshold <= 0 disables.
     brownout_throttle_threshold: int = 8
     brownout_clear_windows: int = 3
-    brownout_batch_divisor: int = 4
-    brownout_batch_floor: int = 8
-    brownout_drift_stretch: float = 4.0
-    brownout_besteffort_weight: float = 0.25
     # SLO watchdog (telemetry/watchdog.py): evaluated on the maintenance
     # cadence, at most every watchdog_interval_s. watchdog_slo is a
     # telemetry/slo.py target dict over live time-to-bind stats (e.g.
@@ -182,16 +176,14 @@ class SchedulerConfiguration:
     # incident autopsy (telemetry/autopsy.py): directory for black-box
     # bundles captured when a watchdog rule trips or a containment site
     # fires. None disables capture (the watchdog still counts incidents
-    # in scheduler_watchdog_incidents_total). Retention: newest
-    # autopsy_max_bundles bundles / autopsy_max_bytes on disk; at most
-    # one bundle per incident class per autopsy_rate_limit_s
+    # in scheduler_watchdog_incidents_total). Retention: AutopsyStore's
+    # own bounds on bundle count and bytes on disk; at most one bundle
+    # per incident class per autopsy_rate_limit_s
     autopsy_dir: Optional[str] = None
-    autopsy_max_bundles: int = 32
-    autopsy_max_bytes: int = 16 * 1024 * 1024
     autopsy_rate_limit_s: float = 30.0
     # explicit tie-break RNG seed for the device pipeline's equal-score
-    # node choice: paired A/B runs (bench --ab-scorer) share a seed so
-    # placement diffs are attributable to the scorer, not the coin.
+    # node choice: paired A/B runs share a seed so placement diffs are
+    # attributable to the change under test, not the coin.
     # 0 = the historical default hash stream.
     tie_break_seed: int = 0
 

@@ -62,8 +62,6 @@ def validate_config(cfg: SchedulerConfiguration,
                     registry: dict | None = None) -> list[str]:
     """Returns a list of error strings (empty = valid)."""
     errs: list[str] = []
-    if cfg.parallelism <= 0:
-        errs.append("parallelism must be positive")
     if cfg.batch_size <= 0:
         errs.append("batch_size must be positive")
     if cfg.binding_workers <= 0:

@@ -17,7 +17,7 @@ Three pillars (ROADMAP item 3):
   cursors and backpressure-aware slow-subscriber eviction.
 
 :mod:`kubernetes_tpu.fabric.fanout` drives the 10k-client smoke
-(``bench.py --fanout-smoke``).
+(``python -m kubernetes_tpu.fabric.fanout [--procs]``).
 
 Submodules other than ``codec`` load lazily (PEP 562): the transport
 layer (hubserver/hubclient) imports ``fabric.codec``, and the relay

@@ -1,6 +1,7 @@
 """Fan-in scale smoke: 10k kubelet-analog reflectors through a relay tree.
 
-The ``bench.py --fanout-smoke`` gate. One hub, a chaos proxy in front of
+Run it: ``python -m kubernetes_tpu.fabric.fanout [--procs]``. One hub, a
+chaos proxy in front of
 it, two level-1 relay nodes dialing upstream through the proxy, eight
 level-2 relay nodes dialing the level-1s, and 10k simulated reflectors
 (in-process subscribers — bounded queues and resume cursors, the exact
@@ -429,7 +430,7 @@ def run_fanout_smoke(subscribers: int = 10000, l1_count: int = 2,
 def _wal_bytes(events: list[dict]) -> tuple[int, int]:
     """(json_bytes, bin1_bytes) for the same WAL record stream — the
     replay-size ratio the bin1 journal WAL buys, measured on the
-    storm's own events (the satellite's bench-artifact number)."""
+    storm's own events."""
     from kubernetes_tpu.storage import Journal, JournalEvent
 
     jb = bb = 0
@@ -819,7 +820,7 @@ def main() -> None:
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="relay-tree fan-out smoke (bench.py --fanout-smoke)")
+        description="relay-tree fan-out smoke")
     ap.add_argument("--subscribers", type=int, default=10000)
     ap.add_argument("--smoke", action="store_true",
                     help="small/fast variant (1k subscribers)")

@@ -208,7 +208,7 @@ class ExportCursor:
         else:
             # common case: same file, tail from our offset. A file
             # that SHRANK in place (same inode — an operator's
-            # `> traces.jsonl`, run_one's warm-pass truncate) restarts
+            # `> traces.jsonl`) restarts
             # from 0 like WalTail: seeking past EOF would silently
             # skip everything written until the file regrows
             if st.st_size < self.offset:

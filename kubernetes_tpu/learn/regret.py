@@ -12,9 +12,8 @@ exactly like the replay dataset's reward shading, and
 is the score mass the scheduler gave up by the choice it made, in
 aggregate-score points: 0 when the chosen node was best and its
 placement stuck, positive when a runner-up would have been better or
-the outcome went bad. Summaries (mean/p50/p99) land in every bench
-artifact row that ran with the alt export on, in the learn-loop's
-metrics, and in the promoted checkpoint's meta (/debug/scorer).
+the outcome went bad. Summaries (mean/p50/p99) land in the learn-loop's
+metrics and in the promoted checkpoint's meta (/debug/scorer).
 
 **Replay scoring** (the gate): a candidate checkpoint is compared to
 the live one on held-out recent placement rows WITHOUT touching the
@@ -166,7 +165,7 @@ def compute_regret(rows: Iterable[dict], evicted: Optional[set] = None,
 def summarize_regret(records: list[dict]) -> dict:
     """{count, regret_mean, regret_p50, regret_p99,
     regret_positive_frac} over compute_regret records — the shape the
-    bench artifact rows, the loop metrics, and checkpoint meta embed."""
+    loop metrics and checkpoint meta embed."""
     if not records:
         return {"count": 0, "regret_mean": 0.0, "regret_p50": 0.0,
                 "regret_p99": 0.0, "regret_positive_frac": 0.0}
@@ -182,7 +181,7 @@ def summarize_regret(records: list[dict]) -> dict:
 
 def harvest_hub_outcomes(hub) -> tuple[set, dict]:
     """(evicted_uids, node -> topology domain) from a LIVE in-process
-    hub — the perf harness's analog of replay.wal_outcomes: bound-pod
+    hub — the scenario replayer's analog of replay.wal_outcomes: bound-pod
     DELETE events in the journal are the eviction signal, node labels
     map to zone (hostname fallback) domains. A compacted journal
     (too_old) yields partial eviction data; domains stay complete."""

@@ -1,9 +1,8 @@
 """Device kernels for batched DRA allocation feasibility.
 
 The host DRA plugin (plugins/dra.py) used to evaluate claim feasibility
-per (pod, node, device) in Python — the worst host tail in the suite
-(DRASteadyStateClaimTemplates at 1.12x baseline, BENCH_r06). This module
-is the device half of its replacement:
+per (pod, node, device) in Python — the worst host tail in the suite.
+This module is the device half of its replacement:
 
 - the cluster's device inventory is mirrored into dense per-node tensors
   (``dev_valid``/``dev_selbits``/``dev_in_use``, [N, D]-shaped with D a

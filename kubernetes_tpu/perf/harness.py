@@ -24,7 +24,6 @@ production-path throughput.
 from __future__ import annotations
 
 import copy
-import os
 import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
@@ -108,7 +107,6 @@ class Workload:
     name: str
     ops: list
     threshold: float = 0.0      # reference CI floor, pods/s
-    baseline: float = 0.0       # same as threshold unless overridden
     node_capacity: int = 8192   # mirror bucket hints (pow2; fixed up front
     pod_capacity: int = 16384   # so warmup compiles the full-size programs)
     batch_size: int = 2048
@@ -134,13 +132,8 @@ class Workload:
     # post-run assertion hook: validate(hub, result) inspects the final
     # cluster state, may attach extra result fields, and RAISES on a
     # violated workload invariant (e.g. GangTopologyPacking's
-    # members-land-topology-close criterion) — a red validate fails the
-    # bench row like a missed threshold would
+    # members-land-topology-close criterion)
     validate: Optional[Callable] = None
-
-    def __post_init__(self) -> None:
-        if not self.baseline:
-            self.baseline = self.threshold
 
 
 class _ChurnState:
@@ -227,21 +220,15 @@ def assert_device_path(sched: Scheduler) -> None:
 def run_workload(w: Workload, now: Callable[[], float] = time.time,
                  sleep: Callable[[float], None] = time.sleep,
                  scale: float = 1.0,
-                 config=None, profile: bool = False,
-                 cycle_times: Optional[list] = None) -> dict:
+                 config=None) -> dict:
     """Execute one workload; returns the result dict (throughput summary,
-    threshold verdict, scheduler stats).
+    scheduler stats, the flight recorder's per-phase/per-plugin
+    percentiles and host-tail share).
 
     ``scale`` shrinks every op count (for warmup/compile passes and unit
     tests) while keeping capacities — and therefore every jitted program
     shape — identical to the full-size run, so a scale=0.01 pass populates
     the XLA compile cache for the real one.
-
-    ``profile`` adds the flight recorder's per-phase/per-plugin
-    percentiles and host-tail share to the result (bench.py --profile).
-    ``cycle_times`` (a caller-owned list) collects every RAW cycle
-    duration in seconds — exact samples, not bucket-resolution histogram
-    reads — for the --trace-overhead on/off comparison.
     """
     if scale != 1.0 and w.rescale is not None:
         w = w.rescale(scale)
@@ -263,16 +250,6 @@ def run_workload(w: Workload, now: Callable[[], float] = time.time,
     cfg.feature_gates.update(w.feature_gates)
     sched = Scheduler(hub, cfg, caps=Capacities(
         nodes=w.node_capacity, pods=w.pod_capacity), now=now)
-    if cycle_times is not None:
-        # exact per-cycle samples: wrap the cycle histogram's observe so
-        # every recorded duration also lands in the caller's list
-        _obs = sched.metrics.batch_duration.observe
-
-        def _capture(value: float, n: int = 1, **labels) -> None:
-            cycle_times.append(value)
-            _obs(value, n, **labels)
-
-        sched.metrics.batch_duration.observe = _capture
     churns: list[_ChurnState] = []
     summary = None
     phases: list[dict] = []
@@ -367,10 +344,8 @@ def run_workload(w: Workload, now: Callable[[], float] = time.time,
         sched.close()  # binder threads released even on failure
     assert_device_path(sched)
     m = sched.metrics
-    # scheduling-quality outcomes for the A/B scorer harness (bench.py
-    # --ab-scorer): preemption count, end-state per-node bound-pod
-    # spread, and time-to-bind tail — the metrics a latency-neutral
-    # learned scorer is supposed to move
+    # scheduling-quality outcomes: preemption count, end-state per-node
+    # bound-pod spread, and time-to-bind tail
     # seed EVERY node at 0 first: a scorer that hotspots all pods onto
     # one node must read as maximal imbalance, not perfect spread
     per_node: dict[str, int] = {n.metadata.name: 0
@@ -389,7 +364,6 @@ def run_workload(w: Workload, now: Callable[[], float] = time.time,
         spread_std = spread_maxmin = 0.0
     result = {
         "name": w.name,
-        "threshold": w.threshold,
         "stats": dict(sched.stats),
         # the metric slices the reference harness scrapes
         # (scheduler_perf.go:140-166): attempt latency percentiles + counts
@@ -414,64 +388,25 @@ def run_workload(w: Workload, now: Callable[[], float] = time.time,
                 sched.timelines).items() if k != "count"},
         },
     }
-    # per-placement regret columns (ISSUE 14): whenever the run exported
-    # the v3 alternative rows, summarize (chosen outcome − best
-    # counterfactual) over this workload's placements into the artifact
-    # row — outcomes harvested from the live hub's journal the same way
-    # replay harvests them from the WAL
-    if getattr(cfg, "trace_export_path", None) \
-            and getattr(cfg, "trace_export_alts", False):
-        try:
-            from kubernetes_tpu.learn import regret as RG
-            from kubernetes_tpu.learn.replay import (
-                iter_placement_rows,
-                iter_trace_lines,
-            )
-
-            paths = [cfg.trace_export_path + ".1", cfg.trace_export_path]
-            rows = [r for p in paths if os.path.exists(p)
-                    for r in iter_placement_rows(iter_trace_lines(p))]
-            evicted, node_domain = RG.harvest_hub_outcomes(hub)
-            # the export opens in APPEND mode: a reused path carries
-            # earlier runs' rows — keep only uids THIS run's (fresh)
-            # hub knows, so the columns summarize this workload only
-            run_uids = {p.metadata.uid for p in hub.list_pods()} \
-                | evicted
-            rows = [r for r in rows if r.get("uid") in run_uids]
-            reg = RG.summarize_regret(
-                RG.compute_regret(rows, evicted, node_domain))
-            result["quality"]["regret_mean"] = reg["regret_mean"]
-            result["quality"]["regret_p99"] = reg["regret_p99"]
-            result["regret"] = reg
-        except Exception:  # noqa: BLE001 — a torn export must not fail
-            pass           # the bench row it decorates
     if sched.jobqueue.active:
         # per-tenant admission/fairness accounting for the gang-storm
         # artifact rows (weights should show up as contended ratios)
         result["tenants"] = sched.jobqueue.tenant_stats()
         result["gangs"] = sched._gang.debug_state()["stats"]
-    if profile:
-        fl = sched.flight
-        result["flight"] = {
-            "enabled": fl.enabled,
-            "cycles_recorded": len(fl.ring),
-            "phases": fl.phase_percentiles(),
-            "plugins": fl.plugin_percentiles(),
-            "host_tail_share": round(fl.host_tail_share(), 4),
-            # pipelined waves: per-cycle device occupancy (launch span
-            # over cycle wall) — the pipelining win shows up here as a
-            # mean close to 1.0 while the strict-alternation arm idles
-            "occupancy": fl.occupancy_stats(),
-            # the device-launch profiler column: compiles by attributed
-            # cause, per-shape walltime, resident buffer bytes
-            "device": (sched.profiler.snapshot()
-                       if sched.profiler is not None else None),
-        }
+    fl = sched.flight
+    result["flight"] = {
+        "enabled": fl.enabled,
+        "cycles_recorded": len(fl.ring),
+        "phases": fl.phase_percentiles(),
+        "plugins": fl.plugin_percentiles(),
+        "host_tail_share": round(fl.host_tail_share(), 4),
+        # the device-launch profiler column: compiles by attributed
+        # cause, per-shape walltime, resident buffer bytes
+        "device": (sched.profiler.snapshot()
+                   if sched.profiler is not None else None),
+    }
     if w.validate is not None:
         w.validate(hub, result)
     if summary is not None:
         result.update(summary.to_dict())
-        result["vs_baseline"] = (
-            round(summary.pods_per_sec / w.baseline, 2) if w.baseline else 0)
-        result["passed"] = summary.pods_per_sec >= w.threshold
     return result

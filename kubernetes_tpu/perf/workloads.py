@@ -8,8 +8,8 @@ suite (required/preferred spreading, node-inclusion policy), churn,
 daemonset, gated, unschedulable (hints on/off), DRA steady state
 (direct claims + claim templates with CEL selectors), and the
 feature-gate variants (QueueingHints, AsyncPreemption, preferred
-NSSelector anti-affinity) — 25 configs, all run and published by
-bench.py.
+NSSelector anti-affinity) — 25 configs; ``perf/run_one.py`` runs one by
+its function's name.
 
 Node template (node-default.yaml): cpu 4, memory 32Gi, pods 110.
 Pod template (pod-default.yaml): requests cpu 100m, memory 500Mi.
@@ -264,16 +264,10 @@ def _churn_node(i: int) -> object:
 def mixed_churn(init_nodes=5000, measure_pods=10000) -> Workload:
     return Workload(
         name="SchedulingWithMixedChurn/5000Nodes_10000Pods",
-        # ratcheted off the r15 lock (1400) by pipelined waves
-        # (BENCH_r19): chain-surviving churn keeps the device-resident
-        # free/nzr chain alive across the 1s recreate-churn (patches
-        # instead of whole-chain invalidation + resync), zero measured-
-        # phase recompiles. Paired same-box A/B best-of-3 reads 1.29x
-        # (on 429.4 vs off 334.1 pods/s on the throttled 2-CPU box) but
-        # the on-arm single-run swing is ±50%, so the ratchet is the
-        # modest, defensible slice of it
-        threshold=1500,
-        baseline=265,
+        # under pipelined waves the churn patches the device-resident
+        # free/nzr chain across the 1s recreate-churn (no whole-chain
+        # invalidation + resync); the measured phase must not recompile
+        threshold=265,
         ops=[
             CreateNodes(init_nodes, _node),
             Churn([_churn_node, _large_cpu_pod], interval_ms=1000,
@@ -300,8 +294,7 @@ def _daemonset_pod(i: int) -> Pod:
 def scheduling_daemonset(init_nodes=15000, measure_pods=15000) -> Workload:
     return Workload(
         name="SchedulingDaemonset/15000Nodes",
-        threshold=3900,   # ratcheted: 10x the reference 390 floor (ISSUE 15)
-        baseline=390,
+        threshold=390,
         node_capacity=16384,
         pod_capacity=32768,
         ops=[
@@ -375,8 +368,7 @@ def preferred_pod_affinity(init_nodes=5000, init_pods=1000,
                            measure_pods=5000) -> Workload:
     return Workload(
         name="SchedulingPreferredPodAffinity/5000Nodes_5000Pods",
-        threshold=900,   # ratcheted: 10x the reference 90 floor (ISSUE 15)
-        baseline=90,
+        threshold=90,
         pod_capacity=32768,
         ops=[
             CreateNodes(init_nodes,
@@ -392,8 +384,7 @@ def preferred_pod_anti_affinity(init_nodes=5000, init_pods=1000,
                                 measure_pods=5000) -> Workload:
     return Workload(
         name="SchedulingPreferredPodAntiAffinity/5000Nodes_5000Pods",
-        threshold=900,   # ratcheted: 10x the reference 90 floor (ISSUE 15)
-        baseline=90,
+        threshold=90,
         pod_capacity=32768,
         ops=[
             CreateNodes(init_nodes,
@@ -849,8 +840,7 @@ def ns_selector_preferred_affinity(init_nodes=5000, init_namespaces=100,
     return Workload(
         name="SchedulingPreferredAffinityWithNSSelector"
              "/5000Nodes_5000Pods",
-        threshold=900,   # ratcheted: 10x the reference 90 floor (ISSUE 15)
-        baseline=90,
+        threshold=90,
         pod_capacity=32768,
         warm_full_nodes=True,   # hostname topology: domains = nodes
         ops=[
@@ -937,8 +927,7 @@ def preferred_topology_spreading(init_nodes=5000, init_pods=5000,
                                  measure_pods=5000) -> Workload:
     return Workload(
         name="PreferredTopologySpreading/5000Nodes_5000Pods",
-        threshold=1250,  # ratcheted: 10x the reference 125 floor (ISSUE 15)
-        baseline=125,
+        threshold=125,
         pod_capacity=32768,
         ops=[
             CreateNodes(init_nodes, lambda i: _node(
@@ -1005,7 +994,7 @@ def unschedulable_qhints(init_nodes=5000, init_pods=100,
                          measure_pods=10000) -> Workload:
     w = unschedulable(init_nodes, init_pods, measure_pods)
     w.name = "Unschedulable/5kNodes_100Init_10kPods_QueueingHintsEnabled"
-    w.threshold = w.baseline = 170
+    w.threshold = 170
     w.feature_gates = {"SchedulerQueueingHints": True}
     return w
 
@@ -1020,7 +1009,7 @@ def scheduling_basic_qhints(init_nodes=5000, init_pods=1000,
                             measure_pods=10000) -> Workload:
     w = scheduling_basic(init_nodes, init_pods, measure_pods)
     w.name = "SchedulingBasic/5000Nodes_10000Pods_QueueingHintsEnabled"
-    w.threshold = w.baseline = 270
+    w.threshold = 270
     w.feature_gates = {"SchedulerQueueingHints": True}
     return w
 
@@ -1062,8 +1051,7 @@ def ns_selector_preferred_anti_affinity(init_nodes=5000, init_pods=1000,
     return Workload(
         name="SchedulingPreferredAntiAffinityWithNSSelector"
              "/5000Nodes_2000Pods",
-        threshold=550,   # ratcheted: 10x the reference 55 floor (ISSUE 15)
-        baseline=55,
+        threshold=55,
         pod_capacity=32768,
         warm_full_nodes=True,   # hostname topology: domains = nodes
         ops=[
@@ -1086,7 +1074,7 @@ def ns_selector_preferred_anti_affinity(init_nodes=5000, init_pods=1000,
 # tenants, quota exhaustion that must not starve other tenants, and
 # priority preemption of whole gangs. No reference floors exist for
 # these — the thresholds are OUR floors, set from the first measured
-# round and ratcheted like the rest of the table. All three carry a
+# round. All three carry a
 # ``rescale`` hook: op counts must stay gang-aligned, so the harness's
 # uniform per-op warmup scaling would strand partial gangs behind
 # min_member; the factory rebuilds the whole workload at the requested
@@ -1204,16 +1192,10 @@ def gang_preemption(init_nodes=128, high_gangs=24) -> Workload:
 
     return Workload(
         name="GangPreemption/128Nodes",
-        # ratcheted off the r15 lock (220) by pipelined waves
-        # (BENCH_r19): preemptor re-probes ride the next wave the
-        # moment the eviction flush fires (activation instead of
-        # backoff routing), attacking exactly the victim-drain-latency
-        # residue r15 documented. Paired same-box A/B best-of-3 reads
-        # 5.19x (on 421.0 vs off 81.2 pods/s; even the WORST on-arm
-        # sample beats the best off-arm 3.7x, and the win is wait
-        # elimination, not CPU, so it does not ride the box's throttle)
-        threshold=800,
-        baseline=30,
+        # preemptor re-probes ride the next wave the moment the
+        # eviction flush fires (activation instead of backoff routing),
+        # so a gang does not wait out a backoff for its victims' drain
+        threshold=30,
         node_capacity=256,
         batch_size=512,
         ops=[
@@ -1301,13 +1283,9 @@ def gang_topology_packing(init_nodes=96, zones=8, gangs=8) -> Workload:
             gangs=max(2, int(gangs * s))))
 
 
-# every thresholded reference workload — bench.py runs the whole list,
-# one subprocess each, and publishes every row in its JSON (bench.py
-# mirrors these BY NAME in BENCH_WORKLOAD_FNS —
-# tests/test_perf_harness.py asserts the two stay in sync). The first
-# five are the BASELINE.json headline configs; the last three are the
-# VERDICT r05 "still unmeasured" thresholded variants.
-BENCH_WORKLOADS = (
+# every thresholded reference workload; the first five are the
+# BASELINE.json headline configs
+ALL_WORKLOADS = (
     scheduling_basic,
     scheduling_node_affinity,
     scheduling_pod_anti_affinity,
@@ -1339,32 +1317,4 @@ BENCH_WORKLOADS = (
     quota_exhaustion_churn,
     gang_preemption,
     gang_topology_packing,
-)
-
-ALL_WORKLOADS = BENCH_WORKLOADS
-
-# the ROADMAP's sub-10x offenders — the `bench.py --profile` set: each
-# runs with the flight recorder's phase attribution in the artifact.
-# Both DRA steady-state rows ride along so the batched device allocator
-# (ops/dra.py) keeps its host-tail collapse visible per phase.
-PROFILE_WORKLOADS = (
-    "scheduling_daemonset",
-    "mixed_churn",
-    # the preferred-scoring band (ISSUE 15): soft terms now run fused in
-    # the auction — the per-phase rows prove the host tail stays burned
-    # down
-    "preferred_pod_anti_affinity",
-    "preferred_topology_spreading",
-    "ns_selector_preferred_affinity",
-    "ns_selector_preferred_anti_affinity",
-    "dra_steady_state",
-    "dra_steady_state_templates",
-    # the whole gang suite rides the per-phase attribution + the
-    # DeviceProfiler's device column (ISSUE-12: launches per gang must
-    # read O(1), gang-shape compiles attributed); bench --profile
-    # additionally runs the fanout smoke for the fabric-side numbers
-    "multi_tenant_gang_storm",
-    "quota_exhaustion_churn",
-    "gang_preemption",
-    "gang_topology_packing",
 )

@@ -9,8 +9,8 @@ Replay semantics:
   scheduler's own ``on_step`` callback plus short idle waits, and the
   driver records how far injection fell behind the recorded schedule
   (``pacing.max_lag_s``). When the box can't hold the schedule the
-  report says ``hardware_limited`` honestly (the bench --scaleout
-  convention) instead of letting the lag silently poison the verdict.
+  report says ``hardware_limited`` honestly instead of letting the lag
+  silently poison the verdict.
 
 - SLOs are evaluated in TRACE time: measured wall time-to-bind × speed.
   Waits engineered by the trace (an outage window, a quota turn) are
@@ -391,7 +391,7 @@ def replay_trace(trace: Trace, speed: float = 10.0, warmup: bool = True,
             "max_lag_s": round(max_lag[0], 3),
             "held": max_lag[0] <= 1.0,
             # 1-core boxes cannot pace injection against a busy drain
-            # loop — same honesty rule as bench --scaleout
+            # loop
             "hardware_limited": (os.cpu_count() or 1) < 2
             or max_lag[0] > 1.0,
         },

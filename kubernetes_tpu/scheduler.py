@@ -101,6 +101,17 @@ QUARANTINE_STRIKES = 3
 QUARANTINE_BASE_S = 5.0
 QUARANTINE_CAP_S = 300.0
 
+# brownout (overload self-protection, entered by _evaluate_brownout):
+# the effective batch shrinks to
+# max(batch_size // BROWNOUT_BATCH_DIVISOR, BROWNOUT_BATCH_FLOOR) (never
+# above the configured batch), the drift sentinel stretches its cadence
+# by BROWNOUT_DRIFT_STRETCH, and best-effort tenants (weight <
+# BROWNOUT_BESTEFFORT_WEIGHT) are parked in the jobqueue
+BROWNOUT_BATCH_DIVISOR = 4
+BROWNOUT_BATCH_FLOOR = 8
+BROWNOUT_DRIFT_STRETCH = 4.0
+BROWNOUT_BESTEFFORT_WEIGHT = 0.25
+
 
 class DeviceFault(RuntimeError):
     """The fused device launch produced untrustworthy output (guard
@@ -289,10 +300,6 @@ class Scheduler:
 
             self.autopsy = AutopsyStore(
                 _autopsy_dir,
-                max_bundles=getattr(self.config,
-                                    "autopsy_max_bundles", 32),
-                max_bytes=getattr(self.config, "autopsy_max_bytes",
-                                  16 * 1024 * 1024),
                 rate_limit_s=getattr(self.config,
                                      "autopsy_rate_limit_s", 30.0),
                 now=now, metrics=self.metrics)
@@ -1806,8 +1813,7 @@ class Scheduler:
         # heuristic like pipeline.scan_unroll: on accelerators the
         # auction's few big fused rounds beat B sequential scan steps;
         # on CPU the soft-serial scan's small per-step kernels beat the
-        # auction's bandwidth-bound [B, N] rounds — measured both ways
-        # on the preferred band (BENCH_r15).
+        # auction's bandwidth-bound [B, N] rounds.
         soft_auction = spec.topo_soft and jax.default_backend() != "cpu"
         use_auction = (not pct
                        and (not spec.enable_topology or soft_auction)
@@ -2712,11 +2718,6 @@ class Scheduler:
                 tr.add("device_compile", launch_s)
         tr.scheduled = n - n_fail
         tr.failed = n_fail
-        # device occupancy: launch-in-flight fraction of this cycle's
-        # wall (dispatch open -> commit done). 1.0 = the device never
-        # sat idle waiting on host work — the pipelining headline.
-        tr.occupancy = max(0.0, min(1.0, launch_s / max(
-            self.now() - (t_dispatched - pack_s), 1e-9)))
         self.flight.record(tr)
         m = self.metrics
         m.algorithm_duration.observe(launch_s)
@@ -3517,8 +3518,8 @@ class Scheduler:
         cfg = self.config
         if not self.brownout:
             return cfg.batch_size
-        return max(cfg.batch_size // max(cfg.brownout_batch_divisor, 1),
-                   min(cfg.brownout_batch_floor, cfg.batch_size))
+        return max(cfg.batch_size // BROWNOUT_BATCH_DIVISOR,
+                   min(BROWNOUT_BATCH_FLOOR, cfg.batch_size))
 
     def _evaluate_brownout(self) -> None:
         """Watch the hub client's 429 counter and shed our own load
@@ -3562,12 +3563,10 @@ class Scheduler:
         # tests and operators retune drift_check_interval post-init
         self._drift_interval_base = self.drift_check_interval
         if self.drift_check_interval > 0:
-            self.drift_check_interval *= max(cfg.brownout_drift_stretch,
-                                             1.0)
+            self.drift_check_interval *= BROWNOUT_DRIFT_STRETCH
         parked: list[str] = []
         if self.jobqueue.active:
-            parked = self.jobqueue.park_below(
-                cfg.brownout_besteffort_weight)
+            parked = self.jobqueue.park_below(BROWNOUT_BESTEFFORT_WEIGHT)
         self.metrics.brownout.set(1.0)
         self.metrics.brownout_transitions.inc(phase="enter")
         logger.warning(

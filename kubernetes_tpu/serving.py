@@ -130,9 +130,6 @@ class ServingEndpoints:
                         "phases": flight.phase_percentiles(),
                         "host_tail_share": round(
                             flight.host_tail_share(), 4),
-                        # pipelined waves: device-occupancy distribution
-                        # (per-cycle launch span / cycle wall)
-                        "occupancy": flight.occupancy_stats(),
                         # the device-launch profiler rides the trace
                         # surface: compiles per bucket shape, recompile
                         # causes, resident HBM buffer bytes

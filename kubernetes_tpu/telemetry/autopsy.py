@@ -8,7 +8,7 @@ the journal suffix advances, /debug surfaces show only the present.
 The :class:`AutopsyStore` freezes that evidence to disk as ONE atomic
 JSON bundle per incident:
 
-* the flight-recorder ring suffix + phase percentiles + occupancy,
+* the flight-recorder ring suffix + phase percentiles,
 * the last-K pod timelines (events, wire stamps, joined latency),
 * the hub journal's ``list_changes`` suffix,
 * queue / gang / job-queue debug snapshots + the stats dict,
@@ -211,7 +211,6 @@ def collect_bundle(sched, trigger: dict) -> dict:
             "cycles": flight.last(RING_SUFFIX_CYCLES),
             "phases": flight.phase_percentiles(),
             "host_tail_share": round(flight.host_tail_share(), 4),
-            "occupancy": flight.occupancy_stats(),
         })
     timelines = getattr(sched, "timelines", None)
     if timelines is not None:

@@ -24,7 +24,8 @@ and execution indistinguishably. This instrument attributes it:
 Surfaced as ``scheduler_device_*`` metrics, the ``device_compile``
 flight-recorder view phase (a compiling launch's walltime, double-
 counted next to ``device_launch`` on purpose — the attribution view
-discipline from the DRA phases), and the ``--profile`` device column.
+discipline from the DRA phases), and the ``device`` block of
+``/debug/trace`` and of a ``perf/harness.run_workload`` result.
 """
 
 from __future__ import annotations
@@ -80,7 +81,8 @@ def _diff_cause(prev: Optional[tuple], cur: tuple) -> str:
 class DeviceProfiler:
     """Per-scheduler launch profiler. Single-threaded like the flight
     recorder (note_launch runs on the scheduling-loop thread only);
-    readers (`/debug/trace`, bench --profile) take cheap snapshots."""
+    readers (`/debug/trace`, `perf/harness.run_workload`) take cheap
+    snapshots."""
 
     MAX_COMPILE_EVENTS = 256              # bounded ring discipline (PR 4)
 
@@ -166,7 +168,7 @@ class DeviceProfiler:
     # ------------- reading -------------
 
     def snapshot(self, events: int = 16) -> dict:
-        """The /debug + --profile payload."""
+        """The /debug/trace and perf-harness payload."""
         def label(shape: tuple) -> str:
             d = dict(shape)
             base = (f"b={d.get('b')} nodes={d.get('nodes')} "

@@ -20,10 +20,10 @@ Two generations of tracing live here:
   instants (name, start, end, thread, cycle or loop turn) and delivers
   ``(phase, secs)`` through ``CycleTrace.add`` / ``observe_phase``
   exactly once, at the instant the span ends, on the thread it ran on.
-  The recorder's overhead budget is <2% of p50 cycle time (enforced by
-  ``bench.py --trace-overhead``): a span is two clock reads, one dict
-  write and one list append; while a JAX profiler trace is being taken
-  it is also a ``jax.profiler.TraceAnnotation`` of the same name.
+  The recorder's overhead budget is <2% of p50 cycle time (not yet
+  measured on the chip's host, ROADMAP S7): a span is two clock reads,
+  one dict write and one list append; while a JAX profiler trace is
+  being taken it is also a ``jax.profiler.TraceAnnotation`` of the same name.
 
 - ``PodTimelines``: per-pod lifecycle stamps (enqueue, pop/attempt,
   assume, bind, parks) plus the last unschedulable diagnosis (which
@@ -44,11 +44,10 @@ from typing import Callable, Optional
 
 logger = logging.getLogger("kubernetes_tpu.trace")
 
-# canonical cycle phases, in rough hot-path order. Host-tail share (the
-# bench --profile headline) is the HOST_PHASES fraction of total cycle
-# time; the dra_* phases are VIEWS (the DynamicResources slices of
-# pack/host_plugins/commit), not disjoint phases, so they are excluded
-# from the share arithmetic.
+# canonical cycle phases, in rough hot-path order. Host-tail share is
+# the HOST_PHASES fraction of total cycle time; the dra_* phases are
+# VIEWS (the DynamicResources slices of pack/host_plugins/commit), not
+# disjoint phases, so they are excluded from the share arithmetic.
 CYCLE_PHASES = (
     "queue_pop",          # pop_batch + per-pod hub vetting
     "snapshot_sync",      # cache.update_snapshot + mirror.sync (H2D pack)
@@ -147,8 +146,8 @@ OVERLAP_PHASES = ("commit_pull",)
 # everything excluded from the serial-cycle-time arithmetic
 EXCLUDED_PHASES = VIEW_PHASES + OVERLAP_PHASES
 
-# what CycleTrace.total(), host_tail_share() and bench.py's phase totals
-# leave out: the views, the overlap and the loop-level phases
+# what CycleTrace.total() and host_tail_share() leave out: the views,
+# the overlap and the loop-level phases
 UNCOUNTED_PHASES = frozenset(EXCLUDED_PHASES + LOOP_PHASES)
 
 # trace-export JSON-lines format version (CycleTrace.to_dict "v"):
@@ -227,7 +226,7 @@ class CycleTrace:
 
     __slots__ = ("cycle", "start", "pods", "scheduled", "failed",
                  "chained", "phases", "spans", "plugins", "placements",
-                 "occupancy", "depth")
+                 "depth")
 
     def __init__(self, cycle: int, start: float, pods: int,
                  chained: bool = False):
@@ -237,11 +236,6 @@ class CycleTrace:
         self.scheduled = 0
         self.failed = 0
         self.chained = chained
-        # device occupancy: fraction of this cycle's wall (dispatch ->
-        # finish) with its launch in flight — the pipelining instrument
-        # (1.0 = the device never waited on host commit work). None until
-        # the cycle finishes; stays None for host-fallback cycles.
-        self.occupancy: float | None = None
         # pipeline depth observed right after this cycle dispatched
         # (how many waves were in flight, the stall detector)
         self.depth = 0
@@ -282,8 +276,6 @@ class CycleTrace:
             "spans": [[n, round(a, 6), round(b, 6), names.get(t, t)]
                       for n, a, b, t in self.spans],
         }
-        if self.occupancy is not None:
-            d["occupancy"] = round(self.occupancy, 4)
         if self.plugins:
             d["plugins_ms"] = {k: round(v * 1e3, 3)
                                for k, v in self.plugins.items()}
@@ -397,11 +389,6 @@ class FlightRecorder:
         except ImportError:          # a control-plane process without JAX
             TraceAnnotation = None
         self._annotation = TraceAnnotation if self.enabled else None
-        # device-occupancy ring (floats, same capacity): record() copies
-        # each finished cycle's occupancy here so occupancy_stats() needn't
-        # walk CycleTrace objects under the readers' snapshot
-        self._occ: collections.deque = collections.deque(
-            maxlen=max(1, capacity))
         self.current: Optional[CycleTrace] = None
         self._cycle_seq = 0
         self._export_path = export_path
@@ -494,8 +481,6 @@ class FlightRecorder:
         if self.current is tr:
             self.current = None
         self.ring.append(tr)
-        if tr.occupancy is not None:
-            self._occ.append(tr.occupancy)
         h = self.phase_hist
         if h is not None:
             for phase, secs in tr.phases.items():
@@ -534,23 +519,6 @@ class FlightRecorder:
                 pass
             self._export_file = None
 
-    def occupancy_stats(self) -> dict:
-        """Device-occupancy summary over the ring: mean/p50/p99 fraction
-        of cycle wall with a launch in flight. The pipelining headline —
-        a mean near 1.0 means commit work fully overlapped device time;
-        strict alternation (pipelined_waves off) sits at launch/(launch +
-        commit). Empty dict when no device cycle has finished yet."""
-        vals = sorted(self._occ)
-        n = len(vals)
-        if n == 0:
-            return {}
-        return {
-            "n": n,
-            "mean": round(sum(vals) / n, 4),
-            "p50": round(vals[n // 2], 4),
-            "p99": round(vals[min(n - 1, int(n * 0.99))], 4),
-        }
-
     def observe_phase(self, phase: str, secs: float) -> None:
         """A standalone phase observation outside a cycle (binder drain
         between cycles, eviction flush, the host-fallback path)."""
@@ -585,7 +553,7 @@ class FlightRecorder:
             self._export_file.close()
             self._export_file = None
 
-    # ------------- reading (/debug/trace, bench --profile) -------------
+    # ------------- reading (/debug/trace, perf/harness) -------------
 
     def last(self, n: int = 32) -> list[dict]:
         if n <= 0:        # [-0:] would be the WHOLE ring, not none of it
@@ -628,7 +596,7 @@ class FlightRecorder:
     def plugin_percentiles(self) -> dict:
         """{"plugin/point": {p50_ms, p99_ms, count, total_s}} from the
         per-plugin histogram — the host-plugin / DRA-allocator slice of
-        the bench --profile breakdown."""
+        the per-phase breakdown."""
         h = self.plugin_hist
         if h is None:
             return {}
@@ -768,8 +736,8 @@ class PodTimelines:
 
     def bind_latencies(self) -> dict[str, float]:
         """uid -> first-enqueued → first-bound seconds for every tracked
-        pod that bound — the ONE time-to-bind pass behind both the bench
-        quality rows and the scenario replay driver's SLO gate
+        pod that bound — the ONE time-to-bind pass behind both the perf
+        harness's quality rows and the scenario replay driver's SLO gate
         (telemetry.slo). Pods that never bound (or whose enqueue stamp
         was LRU-evicted) are absent; callers that need full coverage
         size the timelines to the workload (config.timelines_capacity)."""

@@ -774,7 +774,7 @@ def test_device_fault_storm_ladder_and_quarantine():
     """The device-fault storm gate, small: injected launch errors +
     NaN-poisoned results + a genuine poison pod; every healthy pod
     binds, the poison pod is quarantined with a hub Event, zero daemon
-    deaths (bench.py --chaos-smoke runs the full battery)."""
+    deaths (``chaos --storm all`` runs the full battery)."""
     from kubernetes_tpu.chaos import run_device_storm
 
     report = run_device_storm(pods=24, nodes=4, seed=11)
@@ -859,7 +859,7 @@ def test_quarantine_holds_through_informer_updates():
 @pytest.mark.slow
 def test_chaos_smoke_storm():
     """scheduler + kubemark hollow nodes through the proxy under call
-    faults, watch cuts, and a partition (bench.py --chaos-smoke's gate)."""
+    faults, watch cuts, and a partition (``chaos --storm smoke``)."""
     from kubernetes_tpu.chaos import run_smoke
 
     report = run_smoke(pods=30, nodes=6, seed=7)
@@ -871,7 +871,7 @@ def test_chaos_smoke_storm():
 def test_chaos_crash_storm():
     """The acceptance storm, scaled down for the suite: device faults +
     watch cuts + leader kill + kill-and-restart; every pod bound exactly
-    once, poison quarantined, zero daemon deaths (bench.py --chaos-smoke
+    once, poison quarantined, zero daemon deaths (``chaos --storm crash``
     runs it at >=1k pods)."""
     from kubernetes_tpu.chaos import run_crash_storm
 
@@ -885,7 +885,7 @@ def test_chaos_gang_storm():
     """Gang atomicity under leader kill mid-commit, scaled down for the
     suite: every gang lands fully or not at all (zero partial gangs on
     the bind ledger), no duplicate binds, no leaked assumed pods
-    (bench.py --chaos-smoke runs it at full size)."""
+    (``chaos --storm gang`` runs it at full size)."""
     from kubernetes_tpu.chaos import run_gang_storm
 
     report = run_gang_storm(gangs=6, nodes=10, seed=17, timeout_s=150.0)

@@ -389,6 +389,19 @@ def test_cli_validate_only(tmp_path):
     assert main(["--config", str(path), "--validate-only"]) == 1
 
 
+def test_config_document_with_a_retired_key_still_loads():
+    """A document written for an older release may carry `parallelism`
+    (loaded and read by nothing, then removed): it is ignored like every
+    unknown key, and the rest of the document takes effect."""
+    from kubernetes_tpu.config.load import config_from_dict
+    from kubernetes_tpu.config.validation import validate_config
+
+    cfg = config_from_dict({"parallelism": 0, "batch_size": 32})
+    assert cfg.batch_size == 32
+    assert not hasattr(cfg, "parallelism")
+    assert validate_config(cfg) == []
+
+
 def test_feature_gates():
     """Gates toggle hint consultation and async preemption; unknown gates
     fail validation."""

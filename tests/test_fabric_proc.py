@@ -6,7 +6,7 @@ Most tests run the REAL wire with in-thread shard servers (the routing
 and cursor logic is identical; threads keep tier-1 fast); the
 subprocess tests spawn actual OS processes — a seconds-scale
 two-process smoke stays tier-1, the storm-scale batteries are
-slow-marked (bench.py --fanout-smoke / chaos --storm proc run them at
+slow-marked (fabric.fanout --procs / chaos --storm proc run them at
 full size).
 """
 
@@ -747,7 +747,7 @@ def test_two_process_smoke(tmp_path):
 @pytest.mark.slow
 def test_fanout_smoke_procs_small():
     """The process-mode storm battery at reduced scale (the full 50k
-    run is bench.py --fanout-smoke's procs column)."""
+    run is ``python -m kubernetes_tpu.fabric.fanout --procs``)."""
     from kubernetes_tpu.fabric.fanout import run_fanout_smoke_procs
 
     r = run_fanout_smoke_procs(subscribers=200, pods=40, churn=20,
@@ -763,7 +763,7 @@ def test_fanout_smoke_procs_small():
 @pytest.mark.slow
 def test_proc_crash_storm_small():
     """Process-level kill -9 + WAL-replay chaos (the full battery is
-    chaos --storm proc / bench.py --chaos-smoke)."""
+    ``chaos --storm proc``)."""
     from kubernetes_tpu.chaos import run_proc_crash_storm
 
     r = run_proc_crash_storm(pods=80, nodes=8, timeout_s=180)

@@ -5,7 +5,7 @@ NotLeader redirects, and the retry-idempotency audit.
 Everything here is in-thread (real HTTP, real Raft-lite RPCs, fast
 election timeouts) and runs at seconds scale in tier-1; the kill -9
 storm batteries live in ``chaos --storm state`` and the fanout procs
-smoke (slow-marked / bench-gated).
+smoke (slow-marked).
 """
 
 from __future__ import annotations
@@ -366,8 +366,7 @@ def test_log_compaction_bounds_wal_and_snapshot_install(tmp_path):
 @pytest.mark.slow
 def test_state_storm_small():
     """The replicated-state kill -9 battery at reduced scale (the full
-    300-pod run is ``chaos --storm state`` inside bench.py
-    --chaos-smoke's 'all')."""
+    300-pod run is ``chaos --storm state``)."""
     from kubernetes_tpu.chaos import run_state_storm
 
     r = run_state_storm(pods=80, nodes=8, timeout_s=180)

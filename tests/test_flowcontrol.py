@@ -282,11 +282,11 @@ def test_flow_metrics_round_trip_strict_parser():
 # ------------------------------------------------------------------
 
 
-def _brownout_scheduler(threshold: int = 5):
+def _brownout_scheduler(threshold: int = 5, batch: int = 64):
     hub = Hub()
     hub.create_node(MakeNode().name("n1").capacity(cpu="64").obj())
     cfg = default_config()
-    cfg.batch_size = 64
+    cfg.batch_size = batch
     cfg.brownout_throttle_threshold = threshold
     cfg.brownout_clear_windows = 2
     cfg.tenants = {"prio": {"weight": 8.0}, "scav": {"weight": 0.1}}
@@ -334,6 +334,24 @@ def test_brownout_enters_shrinks_and_recovers():
         text = sched.metrics.registry.render_text()
         assert 'scheduler_brownout_transitions_total{phase="enter"}' \
             in text
+    finally:
+        sched.close()
+
+
+@pytest.mark.parametrize("batch, shrunk", [
+    (4096, 1024),   # the divisor: a quarter of the configured batch
+    (16, 8),        # the floor: a quarter would be 4
+    (8, 8),         # never above the configured batch
+])
+def test_brownout_batch_is_a_quarter_but_not_under_the_floor(batch, shrunk):
+    sched, throttled = _brownout_scheduler(batch=batch)
+    try:
+        assert sched._effective_batch() == batch
+        _tick_brownout(sched)
+        throttled["n"] += 20
+        _tick_brownout(sched)
+        assert sched.brownout
+        assert sched._effective_batch() == shrunk
     finally:
         sched.close()
 

@@ -1,8 +1,8 @@
 """Perf harness unit tests (tiny scales, fake-free real clock): op DSL
-execution, collector windowing/percentiles, churn injection, threshold
-verdicts — the rung the reference covers with scheduler_perf's own
-integration-test label (misc/performance-config.yaml workloads labeled
-integration-test run tiny through the same driver)."""
+execution, collector windowing/percentiles, churn injection — the rung
+the reference covers with scheduler_perf's own integration-test label
+(misc/performance-config.yaml workloads labeled integration-test run
+tiny through the same driver)."""
 
 from kubernetes_tpu.perf.collector import ThroughputCollector, percentile
 from kubernetes_tpu.perf.harness import (
@@ -63,7 +63,7 @@ def test_scheduling_basic_tiny():
     r = run_workload(w)
     assert r["pods_scheduled"] == 10
     assert r["stats"]["scheduled"] == 12
-    assert "vs_baseline" in r and "passed" in r
+    assert r["pods_per_sec"] > 0
 
 
 def test_all_workload_defs_have_thresholds():
@@ -202,17 +202,6 @@ def test_ns_selector_anti_affinity_tiny():
     assert r["stats"]["scheduled"] == 8
 
 
-def test_bench_workload_names_in_sync():
-    """bench.py names its subprocess workloads; they must be exactly
-    workloads.BENCH_WORKLOADS (by function name) or a new bench workload
-    silently never runs."""
-    from kubernetes_tpu.perf.workloads import BENCH_WORKLOADS
-
-    bench = _load_bench()
-    assert tuple(bench.BENCH_WORKLOAD_FNS) == tuple(
-        f.__name__ for f in BENCH_WORKLOADS)
-
-
 def test_dra_steady_state_tiny():
     from kubernetes_tpu.perf.workloads import dra_steady_state
 
@@ -244,33 +233,11 @@ def test_dra_multi_request_tiny():
     assert r["stats"]["unschedulable"] == 0
 
 
-def _load_bench():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "bench", os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    return bench
-
-
-def test_profile_workload_names_in_sync():
-    """bench.py --profile names its offender set; it must be exactly
-    workloads.PROFILE_WORKLOADS or a profiled workload silently drops."""
-    from kubernetes_tpu.perf.workloads import PROFILE_WORKLOADS
-
-    bench = _load_bench()
-    assert tuple(bench.PROFILE_WORKLOAD_FNS) == tuple(PROFILE_WORKLOADS)
-
-
 def test_run_workload_profile_breakdown():
-    """profile=True: the result carries the flight recorder's per-phase
-    p50/p99 (incl. the dra_* views when DRA plugins ran) and the
-    host-tail share — what bench.py --profile publishes per offender."""
+    """The result carries the flight recorder's per-phase p50/p99 (incl.
+    the dra_* views when DRA plugins ran) and the host-tail share."""
     w = small(scheduling_basic(init_nodes=4, init_pods=2, measure_pods=10))
-    r = run_workload(w, profile=True)
+    r = run_workload(w)
     fl = r["flight"]
     assert fl["enabled"] and fl["cycles_recorded"] >= 1
     for phase in ("queue_pop", "device_launch", "commit"):
@@ -279,18 +246,6 @@ def test_run_workload_profile_breakdown():
         assert fl["phases"][phase]["p99_ms"] >= fl["phases"][phase]["p50_ms"]
     assert fl["plugins"], "per-plugin timings present"
     assert 0.0 <= fl["host_tail_share"] <= 1.0
-
-
-def test_run_workload_cycle_times_capture():
-    """cycle_times collects exact raw per-cycle durations (the
-    --trace-overhead arms compare medians of these, not
-    bucket-quantized histogram reads)."""
-    w = small(scheduling_basic(init_nodes=4, init_pods=2, measure_pods=10))
-    times = []
-    r = run_workload(w, cycle_times=times)
-    assert len(times) >= 1
-    assert all(t >= 0.0 for t in times)
-    assert r["pods_scheduled"] == 10
 
 
 def test_qhints_variant_tiny():
@@ -403,77 +358,6 @@ def test_measurement_path_refuses_a_device_fallback():
     clean = Scheduler(Hub(), cfg, caps=Capacities(nodes=16, pods=64))
     clean.close()
     assert_device_path(clean)
-
-
-def _stub_run_one(rows: dict):
-    """A subprocess.run stand-in for bench.py's per-workload run_one
-    children: ``rows`` maps a workload fn to a result dict (exit 0), an
-    int (that exit code, no row) or "timeout"."""
-    import json
-    import subprocess
-
-    def run(cmd, **kw):
-        fn = cmd[cmd.index("kubernetes_tpu.perf.run_one") + 1]
-        row = rows[fn]
-        if row == "timeout":
-            raise subprocess.TimeoutExpired(cmd, kw.get("timeout"))
-        if isinstance(row, int):
-            return subprocess.CompletedProcess(cmd, row, "", "Traceback: x")
-        return subprocess.CompletedProcess(
-            cmd, 0, "noise\n" + json.dumps(row) + "\n", "")
-
-    return run
-
-
-_BASIC_ROW = {
-    "name": "SchedulingBasic/5000Nodes_10000Pods", "pods_per_sec": 12.5,
-    "threshold": 270, "vs_baseline": 0.05, "passed": False,
-    "pods_scheduled": 10000, "elapsed_s": 800.0,
-    "stats": {"device_fallbacks": 0}, "measured_compiles": 0,
-    "warm_s": 1.0, "run_s": 2.0,
-    "platform": "cpu", "device_kind": "cpu", "device_count": 8,
-    "device_fallbacks": 0}
-
-
-def test_bench_rows_keep_the_device_and_fallback_columns():
-    bench = _load_bench()
-    results, headline, failed = bench.run_workloads(
-        ("scheduling_basic",), ["--scale", "1.0"], {},
-        run=_stub_run_one({"scheduling_basic": _BASIC_ROW}))
-    assert failed == [] and headline == _BASIC_ROW
-    row = results["SchedulingBasic"]
-    for key in bench.ROW_DEVICE_KEYS:
-        assert row[key] == _BASIC_ROW[key]
-    assert row["measured_compiles"] == 0
-    assert "stats" not in row           # still a whitelist
-
-
-def test_bench_exits_nonzero_naming_failed_and_timed_out_workloads(
-        monkeypatch, capsys):
-    """A failed or timed-out workload no longer vanishes from a green
-    bench: the rest are measured first, then the exit code is non-zero
-    and stderr names the holes."""
-    import json
-    import sys
-
-    bench = _load_bench()
-    monkeypatch.setattr(bench, "BENCH_WORKLOAD_FNS",
-                        ("boom", "scheduling_basic", "wedged"))
-    monkeypatch.setattr(bench.subprocess, "run", _stub_run_one(
-        {"boom": 1, "scheduling_basic": _BASIC_ROW, "wedged": "timeout"}))
-    monkeypatch.setattr(sys, "argv", ["bench.py", "--no-test-gate"])
-    with pytest.raises(SystemExit) as e:
-        bench.main()
-    assert e.value.code == 1
-    out = capsys.readouterr()
-    assert "FAILED or TIMED OUT: boom, wedged" in out.err
-    # the surviving row was still measured and published, device named
-    summary = json.loads(out.out.strip().splitlines()[-1])
-    assert summary["platform"] == "cpu" and summary["device_count"] == 8
-    assert list(summary["workloads"]) == ["SchedulingBasic"]
-    # all green: main() returns normally
-    monkeypatch.setattr(bench, "BENCH_WORKLOAD_FNS", ("scheduling_basic",))
-    bench.main()
 
 
 # suite-tier discipline (tests/test_markers.py): area marker

@@ -6,8 +6,7 @@ the replicated sched-ring surviving leader failover, and an in-thread
 two-replica partition drain.
 
 Everything here runs at tier-1 speed; the 4-replica kill -9 storm is
-slow-marked (it also runs in ``chaos --storm scaleout`` and the
-``bench --chaos-smoke`` battery).
+slow-marked (it also runs in ``chaos --storm scaleout``).
 """
 
 from __future__ import annotations
