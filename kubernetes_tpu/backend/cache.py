@@ -258,12 +258,16 @@ class Cache:
         with self._lock:
             st = self._pod_states.get(uid)
             if (st is not None and st.assumed
-                    and st.pod.spec.node_name == pod.spec.node_name):
+                    and st.pod.spec.node_name == pod.spec.node_name
+                    and st.pod.metadata.labels == pod.metadata.labels):
                 # confirm on the assumed node: the NodeInfo aggregates are
                 # already right — swap the pod object in place WITHOUT
                 # bumping the node generation, so the bind confirmation does
                 # not force a second mirror row repack (the assume already
-                # did one)
+                # did one). A confirmation that brings other labels than
+                # the assumed clone's is an update below: the mirror's
+                # pod-table slot holds the labels, and only a generation
+                # bump makes it look
                 item = self._nodes.get(pod.spec.node_name)
                 if item is not None:
                     for pi in item.info.pods:
@@ -343,6 +347,7 @@ class Cache:
                 return
             snap_gen = snapshot.generation
             updated_affinity = False
+            changed: Optional[list[str]] = []
             item = self._head
             latest = snap_gen
             while item is not None and item.info.generation > snap_gen:
@@ -351,6 +356,10 @@ class Cache:
                 if info.node is not None:
                     existing = snapshot.node_info_map.get(info.name)
                     clone = info.snapshot()
+                    if existing is None:
+                        changed = None
+                    elif changed is not None:
+                        changed.append(info.name)
                     if existing is None or bool(existing.pods_with_affinity) != bool(
                         clone.pods_with_affinity
                     ) or bool(existing.pods_with_required_anti_affinity) != bool(
@@ -381,6 +390,7 @@ class Cache:
                 self._rebuild_affinity_lists(snapshot)
             snapshot.generation = latest
             snapshot.node_set_version = self._node_set_version
+            snapshot.changed_nodes = None if removed else changed
             snapshot.version += 1
 
     def _rebuild_lists(self, snapshot: Snapshot) -> None:

@@ -252,7 +252,9 @@ class Mirror:
         # topo keys referenced by any packed term/constraint (batch or table):
         # bounds the domain scatter space a launch actually needs
         self._used_tks: set[int] = set()
-        self._uids_with_terms: set[str] = set()  # table pods carrying terms
+        # table pods carrying terms: uid -> the PodInfo whose four term
+        # lists the slot was packed from (_slot_holds compares them)
+        self._uids_with_terms: dict[str, PodInfo] = {}
         # namespace store (name -> labels) for unrolling namespaceSelectors;
         # table pods whose terms carry a non-empty namespaceSelector repack
         # when the namespace set changes (sync checks ns_generation)
@@ -275,7 +277,25 @@ class Mirror:
         self.row_cache_bypass = 0
         self.row_cache_clears = 0
         self._table_i32_tmpl: np.ndarray | None = None
+        # pt_label_vals rows by the labels' content (pod_labels_row), and
+        # the offsets the term-free arm of _pack_pod_slot writes at
+        self._label_rows: dict[tuple, np.ndarray] = {}
+        tc_off = self.table_codec._i32_off
+        self._slot_scalar_off = tuple(
+            tc_off[name][0] for name in
+            ("pod_node", "pod_ns", "pod_uid", "pod_nominated"))
+        self._slot_labels_off = tc_off["pt_label_vals"]
         self._row_node_obj: dict[int, object] = {}  # row -> packed Node obj
+        # [N, R] float64 image of each row's allocatable, true while the
+        # row's Node object stands (NodeInfo.set_node derives both): the
+        # exact minuend of ``free`` (_update_rows_resources)
+        self._alloc64 = np.zeros((caps.nodes, caps.res_cols), np.float64)
+        # what sync and patch_node did, totals of the scheduler's life
+        # like the row cache's (sync_stats; adopt_hysteresis carries them)
+        self.rows_synced = 0
+        self.slots_packed = 0
+        self.slots_kept = 0
+        self.slots_released = 0
         # workload-activity tracking for launch_features(): which rows carry
         # taints / used host ports / images — a feature absent cluster-wide
         # AND batch-wide compiles out of the launch entirely
@@ -288,10 +308,11 @@ class Mirror:
         # Namespace object (AffinityTerm.Matches with empty labels.Set)
         self._known_pod_ns: set[str] = set()
         self._pod_slot: dict[str, int] = {}      # pod uid -> pod-table slot
-        self._node_pods: dict[str, dict[str, int]] = {}  # node -> uid -> slot
-        # uid -> packed Pod object, held strongly so identity comparison is a
-        # sound change detector (a bare id() could be reused after GC)
-        self._pod_obj: dict[str, Pod] = {}
+        # node -> uid -> the Pod object its slot was packed from, held
+        # strongly so identity is a sound first test (a bare id() could be
+        # reused after GC); an object of equal content is re-pointed to
+        # (_slot_holds), any other re-packs
+        self._node_pods: dict[str, dict[str, Pod]] = {}
         self._node_of_pod: dict[str, str] = {}   # uid -> node name
         self._free_slots: list[int] = list(range(caps.pods - 1, -1, -1))
         self._row_names: list[str | None] = [None] * caps.nodes
@@ -426,8 +447,8 @@ class Mirror:
         ``capacity=True`` (node allocatable, and preemption freed-amount
         rows, which add back onto capacity) rounds DOWN. Differences
         like free = alloc - requested are computed in float64 and
-        floored (_free_nzr_of): subtracting two f32 images would round
-        to NEAREST and could overstate headroom."""
+        floored (_update_rows_resources): subtracting two f32 images
+        would round to NEAREST and could overstate headroom."""
         return _round_row_f32(self._res_row64(r), up=not capacity)
 
     def _pairs(self, labels: dict[str, str], cap: int, what: str
@@ -484,21 +505,6 @@ class Mirror:
             self._free_fp = (self._last_sync, h)
         return self._free_fp[1]
 
-    def _free_nzr_of(self, info: NodeInfo,
-                     alloc64: np.ndarray | None = None
-                     ) -> tuple[np.ndarray, np.ndarray]:
-        # exact float64 difference, floored into f32: alloc_f32 - req_f32
-        # would round to NEAREST and can overstate the exact free
-        if alloc64 is None:
-            alloc64 = self._res_row64(info.allocatable)
-        free = _round_row_f32(alloc64 - self._res_row64(info.requested),
-                              up=False)
-        free[F.COL_PODS] = info.allocatable.allowed_pod_number - len(info.pods)
-        nzr = np.asarray(
-            [info.non_zero_requested.milli_cpu,
-             info.non_zero_requested.memory / MI], np.float32)
-        return free, nzr
-
     def _pack_ports(self, info: NodeInfo, f: dict[str, np.ndarray],
                     row: int | None = None) -> None:
         caps = self.caps
@@ -519,34 +525,61 @@ class Mirror:
             pn[i] = port
         f["port_ips"], f["port_protos"], f["port_nums"] = pi, pp, pn
 
-    def _update_node_row_resources(self, row: int, info: NodeInfo) -> None:
-        """Fast repack for pod-only changes (the node object itself is
-        unchanged): only free/nonzeroRequested/ports columns move, plus the
-        pod-table reconcile — the common per-cycle case, ~10x cheaper than a
-        full row repack."""
-        f: dict[str, np.ndarray] = {}
-        f["free"], f["nonzero_requested"] = self._free_nzr_of(info)
-        self._pack_ports(info, f, row)
+    def _update_rows_resources(self, rows: list[int],
+                               infos: list[NodeInfo]) -> None:
+        """What a node's pods move, for every row a sync (or one
+        patch_node) touched, in one vector pass: free and
+        nonzeroRequested, the port fields where the row has or had a host
+        port, then the pod-table reconcile and the packed generation.
+        The node objects' own fields are _pack_node_row's, which has run
+        for the rows whose Node object changed.
+
+        free is the exact float64 difference floored into f32:
+        alloc_f32 - req_f32 would round to NEAREST and can overstate the
+        exact free."""
+        if not rows:
+            return
         nc = self.node_codec
-        for name, arr in f.items():
-            kind_off = nc._f32_off.get(name)
-            if kind_off is not None:
-                off, size = kind_off
-                self.node_f32[row, off:off + size] = arr
-            else:
-                off, size = nc._i32_off[name]
-                self.node_i32[row, off:off + size] = arr
-        self._dirty_rows.add(row)
-        self._reconcile_node_pods(row, info)
+        rows_a = np.asarray(rows, np.intp)
+        req = np.zeros((len(rows), self.caps.res_cols), np.float64)
+        req[:, :F.COL_PODS] = [
+            (r.milli_cpu, r.memory / MI, r.ephemeral_storage / MI)
+            for r in [info.requested for info in infos]]
+        for i, info in enumerate(infos):
+            if info.requested.scalar:
+                for name, v in info.requested.scalar.items():
+                    req[i, self.ext_col(name)] = v
+        alloc = self._alloc64[rows_a]
+        free = _round_row_f32(alloc - req, up=False)
+        free[:, F.COL_PODS] = alloc[:, F.COL_PODS] - [
+            len(info.pods) for info in infos]
+        off, size = nc._f32_off["free"]
+        self.node_f32[rows_a, off:off + size] = free
+        off, size = nc._f32_off["nonzero_requested"]
+        self.node_f32[rows_a, off:off + size] = [
+            (z.milli_cpu, z.memory / MI)
+            for z in [info.non_zero_requested for info in infos]]
+        self._dirty_rows.update(rows)
+        for row, info in zip(rows, infos):
+            # a row that has no host port and had none keeps its three
+            # NONE port fields as they stand
+            if info.used_ports.ports or row in self._rows_with_ports:
+                f: dict[str, np.ndarray] = {}
+                self._pack_ports(info, f, row)
+                nc.pack_into(self.node_f32[row], self.node_i32[row], f)
+            self._reconcile_node_pods(row, info)
+            self._row_gen[info.name] = info.generation
 
     def _pack_node_row(self, row: int, info: NodeInfo) -> None:
+        """The full arm: every field that comes from the Node object
+        (and the port fields, so a fresh row reads NONE there). What the
+        node's pods move follows in _update_rows_resources."""
         caps = self.caps
         node = info.node
         assert node is not None
         f: dict[str, np.ndarray] = {}
-        alloc64 = self._res_row64(info.allocatable)
+        alloc64 = self._alloc64[row] = self._res_row64(info.allocatable)
         f["allocatable"] = _round_row_f32(alloc64, up=False)
-        f["free"], f["nonzero_requested"] = self._free_nzr_of(info, alloc64)
         f["nominated_req"] = self._nominated_req_of_row.get(
             row, np.zeros((caps.res_cols,), np.float32))
         f["node_valid"] = np.bool_(True)
@@ -595,31 +628,93 @@ class Mirror:
         f["image_ids"], f["image_sizes"] = ii, isz
         self.node_codec.pack_into(self.node_f32[row], self.node_i32[row], f)
         self._row_node_obj[row] = node
-        self._reconcile_node_pods(row, info)
 
     def _reconcile_node_pods(self, row: int, info: NodeInfo) -> None:
+        """Bring the node's pod-table slots to ``info.pods``: one pass
+        that packs the pods new to the node and re-packs the ones whose
+        slot would read differently; a pod whose object was replaced by
+        one of equal content (the informer's confirmation of an assumed
+        pod, Cache.add_pod) keeps its slot. The release scan runs only
+        when the counts say something left the node."""
         name = info.name
-        current = self._node_pods.setdefault(name, {})
-        live_uids = {p.pod.metadata.uid for p in info.pods}
-        for uid in list(current):
-            # nominated slots are owned by set_nominated, not the node diff
-            if uid not in live_uids and not uid.startswith("nominated:"):
-                self._release_pod_slot(uid)
+        current = self._node_pods.get(name)
+        if current is None:
+            current = self._node_pods[name] = {}
+        known = 0
+        fresh: list[PodInfo] = []
         for pi in info.pods:
-            uid = pi.pod.metadata.uid
-            if (uid not in current
-                    or self._pod_obj.get(uid) is not pi.pod):
-                # new on this node, moved here, or the pod object was replaced
-                # (update): repack. Releasing first also covers the
-                # moved-before-source-reconciled ordering.
+            pod = pi.pod
+            uid = pod.metadata.uid
+            packed = current.get(uid)
+            if packed is pod:
+                known += 1
+            elif packed is None:
+                fresh.append(pi)
+            else:
+                known += 1
+                if self._slot_holds(uid, packed, pi):
+                    current[uid] = pod
+                    self.slots_kept += 1
+                else:
+                    self._release_pod_slot(uid)
+                    self._pack_pod_slot(uid, pi, row, name)
+        # nominated slots are owned by set_nominated, not the node diff
+        if self._nominated_uids:
+            known += sum(1 for uid in current
+                         if uid.startswith("nominated:"))
+        if known != len(current):
+            live_uids = {p.pod.metadata.uid for p in info.pods}
+            for uid in [u for u in current if u not in live_uids
+                        and not u.startswith("nominated:")]:
                 self._release_pod_slot(uid)
-                self._pack_pod_slot(uid, pi, row, name)
+        for pi in fresh:
+            uid = pi.pod.metadata.uid
+            if uid in self._pod_slot:
+                # moved here before its source node was reconciled
+                self._release_pod_slot(uid)
+            self._pack_pod_slot(uid, pi, row, name)
+
+    def _slot_holds(self, uid: str, packed_pod: Pod, pi: PodInfo) -> bool:
+        """Would _pack_pod_slot write into ``uid``'s slot, packed from
+        ``packed_pod`` on the same row, the bytes it holds? Everything
+        the pack reads of a pod is compared with what it read then: the
+        namespace, the labels (the pt_label_vals row, and the values a
+        term's match_label_keys copy) and the four term lists of the
+        PodInfo. A bind sets only spec.node_name, so a confirmation
+        holds; an update that moves a label does not."""
+        old, new = packed_pod.metadata, pi.pod.metadata
+        if old.namespace != new.namespace or old.labels != new.labels:
+            return False
+        packed = self._uids_with_terms.get(uid)
+        if packed is None:
+            return not NodeInfo._has_affinity(pi)
+        return packed is pi or (
+            packed.required_anti_affinity_terms
+            == pi.required_anti_affinity_terms
+            and packed.required_affinity_terms
+            == pi.required_affinity_terms
+            and packed.preferred_affinity_terms
+            == pi.preferred_affinity_terms
+            and packed.preferred_anti_affinity_terms
+            == pi.preferred_anti_affinity_terms)
 
     def pod_labels_row(self, labels: dict[str, str]) -> np.ndarray:
-        """Labels as a pod-label-column value row [Kp] (registers keys)."""
-        row = np.full((self.caps.pod_label_cols,), NONE, np.int32)
-        for k, v in labels.items():
-            row[self.pod_label_col(k)] = self._i(v)
+        """Labels as a pod-label-column value row [Kp] (registers keys),
+        read-only: pods of one deployment share it. Kept by the labels'
+        content under _pack_batch_np's invariant (pod-label columns and
+        the interner only append for the Mirror's life, so a kept row
+        stays true and a hit may skip the registering) and bounded as
+        that cache is."""
+        key = tuple(labels.items())
+        row = self._label_rows.get(key)
+        if row is None:
+            row = np.full((self.caps.pod_label_cols,), NONE, np.int32)
+            for k, v in labels.items():
+                row[self.pod_label_col(k)] = self._i(v)
+            row.flags.writeable = False
+            if len(self._label_rows) > POD_ROW_CACHE_ENTRIES:
+                self._label_rows.clear()
+            self._label_rows[key] = row
         return row
 
     def _pack_term_group(self, pi_terms, weights, pod: Pod, prefix: str,
@@ -689,18 +784,19 @@ class Mirror:
             # template fast path: copy + patch the 5 scalar fields + labels
             dst = self.pods_i32[slot]
             dst[:] = self._table_template()
-            tc = self.table_codec
-            dst[tc._i32_off["pod_node"][0]] = row
-            dst[tc._i32_off["pod_ns"][0]] = self._i(pod.metadata.namespace)
-            dst[tc._i32_off["pod_uid"][0]] = self._i(pod.metadata.uid)
-            dst[tc._i32_off["pod_nominated"][0]] = 1 if nominated else 0
+            o_node, o_ns, o_uid, o_nominated = self._slot_scalar_off
+            intern = self.interner.intern
+            dst[o_node] = row
+            dst[o_ns] = intern(pod.metadata.namespace)
+            dst[o_uid] = intern(pod.metadata.uid)
+            dst[o_nominated] = 1 if nominated else 0
             if pod.metadata.labels:
-                off, size = tc._i32_off["pt_label_vals"]
+                off, size = self._slot_labels_off
                 dst[off:off + size] = self.pod_labels_row(pod.metadata.labels)
+            self.slots_packed += 1
             self._dirty_slots.add(slot)
             self._pod_slot[uid] = slot
-            self._node_pods[node_name][uid] = slot
-            self._pod_obj[uid] = pod
+            self._node_pods[node_name][uid] = pod
             self._node_of_pod[uid] = node_name
             return
         f: dict[str, np.ndarray] = {}
@@ -723,10 +819,10 @@ class Mirror:
             "pod_panti", f)
         empty_f32 = self.pods_i32[slot, :0].view(np.float32)
         self.table_codec.pack_into(empty_f32, self.pods_i32[slot], f)
+        self.slots_packed += 1
         self._dirty_slots.add(slot)
         self._pod_slot[uid] = slot
-        self._node_pods[node_name][uid] = slot
-        self._pod_obj[uid] = pod
+        self._node_pods[node_name][uid] = pod
         self._node_of_pod[uid] = node_name
         all_terms = (pi.required_anti_affinity_terms
                      + pi.required_affinity_terms
@@ -734,7 +830,7 @@ class Mirror:
                      + [w.pod_affinity_term
                         for w in pi.preferred_anti_affinity_terms])
         if all_terms:
-            self._uids_with_terms.add(uid)
+            self._uids_with_terms[uid] = pi
         if any(t.namespace_selector is not None
                and (t.namespace_selector.match_labels
                     or t.namespace_selector.match_expressions)
@@ -796,7 +892,7 @@ class Mirror:
     def _repack_nssel_pods(self) -> None:
         for uid in list(self._uids_with_nssel):
             node_name = self._node_of_pod.get(uid)
-            pod = self._pod_obj.get(uid)
+            pod = self._node_pods.get(node_name or "", {}).get(uid)
             row = self._row_of.get(node_name or "")
             if node_name is None or pod is None or row is None:
                 continue
@@ -880,8 +976,8 @@ class Mirror:
         self.pods_i32[slot] = 0  # pod_valid -> False, rest zeroed
         self._free_slots.append(slot)
         self._dirty_slots.add(slot)
-        self._pod_obj.pop(uid, None)
-        self._uids_with_terms.discard(uid)
+        self.slots_released += 1
+        self._uids_with_terms.pop(uid, None)
         self._uids_with_nssel.discard(uid)
         node = self._node_of_pod.pop(uid, None)
         if node is not None:
@@ -925,6 +1021,7 @@ class Mirror:
                 return None
             self._invalidate_row(name)
             self._free_fp = None
+            self.rows_synced += 1
             return (row, np.zeros((self.caps.res_cols,), np.float32),
                     np.zeros((2,), np.float32))
         if row is None:
@@ -933,14 +1030,17 @@ class Mirror:
             row = self._free_rows.pop()
             self._row_of[name] = row
             self._row_names[row] = name
+        if self._row_node_obj.get(row) is not info.node:
             self._pack_node_row(row, info)
-        elif self._row_node_obj.get(row) is info.node:
-            self._update_node_row_resources(row, info)
-        else:
-            self._pack_node_row(row, info)
-        self._row_gen[name] = info.generation
+        self._update_rows_resources([row], [info])
         self._free_fp = None
-        return (row, *self._free_nzr_of(info))
+        self.rows_synced += 1
+        # the two fields as they have just been written
+        f_off = self.node_codec._f32_off
+        off, size = f_off["free"]
+        free = self.node_f32[row, off:off + size].copy()
+        off, size = f_off["nonzero_requested"]
+        return (row, free, self.node_f32[row, off:off + size].copy())
 
     # ------------- sync -------------
 
@@ -950,9 +1050,12 @@ class Mirror:
         # O(1) no-op when the snapshot hasn't changed since the last sync of
         # this same snapshot object (Snapshot.version is bumped by every
         # mutating Cache.update_snapshot)
-        if self._last_sync == (id(snapshot), snapshot.version):
+        prev = self._last_sync
+        if prev == (id(snapshot), snapshot.version):
             return 0
-        self._last_sync = (id(snapshot), snapshot.version)
+        # stands again only once every row is through: a sync that raised
+        # (CapacityError) must not leave the next one a delta to trust
+        self._last_sync = None
         # namespace set changed: refresh the store and repack every table pod
         # whose terms carry a namespaceSelector (their unrolled ns lists are
         # stale) — the incremental analog of the reference resolving
@@ -961,14 +1064,25 @@ class Mirror:
             self._ns_gen = snapshot.ns_generation
             self._namespaces = snapshot.namespaces
             self._repack_nssel_pods()
-        live = {info.name for info in snapshot.node_info_list}
         repacked = 0
-        # removals first so a same-sync node swap can reuse the freed row
-        for name in list(self._row_of):
-            if name not in live:
-                self._invalidate_row(name)
-                repacked += 1
-        for info in snapshot.node_info_list:
+        changed = snapshot.changed_nodes
+        if changed is not None and prev == (id(snapshot),
+                                            snapshot.version - 1):
+            # exactly one refresh since this mirror's last sync of this
+            # snapshot, over an unchanged node set: the nodes it re-cloned
+            # are the only ones whose generation can have advanced
+            infos = [snapshot.node_info_map[name] for name in changed]
+        else:
+            infos = snapshot.node_info_list
+            live = {info.name for info in infos}
+            # removals first so a same-sync node swap can reuse the freed row
+            for name in list(self._row_of):
+                if name not in live:
+                    self._invalidate_row(name)
+                    repacked += 1
+        rows: list[int] = []
+        moved: list[NodeInfo] = []
+        for info in infos:
             name = info.name
             row = self._row_of.get(name)
             if row is None:
@@ -978,14 +1092,28 @@ class Mirror:
                 self._row_of[name] = row
                 self._row_names[row] = name
             if self._row_gen.get(name) != info.generation:
-                if self._row_node_obj.get(row) is info.node:
-                    # pod-only change: resources/ports fast path
-                    self._update_node_row_resources(row, info)
-                else:
+                # a pod-only change (the Node object stands) takes the
+                # resources pass alone
+                if self._row_node_obj.get(row) is not info.node:
                     self._pack_node_row(row, info)
-                self._row_gen[name] = info.generation
-                repacked += 1
+                rows.append(row)
+                moved.append(info)
+        self._update_rows_resources(rows, moved)
+        repacked += len(rows)
+        self._last_sync = (id(snapshot), snapshot.version)
+        self.rows_synced += repacked
         return repacked
+
+    def sync_stats(self) -> dict:
+        """What sync and patch_node wrote, for /debug/trace and the
+        registry: node rows repacked; pod-table slots packed, released,
+        and kept (the pod's object was replaced by one of equal content:
+        re-pointed, nothing written). A backlog of pods that bind once
+        packs one slot a pod and keeps about one a pod."""
+        return {"rows_synced": self.rows_synced,
+                "slots_packed": self.slots_packed,
+                "slots_kept": self.slots_kept,
+                "slots_released": self.slots_released}
 
     def _push(self, key: str, host_buf: np.ndarray, dirty: set[int],
               full: bool) -> None:
@@ -1063,14 +1191,18 @@ class Mirror:
         capacity re-bucket (scheduler._grow builds a FRESH mirror):
         without this a rebuilt mirror re-derives a smaller bucket from
         its still-empty domain tables and the next churn swing pays the
-        compile again. The packed-row cache's counts come along too:
-        they are totals the registry mirrors by delta, and the cache
-        itself starts empty."""
+        compile again. The packed-row cache's counts and sync_stats'
+        come along too: they are totals the registry mirrors by delta,
+        and the caches themselves start empty."""
         self._d_hw = prev._d_hw
         self.row_cache_hits = prev.row_cache_hits
         self.row_cache_misses = prev.row_cache_misses
         self.row_cache_bypass = prev.row_cache_bypass
         self.row_cache_clears = prev.row_cache_clears
+        self.rows_synced = prev.rows_synced
+        self.slots_packed = prev.slots_packed
+        self.slots_kept = prev.slots_kept
+        self.slots_released = prev.slots_released
 
     def launch_d_cap(self, enable_topology: bool) -> int:
         """The static d_cap for one launch: the domain bucket when the

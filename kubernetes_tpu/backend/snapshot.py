@@ -33,6 +33,10 @@ class Snapshot:
         # O(1) no-ops between changes
         self.version: int = 0
         self.node_set_version: int = -1
+        # names of the nodes the newest refresh (the one that made
+        # ``version``) re-cloned, or None where it added or removed a
+        # node: a Mirror that synced version - 1 visits these alone
+        self.changed_nodes: Optional[list[str]] = None
 
     # --- lister surface (snapshot.go:158-199) ---
 

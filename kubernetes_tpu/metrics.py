@@ -260,6 +260,13 @@ class SchedulerMetrics:
             "cache did: hit (a row of the same content copied), miss "
             "(packed and kept), bypass (a pod whose row is not a function "
             "of its content alone, packed every time)", ("result",)))
+        self.mirror_slots = r.register(Counter(
+            "scheduler_mirror_slot_total",
+            "Pod-table slots by what the mirror's sync did with them: "
+            "packed (a pod new to its node, or one whose content moved), "
+            "kept (the pod's object was replaced by one of equal content, "
+            "as a bind confirmation does: re-pointed, nothing written), "
+            "released (the pod left its node)", ("result",)))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

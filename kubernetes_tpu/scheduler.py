@@ -3658,6 +3658,11 @@ class Scheduler:
                           ("bypass", mirror.row_cache_bypass)):
             self._mirror_count(f"pack_row_cache:{result}", n,
                                m.pack_row_cache, result=result)
+        for result, n in (("packed", mirror.slots_packed),
+                          ("kept", mirror.slots_kept),
+                          ("released", mirror.slots_released)):
+            self._mirror_count(f"mirror_slot:{result}", n,
+                               m.mirror_slots, result=result)
         self._mirror_journal_stats()
         if self.jobqueue.active:
             for tenant, st in self.jobqueue.tenant_stats().items():

@@ -127,6 +127,10 @@ class ServingEndpoints:
                         # the mirror's packed-row cache: pods packed
                         # = hits + misses + bypass, clears at its bound
                         "pack_row_cache": sched.mirror.row_cache_stats(),
+                        # what the mirror's sync wrote: node rows, and
+                        # pod-table slots packed, kept (a confirmation
+                        # that changed nothing) and released
+                        "mirror_sync": sched.mirror.sync_stats(),
                         "phases": flight.phase_percentiles(),
                         "host_tail_share": round(
                             flight.host_tail_share(), 4),

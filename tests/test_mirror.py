@@ -529,3 +529,594 @@ def test_every_packed_pod_is_a_hit_a_miss_or_a_bypass():
     fresh.adopt_hysteresis(cached)
     assert fresh.row_cache_stats() == {**cached.row_cache_stats(),
                                        "entries": 0}
+
+
+# ---------------------------------------------------------------------------
+# Mirror.sync does the work of the delta (ISSUE 31): whatever it skips, the
+# host tables hold what a fresh Mirror synced from the same snapshot holds
+
+SYNC_CAPS = dict(nodes=16, pods=128)
+ZONES = ("moon-1", "moon-2", "moon-3")
+# pods of the shapes a slot can hold: labels alone, a host port (the node
+# row's port fields), (anti-)affinity terms (the slow arm of _pack_pod_slot)
+SYNC_SHAPES = {
+    "plain": lambda n: _pod(n),
+    "labelled": lambda n: _pod(n, {"color": "blue"}),
+    "web": lambda n: _pod(n, WEB),
+    "host-port": lambda n: _pod(n, {"color": "blue"}, containers=[Container(
+        name="c", ports=[ContainerPort(host_port=8080)],
+        resources=ResourceRequirements(requests={"cpu": "50m"}))]),
+    "required-anti-affinity": HAND_SHAPES["required-anti-affinity"],
+    "preferred-affinity-and-anti": HAND_SHAPES["preferred-affinity-and-anti"],
+    "required-and-preferred-with-spread":
+        HAND_SHAPES["required-and-preferred-with-spread"],
+    "extended-resource": HAND_SHAPES["init-containers-overhead-extended"],
+}
+
+
+def _node(name, zone, cpu="8", **labels):
+    return Node(
+        metadata=ObjectMeta(name=name, labels={
+            LABEL_HOSTNAME: name, LABEL_ZONE: zone, **labels}),
+        status=NodeStatus(allocatable={
+            "cpu": cpu, "memory": "64Gi", "pods": "110",
+            "example.com/gpu": "64"}))
+
+
+def _fresh_mirror(inc, snap, nominated):
+    """A Mirror that meets ``snap`` for the first time. The naming
+    registries (interner, label columns, topology keys and their domains,
+    extended-resource columns) only append, so the ids a mirror packs
+    depend on the order it met the names in: the fresh one is given the
+    incremental one's names, and row and slot numbers are left to differ."""
+    m = Mirror(interner=inc.interner, caps=inc.caps)
+    m._label_col = dict(inc._label_col)
+    m._pod_label_col = dict(inc._pod_label_col)
+    m._topo_col = dict(inc._topo_col)
+    m._tk_key = list(inc._tk_key)
+    m._tk_domains = [dict(d) for d in inc._tk_domains]
+    m._ext_index = dict(inc._ext_index)
+    m.sync(snap)
+    m.set_nominated(nominated)
+    return m
+
+
+def _fields_of(codec, f32_row, i32_row):
+    out = {}
+    for name, (off, size) in codec._f32_off.items():
+        out[name] = f32_row[off:off + size].tobytes()
+    for name, (off, size) in codec._i32_off.items():
+        out[name] = i32_row[off:off + size].tobytes()
+    return out
+
+
+def _assert_same_tables(inc, fresh):
+    """Every field of node_f32, node_i32 and pods_i32, by node name and
+    pod uid; rows and slots in use, and nothing but zeros outside them."""
+    assert set(inc._row_of) == set(fresh._row_of)
+    for name in inc._row_of:
+        a, b = inc.row_of(name), fresh.row_of(name)
+        got = _fields_of(inc.node_codec, inc.node_f32[a], inc.node_i32[a])
+        want = _fields_of(fresh.node_codec, fresh.node_f32[b],
+                          fresh.node_i32[b])
+        diff = [f for f in want if got[f] != want[f]]
+        assert not diff, f"node {name}: fields {diff} differ"
+    assert set(inc._pod_slot) == set(fresh._pod_slot)
+    node_off = inc.table_codec._i32_off["pod_node"][0]
+    empty = np.zeros((0,), np.float32)
+    for uid, a in inc._pod_slot.items():
+        b = fresh._pod_slot[uid]
+        got = _fields_of(inc.table_codec, empty, inc.pods_i32[a])
+        want = _fields_of(fresh.table_codec, empty, fresh.pods_i32[b])
+        assert (inc.name_of_row(int(inc.pods_i32[a, node_off]))
+                == fresh.name_of_row(int(fresh.pods_i32[b, node_off]))), uid
+        diff = [f for f in want if f != "pod_node" and got[f] != want[f]]
+        assert not diff, f"pod {uid}: fields {diff} differ"
+    for m in (inc, fresh):
+        assert int(m.node_i32.any(axis=1).sum()) == len(m._row_of)
+        assert int(m.pods_i32.any(axis=1).sum()) == len(m._pod_slot)
+    # what launch_features and table_has_topology read
+    names = lambda m, rows: {m.name_of_row(r) for r in rows}
+    assert names(inc, inc._rows_with_ports) == names(fresh,
+                                                     fresh._rows_with_ports)
+    assert set(inc._uids_with_terms) == set(fresh._uids_with_terms)
+    assert set(inc._uids_with_nssel) == set(fresh._uids_with_nssel)
+
+
+def _assert_device_is_host(m):
+    """to_blobs scatters the dirty rows and slots alone: a write that
+    skipped its dirty mark would leave the device behind the host."""
+    blobs = m.to_blobs()
+    assert np.asarray(blobs.node_f32).tobytes() == m.node_f32.tobytes()
+    assert np.asarray(blobs.node_i32).tobytes() == m.node_i32.tobytes()
+    assert np.asarray(blobs.pods_i32).tobytes() == m.pods_i32.tobytes()
+
+
+class _Cluster:
+    """A Cache driven as the scheduler and its informers drive it, the
+    mirror under test beside it."""
+
+    def __init__(self, seed):
+        import random
+
+        self.rng = random.Random(seed)
+        self.cache = Cache()
+        self.snap = Snapshot()
+        self.inc = Mirror(caps=Capacities(**SYNC_CAPS))
+        self.nodes = {}                 # name -> Node in the cache
+        self.assumed = {}               # uid -> assumed clone
+        self.confirmed = {}             # uid -> confirmed Pod
+        self.nominated = {}             # node name -> [Pod]
+        self.serial = 0
+        for i in range(6):
+            self.add_node()
+
+    def add_node(self):
+        # ten names in all: each is a hostname domain for the mirror's life
+        name = self.rng.choice(sorted(
+            {f"node-{i}" for i in range(10)} - set(self.nodes)))
+        node = _node(name, self.rng.choice(ZONES))
+        self.nodes[name] = node
+        self.cache.add_node(node)
+
+    def new_pod(self):
+        self.serial += 1
+        shape = self.rng.choice(sorted(SYNC_SHAPES))
+        return SYNC_SHAPES[shape](f"p{self.serial}")
+
+    def relabelled(self, pod):
+        new = pod.clone()
+        self.serial += 1
+        new.metadata.labels["rev"] = str(self.serial)
+        return new
+
+    def pick(self, pods):
+        return pods[self.rng.choice(sorted(pods))] if pods else None
+
+    # ---- the operations; each returns the nodes it touched
+    def assume(self):
+        if len(self.assumed) + len(self.confirmed) > 40 or not self.nodes:
+            return self.remove_pod()
+        a = self.new_pod().clone()
+        a.spec.node_name = self.rng.choice(sorted(self.nodes))
+        self.cache.assume_pod(a)
+        self.assumed[a.metadata.uid] = a
+        return [a.spec.node_name]
+
+    def confirm_equal(self):
+        a = self.pick(self.assumed)
+        if a is None:
+            return self.assume()
+        c = a.clone()                   # the informer's own object
+        self.cache.add_pod(c)
+        del self.assumed[c.metadata.uid]
+        self.confirmed[c.metadata.uid] = c
+        return [c.spec.node_name]
+
+    def confirm_relabelled(self):
+        a = self.pick(self.assumed)
+        if a is None:
+            return self.assume()
+        c = self.relabelled(a)
+        self.cache.add_pod(c)
+        del self.assumed[c.metadata.uid]
+        self.confirmed[c.metadata.uid] = c
+        return [c.spec.node_name]
+
+    def update_pod(self, relabel=True):
+        old = self.pick(self.confirmed)
+        if old is None:
+            return self.confirm_equal()
+        new = self.relabelled(old) if relabel else old.clone()
+        self.cache.update_pod(old, new)
+        self.confirmed[new.metadata.uid] = new
+        return [new.spec.node_name]
+
+    def update_pod_same_content(self):
+        return self.update_pod(relabel=False)
+
+    def update_pod_terms(self):
+        """Other (anti-)affinity terms, or none, under the same labels."""
+        old = self.pick(self.confirmed)
+        if old is None:
+            return self.confirm_equal()
+        new = old.clone()
+        new.spec.affinity = self.rng.choice((None, Affinity(
+            pod_anti_affinity=PodAntiAffinity(required=[_term(LABEL_ZONE)])),
+            Affinity(pod_affinity=PodAffinity(preferred=[
+                WeightedPodAffinityTerm(self.rng.randrange(1, 9),
+                                        _term(LABEL_HOSTNAME))]))))
+        self.cache.update_pod(old, new)
+        self.confirmed[new.metadata.uid] = new
+        return [new.spec.node_name]
+
+    def move_pod(self):
+        old = self.pick(self.confirmed)
+        if old is None or len(self.nodes) < 2:
+            return self.confirm_equal()
+        new = old.clone()
+        new.spec.node_name = self.rng.choice(
+            sorted(set(self.nodes) - {old.spec.node_name}))
+        self.cache.add_pod(new)         # informer truth wins
+        self.confirmed[new.metadata.uid] = new
+        return [old.spec.node_name, new.spec.node_name]
+
+    def remove_pod(self):
+        p = self.pick(self.confirmed)
+        if p is None:
+            return self.forget_pod()
+        self.cache.remove_pod(p)
+        del self.confirmed[p.metadata.uid]
+        return [p.spec.node_name]
+
+    def forget_pod(self):
+        a = self.pick(self.assumed)
+        if a is None:
+            return []
+        self.cache.forget_pod(a)
+        del self.assumed[a.metadata.uid]
+        return [a.spec.node_name]
+
+    def host_port_comes_and_goes(self):
+        """The only host-port pod of a node arrives, then leaves."""
+        if not self.nodes:
+            return []
+        self.serial += 1
+        p = SYNC_SHAPES["host-port"](f"hp{self.serial}")
+        p.spec.node_name = self.rng.choice(sorted(self.nodes))
+        self.cache.add_pod(p)
+        self.sync_and_compare()
+        self.cache.remove_pod(p)
+        return [p.spec.node_name]
+
+    def update_node(self):
+        old = self.pick(self.nodes)
+        if old is None:
+            return self.grow_nodes()
+        self.serial += 1
+        new = _node(old.metadata.name, old.metadata.labels[LABEL_ZONE],
+                    cpu=str(self.rng.choice((4, 8, 16))),
+                    rev=str(self.serial))
+        self.cache.update_node(old, new)
+        self.nodes[new.metadata.name] = new
+        return [new.metadata.name]
+
+    def grow_nodes(self):
+        if len(self.nodes) >= 10:
+            return self.remove_node()
+        self.add_node()
+        return []
+
+    def remove_node(self):
+        node = self.pick(self.nodes)
+        if node is None:
+            return []
+        self.cache.remove_node(node)
+        del self.nodes[node.metadata.name]
+        return []
+
+    def nominate(self):
+        self.nominated = {}
+        for _ in range(self.rng.randrange(3)):
+            if self.nodes:
+                p = self.new_pod()
+                self.nominated.setdefault(
+                    self.rng.choice(sorted(self.nodes)), []).append(p)
+        return []
+
+    def refresh_twice(self):
+        """Two update_snapshot calls between two syncs: the newest one's
+        changed_nodes are not all that moved."""
+        touched = self.assume()
+        self.cache.update_snapshot(self.snap)
+        return touched + self.confirm_equal()
+
+    OPS = ("assume", "assume", "assume", "confirm_equal", "confirm_equal",
+           "confirm_equal", "confirm_relabelled", "update_pod",
+           "update_pod_same_content", "update_pod_terms", "move_pod", "remove_pod", "forget_pod",
+           "host_port_comes_and_goes", "update_node", "grow_nodes",
+           "remove_node", "nominate", "refresh_twice")
+
+    def patch(self, names):
+        """Scheduler._apply_chain_patches: the live aggregate of a node,
+        outside the snapshot."""
+        for name in names:
+            info = self.cache.node_info(name)
+            got = self.inc.patch_node(name, info)
+            if got is not None and info is not None and info.node is not None:
+                row, free, nzr = got
+                off, size = self.inc.node_codec._f32_off["free"]
+                assert (free.tobytes()
+                        == self.inc.node_f32[row, off:off + size].tobytes())
+                assert nzr.shape == (2,)
+
+    def sync_and_compare(self):
+        self.cache.update_snapshot(self.snap)
+        self.inc.sync(self.snap)
+        self.inc.set_nominated(self.nominated)
+        _assert_same_tables(
+            self.inc, _fresh_mirror(self.inc, self.snap, self.nominated))
+        _assert_device_is_host(self.inc)
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_incremental_mirror_equals_a_fresh_one_after_every_sync(seed):
+    c = _Cluster(seed)
+    c.sync_and_compare()
+    for step in range(60):
+        touched = []
+        for _ in range(c.rng.randrange(1, 5)):
+            touched += getattr(c, c.rng.choice(c.OPS))()
+        if c.rng.random() < 0.3:
+            c.patch(touched)
+        c.sync_and_compare()
+    st = c.inc.sync_stats()
+    assert st["slots_kept"] > 0 and st["slots_released"] > 0
+    assert st["slots_packed"] - st["slots_released"] == len(c.inc._pod_slot)
+
+
+def _bound_cluster(n_pods=3):
+    """One node carrying ``n_pods`` assumed pods, synced."""
+    cache, snap = Cache(), Snapshot()
+    cache.add_node(_node("n0", "moon-1"))
+    m = Mirror(caps=Capacities(**SYNC_CAPS))
+    assumed = []
+    for i in range(n_pods):
+        a = _pod(f"a{i}", {"color": "blue"}).clone()
+        a.spec.node_name = "n0"
+        cache.assume_pod(a)
+        assumed.append(a)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    m.to_blobs()
+    return cache, snap, m, assumed
+
+
+def _one_more(cache, name="extra"):
+    p = _pod(name).clone()
+    p.spec.node_name = "n0"
+    cache.assume_pod(p)
+    return p
+
+
+def test_a_content_neutral_confirmation_dirties_no_slot():
+    cache, snap, m, assumed = _bound_cluster()
+    before = m.sync_stats()
+    for a in assumed:
+        cache.add_pod(a.clone())        # the informer's new object
+    cache.update_snapshot(snap)
+    assert m.sync(snap) == 0, "a confirmation alone bumps no generation"
+    extra = _one_more(cache)            # the node's next change
+    cache.update_snapshot(snap)
+    assert m.sync(snap) == 1
+    st = m.sync_stats()
+    assert st["slots_kept"] == before["slots_kept"] + 3
+    assert st["slots_packed"] == before["slots_packed"] + 1
+    assert st["slots_released"] == before["slots_released"]
+    assert m._dirty_slots == {m._pod_slot[extra.metadata.uid]}
+    # re-pointed: the next change of the node finds the objects it holds
+    _one_more(cache, "extra2")
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert m.sync_stats()["slots_kept"] == st["slots_kept"]
+
+
+@pytest.mark.parametrize("how", ["confirmation", "update_pod"])
+def test_a_changed_label_repacks_the_slot(how):
+    cache, snap, m, assumed = _bound_cluster()
+    before = m.sync_stats()
+    new = assumed[0].clone()
+    new.metadata.labels["color"] = "green"
+    if how == "confirmation":
+        cache.add_pod(new)
+    else:
+        cache.add_pod(assumed[0].clone())
+        cache.update_pod(assumed[0], new)
+    cache.update_snapshot(snap)
+    assert m.sync(snap) == 1, "the cache says the node changed"
+    st = m.sync_stats()
+    assert st["slots_packed"] == before["slots_packed"] + 1
+    assert st["slots_released"] == before["slots_released"] + 1
+    assert st["slots_kept"] == before["slots_kept"]
+    slot = m._pod_slot[new.metadata.uid]
+    off, size = m.table_codec._i32_off["pt_label_vals"]
+    assert (m.pods_i32[slot, off:off + size].tobytes()
+            == m.pod_labels_row({"color": "green"}).tobytes())
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+
+
+def test_an_update_to_other_affinity_terms_repacks_the_slot():
+    cache, snap, m, _ = _bound_cluster(0)
+    old = HAND_SHAPES["required-anti-affinity"]("t")
+    old.spec.node_name = "n0"
+    cache.add_pod(old)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    packed = m.slots_packed
+    same = old.clone()                  # new object and PodInfo, equal terms
+    cache.update_pod(old, same)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert (m.slots_packed, m.slots_kept) == (packed, 1)
+    other = same.clone()
+    other.spec.affinity = Affinity(pod_anti_affinity=PodAntiAffinity(
+        required=[_term(LABEL_ZONE)]))
+    cache.update_pod(same, other)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert (m.slots_packed, m.slots_kept) == (packed + 1, 1)
+    bare = other.clone()
+    bare.spec.affinity = None           # the terms go: the fast arm again
+    cache.update_pod(other, bare)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert (m.slots_packed, m.slots_kept) == (packed + 2, 1)
+    assert not m.table_has_topology()
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+
+
+def test_a_row_that_loses_its_last_host_port_reads_none_again():
+    from kubernetes_tpu.utils.interner import NONE
+
+    cache, snap, m, _ = _bound_cluster()
+    row = m.row_of("n0")
+
+    def port_fields():
+        return np.concatenate([
+            m.node_i32[row, off:off + size] for off, size in (
+                m.node_codec._i32_off[f]
+                for f in ("port_ips", "port_protos", "port_nums"))])
+
+    assert (port_fields() == NONE).all() and not m._rows_with_ports
+    hp = SYNC_SHAPES["host-port"]("hp")
+    hp.spec.node_name = "n0"
+    cache.add_pod(hp)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert m._rows_with_ports == {row}
+    assert 8080 in port_fields() and "ports" in m.launch_features([])
+    cache.remove_pod(hp)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert (port_fields() == NONE).all() and not m._rows_with_ports
+    assert "ports" not in m.launch_features([])
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+
+
+def test_a_touched_node_costs_the_pods_that_are_new_to_it():
+    """18 pods on the node and one new: one slot packed, no release scan
+    (nothing left), nothing else dirtied."""
+    cache, snap, m, assumed = _bound_cluster(18)
+    for a in assumed:
+        cache.add_pod(a.clone())
+    before = m.sync_stats()
+    extra = _one_more(cache)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    st = m.sync_stats()
+    assert st["slots_packed"] == before["slots_packed"] + 1
+    assert st["slots_kept"] == before["slots_kept"] + 18
+    assert st["slots_released"] == before["slots_released"]
+    assert m._dirty_slots == {m._pod_slot[extra.metadata.uid]}
+    assert m._dirty_rows == {m.row_of("n0")}
+
+
+def test_patch_node_followed_by_a_full_sync_repacks_nothing():
+    cache, snap, m, _ = _bound_cluster()
+    _one_more(cache)
+    row, free, nzr = m.patch_node("n0", cache.node_info("n0"))
+    st = m.sync_stats()
+    m.to_blobs()
+    cache.update_snapshot(snap)
+    assert m.sync(snap) == 0
+    assert m.sync_stats() == st and not m._dirty_slots and not m._dirty_rows
+    fresh = _fresh_mirror(m, snap, {})
+    _assert_same_tables(m, fresh)
+    off, size = m.node_codec._f32_off["free"]
+    assert free.tobytes() == fresh.node_f32[
+        fresh.row_of("n0"), off:off + size].tobytes()
+    off, size = m.node_codec._f32_off["nonzero_requested"]
+    assert nzr.tobytes() == fresh.node_f32[
+        fresh.row_of("n0"), off:off + size].tobytes()
+
+
+def test_sync_visits_the_changed_nodes_alone_when_the_snapshot_names_them():
+    cache, snap = Cache(), Snapshot()
+    for i in range(5):
+        cache.add_node(_node(f"n{i}", ZONES[i % 3]))
+    m = Mirror(caps=Capacities(**SYNC_CAPS))
+    cache.update_snapshot(snap)
+    assert snap.changed_nodes is None, "the node set moved: a full pass"
+    assert m.sync(snap) == 5
+    p = _pod("p").clone()
+    p.spec.node_name = "n3"
+    cache.assume_pod(p)
+    cache.update_snapshot(snap)
+    assert snap.changed_nodes == ["n3"]
+    # a generation the refresh did not name is not looked at
+    m._row_gen["n1"] = -1
+    assert m.sync(snap) == 1
+    # two refreshes since the last sync: the newest names one node only,
+    # so the full pass runs and finds the stale row as well
+    for node in ("n0", "n2"):
+        q = _pod(f"q-{node}").clone()
+        q.spec.node_name = node
+        cache.assume_pod(q)
+        cache.update_snapshot(snap)
+    assert snap.changed_nodes == ["n2"]
+    assert m.sync(snap) == 3            # n0, n2 and the stale n1
+    # a node removed: the refresh cannot name what moved
+    cache.remove_node(_node("n4", ZONES[1]))
+    cache.update_snapshot(snap)
+    assert snap.changed_nodes is None
+    assert m.sync(snap) == 1 and m.row_of("n4") == -1
+    # another Snapshot object: a full pass, nothing to repack
+    other = Snapshot()
+    cache.update_snapshot(other)
+    assert m.sync(other) == 0
+    _assert_same_tables(m, _fresh_mirror(m, other, {}))
+
+
+def test_a_sync_that_raised_leaves_the_next_one_a_full_pass():
+    from kubernetes_tpu.api.objects import NodeSpec, Taint
+    from kubernetes_tpu.backend.mirror import CapacityError
+
+    cache, snap = Cache(), Snapshot()
+    nodes = [_node(f"n{i}", ZONES[i]) for i in range(3)]
+    for node in nodes:
+        cache.add_node(node)
+    m = Mirror(caps=Capacities(**SYNC_CAPS))
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    for i in range(2):
+        p = _pod(f"p{i}").clone()
+        p.spec.node_name = f"n{i}"
+        cache.assume_pod(p)
+    tainted = _node("n2", ZONES[2])
+    tainted.spec = NodeSpec(taints=[
+        Taint(key=f"k{i}", value="v", effect="NoSchedule")
+        for i in range(m.caps.node_taints + 1)])
+    cache.update_node(nodes[2], tainted)
+    cache.update_snapshot(snap)
+    with pytest.raises(CapacityError):
+        m.sync(snap)
+    assert m._last_sync is None
+    cache.update_node(tainted, _node("n2", ZONES[2]))
+    cache.update_snapshot(snap)
+    assert snap.changed_nodes == ["n2"]
+    assert m.sync(snap) == 3            # not the delta: every row again
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+
+
+def test_sync_stats_survive_a_rebucketed_mirror():
+    cache, snap, m, assumed = _bound_cluster()
+    for a in assumed:
+        cache.add_pod(a.clone())
+    cache.remove_pod(assumed[0])
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    st = m.sync_stats()
+    assert st == {"rows_synced": 2, "slots_packed": 3, "slots_kept": 2,
+                  "slots_released": 1}
+    grown = Mirror(caps=Capacities(nodes=16, pods=256))
+    grown.adopt_hysteresis(m)
+    assert grown.sync_stats() == st
+    assert not grown._label_rows and not grown._pod_slot
+
+
+def test_label_rows_are_shared_read_only_and_bounded(monkeypatch):
+    monkeypatch.setattr(mirror_mod, "POD_ROW_CACHE_ENTRIES", 4)
+    m = Mirror(caps=Capacities(**SYNC_CAPS))
+    a = m.pod_labels_row({"color": "blue"})
+    assert m.pod_labels_row({"color": "blue"}) is a
+    with pytest.raises(ValueError):
+        a[0] = 7
+    plain = Mirror(interner=m.interner, caps=m.caps)
+    plain._pod_label_col = dict(m._pod_label_col)
+    for i in range(12):
+        labels = {"color": "blue", f"k{i % 3}": str(i)}
+        want = np.full((m.caps.pod_label_cols,), -1, np.int32)
+        for k, v in labels.items():
+            want[plain.pod_label_col(k)] = plain._i(v)
+        assert m.pod_labels_row(labels).tobytes() == want.tobytes()
+        assert len(m._label_rows) <= 5

@@ -276,6 +276,77 @@ def test_pack_row_cache_counts_reach_the_registry_and_debug_trace():
         sched.close()
 
 
+def test_mirror_sync_counts_reach_the_registry_and_debug_trace():
+    """Mirror.sync_stats() -> scheduler_mirror_slot_total{result} (by
+    delta, at maintenance) and /debug/trace's "mirror_sync"; a pod that
+    binds once packs one slot, and a re-bucketed mirror carries the
+    totals on."""
+    from kubernetes_tpu.api.objects import (
+        LABEL_HOSTNAME,
+        LabelSelector,
+        TopologySpreadConstraint,
+    )
+    from kubernetes_tpu.backend.mirror import CapacityError
+
+    def spread_pod(name):
+        # a topology launch never chains: every one syncs the mirror
+        pod = mk_sched_pod(name)
+        pod.metadata.labels = {"color": "blue"}
+        pod.spec.topology_spread_constraints = [TopologySpreadConstraint(
+            max_skew=50, topology_key=LABEL_HOSTNAME,
+            when_unsatisfiable="DoNotSchedule",
+            label_selector=LabelSelector(match_labels={"color": "blue"}))]
+        return pod
+
+    hub = Hub()
+    sched = _sched(hub)
+    try:
+        hub.create_node(mknode(0))
+        for rnd in range(4):            # every round touches the one node
+            for i in range(4):
+                hub.create_pod(spread_pod(f"p{rnd}-{i}"))
+            sched.run_until_idle()
+        # an informer resend: a new object of equal content, seen by the
+        # sync of the next launch
+        bound = next(p for p in hub.list_pods() if p.spec.node_name)
+        sched.cache.update_pod(bound, bound.clone())
+        hub.create_pod(spread_pod("next"))
+        sched.run_until_idle()
+        sched.run_maintenance()
+        m, st = sched.metrics, sched.mirror.sync_stats()
+        assert set(st) == {"rows_synced", "slots_packed", "slots_kept",
+                           "slots_released"}
+        assert st["slots_packed"] == 16, "one slot a pod bound, not two"
+        assert st["slots_kept"] == 1 and st["slots_released"] == 0
+        for result in ("packed", "kept", "released"):
+            assert m.mirror_slots.value(result=result) == st[
+                f"slots_{result}"]
+        assert (f'scheduler_mirror_slot_total{{result="packed"}} '
+                f'{st["slots_packed"]}') in m.registry.render_text()
+        sched._grow(CapacityError("pod_labels", sched.caps.pod_labels + 1))
+        assert sched.mirror.sync_stats() == st
+        hub.create_pod(spread_pod("late"))
+        sched.run_until_idle()
+        sched.run_maintenance()
+        after = sched.mirror.sync_stats()
+        # the fresh mirror packs the cluster again, on top of the totals
+        assert after["slots_packed"] >= st["slots_packed"] + 17
+        assert m.mirror_slots.value(result="packed") == after["slots_packed"]
+        assert m.mirror_slots.value(result="kept") == after["slots_kept"]
+        srv = ServingEndpoints(sched, port=0, debug_auth=token_auth("t"))
+        srv.start()
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{srv.port}/debug/trace?n=1")
+            req.add_header("Authorization", "Bearer t")
+            tr = json.loads(urllib.request.urlopen(req, timeout=5).read())
+        finally:
+            srv.stop()
+        assert tr["mirror_sync"] == after
+    finally:
+        sched.close()
+
+
 # ------------------------------------------------ the collector's pauses
 
 
