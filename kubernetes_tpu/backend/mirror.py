@@ -16,6 +16,8 @@ kernels once per bucket).
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 import jax
@@ -296,6 +298,11 @@ class Mirror:
         self.slots_packed = 0
         self.slots_kept = 0
         self.slots_released = 0
+        # of slots_packed, the slots of pods with (anti)affinity terms:
+        # _pack_pod_slot's slow arm, and the seconds it spent there by
+        # its own clock pair (the flight recorder's slot_pack_terms view)
+        self.slots_packed_terms = 0
+        self.slot_terms_s = 0.0
         # workload-activity tracking for launch_features(): which rows carry
         # taints / used host ports / images — a feature absent cluster-wide
         # AND batch-wide compiles out of the launch entirely
@@ -799,6 +806,7 @@ class Mirror:
             self._node_pods[node_name][uid] = pod
             self._node_of_pod[uid] = node_name
             return
+        t0 = time.perf_counter()
         f: dict[str, np.ndarray] = {}
         f["pod_valid"] = np.bool_(True)
         f["pod_node"] = np.int32(row)
@@ -820,6 +828,7 @@ class Mirror:
         empty_f32 = self.pods_i32[slot, :0].view(np.float32)
         self.table_codec.pack_into(empty_f32, self.pods_i32[slot], f)
         self.slots_packed += 1
+        self.slots_packed_terms += 1
         self._dirty_slots.add(slot)
         self._pod_slot[uid] = slot
         self._node_pods[node_name][uid] = pod
@@ -836,6 +845,7 @@ class Mirror:
                     or t.namespace_selector.match_expressions)
                for t in all_terms):
             self._uids_with_nssel.add(uid)
+        self.slot_terms_s += time.perf_counter() - t0
 
     @staticmethod
     def _effective_exprs(sel, owner_labels: dict[str, str],
@@ -1106,12 +1116,15 @@ class Mirror:
 
     def sync_stats(self) -> dict:
         """What sync and patch_node wrote, for /debug/trace and the
-        registry: node rows repacked; pod-table slots packed, released,
-        and kept (the pod's object was replaced by one of equal content:
-        re-pointed, nothing written). A backlog of pods that bind once
-        packs one slot a pod and keeps about one a pod."""
+        registry: node rows repacked; pod-table slots packed (of them
+        ``slots_packed_terms`` for pods with affinity terms, the slow
+        arm), released, and kept (the pod's object was replaced by one
+        of equal content: re-pointed, nothing written). A backlog of
+        pods that bind once packs one slot a pod and keeps about one a
+        pod."""
         return {"rows_synced": self.rows_synced,
                 "slots_packed": self.slots_packed,
+                "slots_packed_terms": self.slots_packed_terms,
                 "slots_kept": self.slots_kept,
                 "slots_released": self.slots_released}
 
@@ -1201,6 +1214,8 @@ class Mirror:
         self.row_cache_clears = prev.row_cache_clears
         self.rows_synced = prev.rows_synced
         self.slots_packed = prev.slots_packed
+        self.slots_packed_terms = prev.slots_packed_terms
+        self.slot_terms_s = prev.slot_terms_s
         self.slots_kept = prev.slots_kept
         self.slots_released = prev.slots_released
 

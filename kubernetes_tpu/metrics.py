@@ -267,6 +267,12 @@ class SchedulerMetrics:
             "kept (the pod's object was replaced by one of equal content, "
             "as a bind confirmation does: re-pointed, nothing written), "
             "released (the pod left its node)", ("result",)))
+        self.mirror_slot_terms = r.register(Counter(
+            "scheduler_mirror_slot_terms_total",
+            "Of the packed pod-table slots, those of pods with affinity "
+            "terms: the slow arm of the mirror's slot pack. A counter of "
+            "its own, so that the result values of "
+            "scheduler_mirror_slot_total stay disjoint"))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

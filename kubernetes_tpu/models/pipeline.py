@@ -249,7 +249,8 @@ def _guard_reduction(scores: jnp.ndarray, free: jnp.ndarray) -> jnp.ndarray:
 # program and no cache key. KERNEL_SCOPES lists them for whoever reads
 # per-kernel device time.
 KERNEL_SCOPES = ("static_filters", "auction_rounds", "soft_topology_auction",
-                 "commit_scan", "patch_chain", "scatter_rows")
+                 "commit_scan", "patch_chain", "scatter_rows",
+                 "inter_pod_affinity")
 
 
 @jax.named_scope("static_filters")

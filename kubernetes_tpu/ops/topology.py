@@ -32,6 +32,7 @@ Reference semantics implemented here:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import common as C
@@ -199,6 +200,7 @@ def pair_tsc_match(pods: PodFeatures) -> jnp.ndarray:
 # --------------------------- InterPodAffinity ---------------------------
 
 
+@jax.named_scope("inter_pod_affinity")
 def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
                               tds: jnp.ndarray, d_cap: int):
     """Pre-batch-table part of the Filter (filtering.go): returns
@@ -247,6 +249,7 @@ def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
     return ~fail1 & ~fail2, present, any_match
 
 
+@jax.named_scope("inter_pod_affinity")
 def inter_pod_affinity_score(ct: ClusterTensors, pod: PodFeatures,
                              tds: jnp.ndarray, d_cap: int,
                              hard_weight: jnp.ndarray) -> jnp.ndarray:

@@ -1706,12 +1706,19 @@ class Scheduler:
                     with span("snapshot_sync", tr, view="snapshot_cache"):
                         self.cache.update_snapshot(self.snapshot)
                     with span("snapshot_sync", tr, view="mirror_sync"):
+                        terms_s0 = self.mirror.slot_terms_s
                         self.mirror.sync(self.snapshot)
                         # a full sync subsumes every pending chain patch:
                         # handlers mutate the cache synchronously before
                         # registering, and the sync read that cache
                         self._chain_dirty.clear()
                         self._chain_deltas.clear()
+                        # the mirror's own view of this sync, reported
+                        # just before its parent: seconds in the slow
+                        # arm of _pack_pod_slot (0.0 = no pod with terms)
+                        self.flight.observe_view(
+                            "slot_pack_terms",
+                            self.mirror.slot_terms_s - terms_s0)
                 with span("pack", tr):
                     self.mirror.set_nominated(self.nominator.by_node())
                     spec = self.mirror.prepare_launch(
@@ -3663,6 +3670,8 @@ class Scheduler:
                           ("released", mirror.slots_released)):
             self._mirror_count(f"mirror_slot:{result}", n,
                                m.mirror_slots, result=result)
+        self._mirror_count("mirror_slot_terms", mirror.slots_packed_terms,
+                           m.mirror_slot_terms)
         self._mirror_journal_stats()
         if self.jobqueue.active:
             for tenant, st in self.jobqueue.tenant_stats().items():

@@ -8,6 +8,14 @@ import re
 import jax.numpy as jnp
 import pytest
 
+from kubernetes_tpu.api.objects import (
+    LABEL_ZONE,
+    Affinity,
+    LabelSelector,
+    PodAffinity,
+    PodAffinityTerm,
+)
+
 from kubernetes_tpu.backend.mirror import _scatter_rows_jit
 from kubernetes_tpu.models.pipeline import (
     KERNEL_SCOPES,
@@ -52,6 +60,22 @@ def _plain_spec():
         mirror.well_known(), CAPS
 
 
+def _affinity_spec():
+    """Pods with a required zone affinity term: the launch takes the
+    topology programs, inter-pod affinity among them."""
+    _cache, _snap, mirror = build_cluster(16, caps=CAPS)
+    pods = [make_pod(i) for i in range(8)]
+    for p in pods:
+        p.metadata.uid = p.metadata.name
+        p.spec.affinity = Affinity(pod_affinity=PodAffinity(required=[
+            PodAffinityTerm(topology_key=LABEL_ZONE,
+                            label_selector=LabelSelector(
+                                match_labels={"app": "app-0"}))]))
+    spec = mirror.prepare_launch(pods, 8)
+    assert spec.enable_topology
+    return spec, mirror.well_known(), CAPS
+
+
 def _soft_spec():
     rng = random.Random(7)
     _table, _snap, mirror = build_soft(rng)
@@ -72,6 +96,8 @@ def _soft_spec():
      ("auction_rounds",)),
     (_soft_spec, False, ("static_filters", "soft_topology_auction"),
      ("auction_rounds", "commit_scan")),
+    (_affinity_spec, True, ("static_filters", "inter_pod_affinity",
+                            "commit_scan"), ("auction_rounds",)),
 ])
 def test_schedule_batch_kernels_carry_their_scope(make, serial_scan, scopes,
                                                   absent):
@@ -94,4 +120,4 @@ def test_chain_and_mirror_scatters_carry_their_scope():
         free, idx, rows[0]).as_text(debug_info=True))
     assert set(KERNEL_SCOPES) == {
         "static_filters", "auction_rounds", "soft_topology_auction",
-        "commit_scan", "patch_chain", "scatter_rows"}
+        "commit_scan", "patch_chain", "scatter_rows", "inter_pod_affinity"}

@@ -1096,7 +1096,8 @@ def test_sync_stats_survive_a_rebucketed_mirror():
     cache.update_snapshot(snap)
     m.sync(snap)
     st = m.sync_stats()
-    assert st == {"rows_synced": 2, "slots_packed": 3, "slots_kept": 2,
+    assert st == {"rows_synced": 2, "slots_packed": 3,
+                  "slots_packed_terms": 0, "slots_kept": 2,
                   "slots_released": 1}
     grown = Mirror(caps=Capacities(nodes=16, pods=256))
     grown.adopt_hysteresis(m)

@@ -314,8 +314,10 @@ def test_mirror_sync_counts_reach_the_registry_and_debug_trace():
         sched.run_until_idle()
         sched.run_maintenance()
         m, st = sched.metrics, sched.mirror.sync_stats()
-        assert set(st) == {"rows_synced", "slots_packed", "slots_kept",
+        assert set(st) == {"rows_synced", "slots_packed",
+                           "slots_packed_terms", "slots_kept",
                            "slots_released"}
+        assert st["slots_packed_terms"] == 0, "no pod here carries a term"
         assert st["slots_packed"] == 16, "one slot a pod bound, not two"
         assert st["slots_kept"] == 1 and st["slots_released"] == 0
         for result in ("packed", "kept", "released"):

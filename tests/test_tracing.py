@@ -562,6 +562,40 @@ def test_commit_is_not_laid_under_its_own_pull_or_wait(monkeypatch):
         sched.close()
 
 
+def _wait_for_a_machine_that_is_not_oversubscribed(limit_s=30.0,
+                                                   busy_max=0.35,
+                                                   sample_s=0.2):
+    """What the exclusive spans leave out is the glue between them, and its
+    length is two hand-overs of the interpreter a turn (to the commit thread
+    after device_dispatch, to a binder thread before binder_drain): 0.1 ms
+    each while a core is free, 4 ms when the thread that holds the
+    interpreter waits for one. With the machine three quarters busy (five
+    other test workers, a cell's rehearsal among them) the same loop reads
+    0.87 to 0.95 where alone it reads 0.97, whatever its spans cover
+    (ROADMAP R-A15). So the turns are measured in a moment when the other
+    processes leave two thirds of the machine alone, waited for up to
+    `limit_s`; past that they are measured as the machine is. Where there
+    is no /proc/stat there is no wait."""
+    import time
+
+    def ticks():
+        with open("/proc/stat") as f:
+            v = [int(x) for x in f.readline().split()[1:]]
+        return sum(v), sum(v) - v[3] - v[4]      # all, all but idle + iowait
+
+    try:
+        before = ticks()
+    except OSError:
+        return
+    deadline = time.monotonic() + limit_s
+    while time.monotonic() < deadline:
+        time.sleep(sample_s)
+        now = ticks()
+        if now[1] - before[1] <= busy_max * max(1, now[0] - before[0]):
+            return
+        before = now
+
+
 def test_loop_turns_are_tiled_by_exclusive_spans():
     """(c) over twenty turns of Scheduler.run the exclusive spans of the
     loop thread cover at least 95% of its wall time and never overlap."""
@@ -574,6 +608,7 @@ def test_loop_turns_are_tiled_by_exclusive_spans():
     try:
         hub.create_pod(mkpod("warm"))
         sched.run_until_idle()              # the compile, outside the turns
+        _wait_for_a_machine_that_is_not_oversubscribed()
         sched.start()
         fl = sched.flight
         time.sleep(0.05)
