@@ -208,6 +208,27 @@ def _selector_key(sel):
             if sel.match_expressions else ())
 
 
+def _term_key(weight: int, t: PodAffinityTerm) -> tuple:
+    """What a packed (anti-)affinity term reads of the term itself, for
+    the two content keys (Mirror._pod_row_key, Mirror._slot_row_key). The
+    caller has turned away a term with a namespace_selector."""
+    return (weight, t.topology_key, _selector_key(t.label_selector),
+            tuple(t.namespaces), tuple(t.match_label_keys),
+            tuple(t.mismatch_label_keys))
+
+
+def _weighted_terms(pi: PodInfo) -> tuple:
+    """A PodInfo's four term lists in the pod table's order (anti,
+    affinity, preferred affinity, preferred anti), each as (weight, term)
+    pairs; a required term weighs 0."""
+    return ([(0, t) for t in pi.required_anti_affinity_terms],
+            [(0, t) for t in pi.required_affinity_terms],
+            [(w.weight, w.pod_affinity_term)
+             for w in pi.preferred_affinity_terms],
+            [(w.weight, w.pod_affinity_term)
+             for w in pi.preferred_anti_affinity_terms])
+
+
 class Mirror:
     def __init__(self, interner: Interner | None = None,
                  caps: Capacities = Capacities(), mesh=None):
@@ -287,6 +308,14 @@ class Mirror:
             tc_off[name][0] for name in
             ("pod_node", "pod_ns", "pod_uid", "pod_nominated"))
         self._slot_labels_off = tc_off["pt_label_vals"]
+        # packed pod-table rows of pods with terms, by the slot's content
+        # (_slot_row_key), read-only, and the cache's counts: totals of
+        # the scheduler's life, as the packed-row cache's are
+        self._slot_rows: dict[tuple, np.ndarray] = {}
+        self.slot_row_hits = 0
+        self.slot_row_misses = 0
+        self.slot_row_bypass = 0
+        self.slot_row_clears = 0
         self._row_node_obj: dict[int, object] = {}  # row -> packed Node obj
         # [N, R] float64 image of each row's allocatable, true while the
         # row's Node object stands (NodeInfo.set_node derives both): the
@@ -299,8 +328,9 @@ class Mirror:
         self.slots_kept = 0
         self.slots_released = 0
         # of slots_packed, the slots of pods with (anti)affinity terms:
-        # _pack_pod_slot's slow arm, and the seconds it spent there by
-        # its own clock pair (the flight recorder's slot_pack_terms view)
+        # _pack_pod_slot's terms arm (a copied row or a full pack), and
+        # the seconds it spent there by its own clock pair (the flight
+        # recorder's slot_pack_terms view)
         self.slots_packed_terms = 0
         self.slot_terms_s = 0.0
         # workload-activity tracking for launch_features(): which rows carry
@@ -776,8 +806,57 @@ class Mirror:
             self._table_i32_tmpl = ti32[0]
         return self._table_i32_tmpl
 
+    @staticmethod
+    def _slot_row_key(pi: PodInfo):
+        """Content key of the pod-table row cache (_pack_pod_slot's terms
+        arm): everything that arm reads of the pod apart from the three
+        columns patched per slot (pod_node, pod_uid, pod_nominated), and
+        nothing of the mirror's mutable state. That is the namespace (a
+        term with no listed namespaces defaults to the owner's, and
+        pod_ns), the labels (pt_label_vals, and the values a term's
+        match_label_keys / mismatch_label_keys copy) and the four term
+        lists: what _slot_holds compares. None where the row is not a
+        function of that content: a term with a namespace_selector
+        (_resolve_term_namespaces reads the namespace store and
+        _known_pod_ns, and _repack_nssel_pods re-packs such slots when a
+        namespace appears), the line _pod_row_key draws. Dicts and lists
+        go in in their own order: two spellings of one content get two
+        keys and two equal rows, an entry lost and never a wrong row."""
+        groups = _weighted_terms(pi)
+        for group in groups:
+            for _w, t in group:
+                if t.namespace_selector is not None:
+                    return None
+        meta = pi.pod.metadata
+        return (meta.namespace, tuple(meta.labels.items()),
+                *[tuple(_term_key(w, t) for w, t in g) for g in groups])
+
     def _pack_pod_slot(self, uid: str, pi: PodInfo, row: int, node_name: str,
                        nominated: bool = False) -> None:
+        """Write ``uid``'s pod-table slot. A pod without terms copies the
+        template row and patches its scalars and labels; a pod with terms
+        copies the row kept under its content key (_slot_row_key) and
+        patches pod_node, pod_uid and pod_nominated, or, on a miss, takes
+        the full pack (_pack_term_slot) and leaves a read-only copy of
+        the row under the key. A pod with no key (a namespace_selector)
+        takes the full pack every time, counted as a bypass.
+
+        INVARIANT, as _pack_batch_np's: a hit is byte for byte what the
+        full pack would write for that pod now. A kept row is derived
+        from the pod's content and from registries that only append for
+        the Mirror's life (the interner, pod_label_col, topo_col), and a
+        re-bucketed mirror is a FRESH Mirror with other row widths and an
+        empty cache (adopt_hysteresis takes over the counts only). A hit
+        skips the full pack's side effects (topo_col, _used_tks,
+        pod_label_col, the interner): sound only because a miss on this
+        same Mirror ran them and nothing un-registers. An edit that lets
+        the full pack read mutable state for a keyed pod has to make
+        _slot_row_key return None for it. The cache is bounded as
+        _pod_rows is: cleared past POD_ROW_CACHE_ENTRIES, a clear counted.
+        The clock pair stands around the whole terms arm, hit and miss
+        alike: slot_terms_s is the seconds spent on slots of pods with
+        terms, and slots_packed_terms counts every such slot (= hits +
+        misses + bypass, slot_row_cache_stats)."""
         self._note_namespace(pi.pod.metadata.namespace)
         if not self._free_slots:
             raise CapacityError("pods", self.caps.pods + 1)
@@ -807,6 +886,48 @@ class Mirror:
             self._node_of_pod[uid] = node_name
             return
         t0 = time.perf_counter()
+        dst = self.pods_i32[slot]
+        key = self._slot_row_key(pi)
+        kept = self._slot_rows.get(key) if key is not None else None
+        if kept is not None:
+            self.slot_row_hits += 1
+            dst[:] = kept
+            o_node, _o_ns, o_uid, o_nominated = self._slot_scalar_off
+            dst[o_node] = row
+            dst[o_uid] = self.interner.intern(pod.metadata.uid)
+            dst[o_nominated] = 1 if nominated else 0
+        else:
+            self._pack_term_slot(dst, pi, row, nominated)
+            if key is None:
+                self.slot_row_bypass += 1
+                if any(t.namespace_selector is not None
+                       and (t.namespace_selector.match_labels
+                            or t.namespace_selector.match_expressions)
+                       for group in _weighted_terms(pi) for _w, t in group):
+                    self._uids_with_nssel.add(uid)
+            else:
+                self.slot_row_misses += 1
+                if len(self._slot_rows) > POD_ROW_CACHE_ENTRIES:
+                    self._slot_rows.clear()
+                    self.slot_row_clears += 1
+                kept = dst.copy()
+                kept.flags.writeable = False
+                self._slot_rows[key] = kept
+        self.slots_packed += 1
+        self.slots_packed_terms += 1
+        self._dirty_slots.add(slot)
+        self._pod_slot[uid] = slot
+        self._node_pods[node_name][uid] = pod
+        self._node_of_pod[uid] = node_name
+        self._uids_with_terms[uid] = pi
+        self.slot_terms_s += time.perf_counter() - t0
+
+    def _pack_term_slot(self, dst: np.ndarray, pi: PodInfo, row: int,
+                        nominated: bool) -> None:
+        """The full pack of a pod-table row with terms into ``dst``: every
+        field derived from the pod and the registries (some thirty padded
+        arrays through the codec)."""
+        pod = pi.pod
         f: dict[str, np.ndarray] = {}
         f["pod_valid"] = np.bool_(True)
         f["pod_node"] = np.int32(row)
@@ -825,27 +946,7 @@ class Mirror:
             [w.pod_affinity_term for w in pi.preferred_anti_affinity_terms],
             [w.weight for w in pi.preferred_anti_affinity_terms], pod,
             "pod_panti", f)
-        empty_f32 = self.pods_i32[slot, :0].view(np.float32)
-        self.table_codec.pack_into(empty_f32, self.pods_i32[slot], f)
-        self.slots_packed += 1
-        self.slots_packed_terms += 1
-        self._dirty_slots.add(slot)
-        self._pod_slot[uid] = slot
-        self._node_pods[node_name][uid] = pod
-        self._node_of_pod[uid] = node_name
-        all_terms = (pi.required_anti_affinity_terms
-                     + pi.required_affinity_terms
-                     + [w.pod_affinity_term for w in pi.preferred_affinity_terms]
-                     + [w.pod_affinity_term
-                        for w in pi.preferred_anti_affinity_terms])
-        if all_terms:
-            self._uids_with_terms[uid] = pi
-        if any(t.namespace_selector is not None
-               and (t.namespace_selector.match_labels
-                    or t.namespace_selector.match_expressions)
-               for t in all_terms):
-            self._uids_with_nssel.add(uid)
-        self.slot_terms_s += time.perf_counter() - t0
+        self.table_codec.pack_into(dst[:0].view(np.float32), dst, f)
 
     @staticmethod
     def _effective_exprs(sel, owner_labels: dict[str, str],
@@ -1117,9 +1218,10 @@ class Mirror:
     def sync_stats(self) -> dict:
         """What sync and patch_node wrote, for /debug/trace and the
         registry: node rows repacked; pod-table slots packed (of them
-        ``slots_packed_terms`` for pods with affinity terms, the slow
-        arm), released, and kept (the pod's object was replaced by one
-        of equal content: re-pointed, nothing written). A backlog of
+        ``slots_packed_terms`` for pods with affinity terms, the terms
+        arm: slot_row_cache_stats parts them), released, and kept (the
+        pod's object was replaced by one of equal content: re-pointed,
+        nothing written). A backlog of
         pods that bind once packs one slot a pod and keeps about one a
         pod."""
         return {"rows_synced": self.rows_synced,
@@ -1204,7 +1306,7 @@ class Mirror:
         capacity re-bucket (scheduler._grow builds a FRESH mirror):
         without this a rebuilt mirror re-derives a smaller bucket from
         its still-empty domain tables and the next churn swing pays the
-        compile again. The packed-row cache's counts and sync_stats'
+        compile again. The two row caches' counts and sync_stats'
         come along too: they are totals the registry mirrors by delta,
         and the caches themselves start empty."""
         self._d_hw = prev._d_hw
@@ -1212,6 +1314,10 @@ class Mirror:
         self.row_cache_misses = prev.row_cache_misses
         self.row_cache_bypass = prev.row_cache_bypass
         self.row_cache_clears = prev.row_cache_clears
+        self.slot_row_hits = prev.slot_row_hits
+        self.slot_row_misses = prev.slot_row_misses
+        self.slot_row_bypass = prev.slot_row_bypass
+        self.slot_row_clears = prev.slot_row_clears
         self.rows_synced = prev.rows_synced
         self.slots_packed = prev.slots_packed
         self.slots_packed_terms = prev.slots_packed_terms
@@ -1665,9 +1771,7 @@ class Mirror:
                     if t.namespace_selector is not None:
                         return None
                 groups.append((len(grp.required), tuple(
-                    (w, t.topology_key, _selector_key(t.label_selector),
-                     tuple(t.namespaces), tuple(t.match_label_keys),
-                     tuple(t.mismatch_label_keys)) for w, t in terms)))
+                    _term_key(w, t) for w, t in terms)))
             aff_key = tuple(groups)
         meta = pod.metadata
         return (
@@ -1751,6 +1855,16 @@ class Mirror:
         self.row_cache_misses += misses
         self.row_cache_bypass += len(pods) - hits - misses
         return f32, i32
+
+    def slot_row_cache_stats(self) -> dict:
+        """The pod-table row cache's counts (_pack_pod_slot's terms arm),
+        for /debug/trace and the registry: slots_packed_terms = hits +
+        misses + bypass."""
+        return {"hits": self.slot_row_hits,
+                "misses": self.slot_row_misses,
+                "bypass": self.slot_row_bypass,
+                "clears": self.slot_row_clears,
+                "entries": len(self._slot_rows)}
 
     def row_cache_stats(self) -> dict:
         """The packed-row cache's counts, for /debug/trace and the

@@ -260,6 +260,14 @@ class SchedulerMetrics:
             "cache did: hit (a row of the same content copied), miss "
             "(packed and kept), bypass (a pod whose row is not a function "
             "of its content alone, packed every time)", ("result",)))
+        self.mirror_slot_row_cache = r.register(Counter(
+            "scheduler_mirror_slot_row_cache_total",
+            "Pod-table slots of pods with affinity terms by what the "
+            "mirror's slot row cache did: hit (a row of the same content "
+            "copied and its own columns patched), miss (packed in full "
+            "and kept), bypass (a term with a namespace selector: packed "
+            "in full every time). Their sum is "
+            "scheduler_mirror_slot_terms_total", ("result",)))
         self.mirror_slots = r.register(Counter(
             "scheduler_mirror_slot_total",
             "Pod-table slots by what the mirror's sync did with them: "
@@ -270,7 +278,7 @@ class SchedulerMetrics:
         self.mirror_slot_terms = r.register(Counter(
             "scheduler_mirror_slot_terms_total",
             "Of the packed pod-table slots, those of pods with affinity "
-            "terms: the slow arm of the mirror's slot pack. A counter of "
+            "terms: the terms arm of the mirror's slot pack. A counter of "
             "its own, so that the result values of "
             "scheduler_mirror_slot_total stay disjoint"))
         self.pod_e2e_duration = r.register(Histogram(

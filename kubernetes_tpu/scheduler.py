@@ -1714,7 +1714,7 @@ class Scheduler:
                         self._chain_dirty.clear()
                         self._chain_deltas.clear()
                         # the mirror's own view of this sync, reported
-                        # just before its parent: seconds in the slow
+                        # just before its parent: seconds in the terms
                         # arm of _pack_pod_slot (0.0 = no pod with terms)
                         self.flight.observe_view(
                             "slot_pack_terms",
@@ -3665,6 +3665,11 @@ class Scheduler:
                           ("bypass", mirror.row_cache_bypass)):
             self._mirror_count(f"pack_row_cache:{result}", n,
                                m.pack_row_cache, result=result)
+        for result, n in (("hit", mirror.slot_row_hits),
+                          ("miss", mirror.slot_row_misses),
+                          ("bypass", mirror.slot_row_bypass)):
+            self._mirror_count(f"slot_row_cache:{result}", n,
+                               m.mirror_slot_row_cache, result=result)
         for result, n in (("packed", mirror.slots_packed),
                           ("kept", mirror.slots_kept),
                           ("released", mirror.slots_released)):

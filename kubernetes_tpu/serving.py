@@ -127,6 +127,10 @@ class ServingEndpoints:
                         # the mirror's packed-row cache: pods packed
                         # = hits + misses + bypass, clears at its bound
                         "pack_row_cache": sched.mirror.row_cache_stats(),
+                        # the pod-table row cache of slots with terms:
+                        # slots_packed_terms = hits + misses + bypass
+                        "slot_row_cache":
+                            sched.mirror.slot_row_cache_stats(),
                         # what the mirror's sync wrote: node rows, and
                         # pod-table slots packed, kept (a confirmation
                         # that changed nothing) and released

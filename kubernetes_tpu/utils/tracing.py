@@ -123,8 +123,9 @@ LOOP_VIEW_PHASES = (
     "gc_pause",           # one collector pause (utils/gcguard's
                           # gc.callbacks hook), on whichever thread
                           # the collector ran
-    "slot_pack_terms",    # Mirror._pack_pod_slot's slow arm (pods with
-                          # affinity terms), the mirror's own clock;
+    "slot_pack_terms",    # Mirror._pack_pod_slot's terms arm (pods with
+                          # affinity terms: a copied row or a full
+                          # pack), the mirror's own clock;
                           # reported with (and inside) mirror_sync once
                           # a sync, 0.0 when it packed no such slot
 )

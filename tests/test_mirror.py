@@ -1,6 +1,7 @@
 """Mirror packing semantics: the f32 representability boundary, and the
 packed-row cache of _pack_batch_np (a hit is byte for byte the slow path's
-row; what is not a function of a pod's content bypasses it)."""
+row; what is not a function of a pod's content bypasses it), Mirror.sync
+against a fresh mirror, and the pod-table row cache of slots with terms."""
 
 import copy
 import glob
@@ -38,6 +39,7 @@ from kubernetes_tpu.api.objects import (
 from kubernetes_tpu.backend import mirror as mirror_mod
 from kubernetes_tpu.backend.cache import Cache
 from kubernetes_tpu.backend.mirror import Mirror
+from kubernetes_tpu.backend.node_info import PodInfo
 from kubernetes_tpu.backend.snapshot import Snapshot
 from kubernetes_tpu.ops.features import Capacities
 from kubernetes_tpu.utils.interner import Interner
@@ -568,8 +570,11 @@ def _fresh_mirror(inc, snap, nominated):
     registries (interner, label columns, topology keys and their domains,
     extended-resource columns) only append, so the ids a mirror packs
     depend on the order it met the names in: the fresh one is given the
-    incremental one's names, and row and slot numbers are left to differ."""
+    incremental one's names, and row and slot numbers are left to differ.
+    It keeps no pod-table row: every slot with terms is the full pack, so
+    the comparison holds a hit of ``inc`` to the bytes of a full pack."""
     m = Mirror(interner=inc.interner, caps=inc.caps)
+    m._slot_row_key = lambda pi: None
     m._label_col = dict(inc._label_col)
     m._pod_label_col = dict(inc._pod_label_col)
     m._topo_col = dict(inc._topo_col)
@@ -716,7 +721,8 @@ class _Cluster:
         return self.update_pod(relabel=False)
 
     def update_pod_terms(self):
-        """Other (anti-)affinity terms, or none, under the same labels."""
+        """Other (anti-)affinity terms, or none, under the same labels:
+        one of a few, so that pods come to share them."""
         old = self.pick(self.confirmed)
         if old is None:
             return self.confirm_equal()
@@ -724,7 +730,7 @@ class _Cluster:
         new.spec.affinity = self.rng.choice((None, Affinity(
             pod_anti_affinity=PodAntiAffinity(required=[_term(LABEL_ZONE)])),
             Affinity(pod_affinity=PodAffinity(preferred=[
-                WeightedPodAffinityTerm(self.rng.randrange(1, 9),
+                WeightedPodAffinityTerm(self.rng.choice((2, 7)),
                                         _term(LABEL_HOSTNAME))]))))
         self.cache.update_pod(old, new)
         self.confirmed[new.metadata.uid] = new
@@ -853,6 +859,11 @@ def test_incremental_mirror_equals_a_fresh_one_after_every_sync(seed):
     st = c.inc.sync_stats()
     assert st["slots_kept"] > 0 and st["slots_released"] > 0
     assert st["slots_packed"] - st["slots_released"] == len(c.inc._pod_slot)
+    # pods of one shape share their terms: among the slots the comparisons
+    # above held to a full pack were copies of a kept row
+    rc = c.inc.slot_row_cache_stats()
+    assert rc["hits"] > 0 and rc["misses"] > 0 and rc["bypass"] == 0
+    assert rc["hits"] + rc["misses"] == st["slots_packed_terms"]
 
 
 def _bound_cluster(n_pods=3):
@@ -1121,3 +1132,217 @@ def test_label_rows_are_shared_read_only_and_bounded(monkeypatch):
             want[plain.pod_label_col(k)] = plain._i(v)
         assert m.pod_labels_row(labels).tobytes() == want.tobytes()
         assert len(m._label_rows) <= 5
+
+
+# ---------------------------------------------------------------------------
+# the pod-table row cache (ISSUE 33): a slot with terms is packed once a
+# content, then copied; the copy is byte for byte the full pack
+
+
+def _match_label_keys_pod(n):
+    return _pod(n, {**WEB, "pod-template-hash": "abc12"}, affinity=Affinity(
+        pod_affinity=PodAffinity(required=[
+            _term(match_label_keys=["pod-template-hash", "absent"])]),
+        pod_anti_affinity=PodAntiAffinity(preferred=[
+            WeightedPodAffinityTerm(5, _term(
+                LABEL_HOSTNAME, mismatch_label_keys=["tier"]))])))
+
+
+# every benchmark template that carries terms, and hand shapes around them
+TERM_SHAPES = {
+    **{name: make for name, make in _template_shapes().items()
+       if "affinity" in name and name not in TEMPLATE_BYPASS},
+    "match-label-keys": _match_label_keys_pod,
+    **{name: HAND_SHAPES[name] for name in (
+        "required-affinity", "required-anti-affinity",
+        "preferred-affinity-and-anti", "required-and-preferred-with-spread")},
+}
+
+
+def _bind(cache, pod, node):
+    a = pod.clone()
+    a.spec.node_name = node
+    cache.assume_pod(a)
+    return a
+
+
+def _slot_cluster(n_nodes=3):
+    cache, snap = Cache(), Snapshot()
+    for i in range(n_nodes):
+        cache.add_node(_node(f"n{i}", ZONES[i % 3]))
+    m = Mirror(caps=Capacities(**SYNC_CAPS))
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    return cache, snap, m
+
+
+def test_the_benchmark_templates_with_terms_are_all_here():
+    assert {"pod-with-pod-affinity", "pod-with-pod-affinity-init",
+            "pod-with-pod-anti-affinity", "pod-with-preferred-pod-affinity",
+            "pod-with-preferred-pod-anti-affinity"} <= set(TERM_SHAPES)
+
+
+@pytest.mark.parametrize("shape", sorted(TERM_SHAPES))
+def test_a_slot_written_by_a_hit_is_what_a_full_pack_writes(shape):
+    """One miss a content; the slots after it, on other rows, of other
+    uids and as a nominated overlay, are copies with three columns
+    patched, and read what a mirror that keeps no row packs in full."""
+    make = TERM_SHAPES[shape]
+    cache, snap, m = _slot_cluster()
+    _bind(cache, make(f"{shape}-0"), "n0")
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert m.slot_row_cache_stats() == {
+        "hits": 0, "misses": 1, "bypass": 0, "clears": 0, "entries": 1}
+    for i, node in enumerate(("n1", "n2", "n1", "n0"), start=1):
+        _bind(cache, make(f"{shape}-{i}"), node)
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    nominated = {"n2": [make(f"{shape}-nominated")]}
+    m.set_nominated(nominated)
+    st = m.slot_row_cache_stats()
+    assert (st["hits"], st["misses"], st["bypass"]) == (5, 1, 0)
+    assert m.sync_stats()["slots_packed_terms"] == 6
+    fresh = _fresh_mirror(m, snap, nominated)
+    assert fresh.slot_row_cache_stats() == {
+        "hits": 0, "misses": 0, "bypass": 6, "clears": 0, "entries": 0}
+    _assert_same_tables(m, fresh)
+    _assert_device_is_host(m)
+    # the overlay slot carries the flag, the bound ones do not
+    o_nom = m.table_codec._i32_off["pod_nominated"][0]
+    flags = {uid: int(m.pods_i32[slot, o_nom])
+             for uid, slot in m._pod_slot.items()}
+    assert [u for u, f in flags.items() if f] == [
+        f"nominated:{nominated['n2'][0].metadata.uid}"]
+    # the overlay refreshed, as every cycle does: a hit again, same bytes
+    m.set_nominated(nominated)
+    assert m.slot_row_cache_stats()["hits"] == 6
+    _assert_same_tables(m, fresh)
+
+
+def _no_listed_namespaces(n, namespace="default", labels=None):
+    return _pod(n, labels or WEB, namespace=namespace, affinity=Affinity(
+        pod_affinity=PodAffinity(required=[
+            _term(match_label_keys=["tier"])])))
+
+
+@pytest.mark.parametrize("differ", ["labels", "namespace"])
+def test_equal_terms_under_other_labels_or_namespace_get_other_rows(differ):
+    """The terms are equal, but match_label_keys copies the owner's label
+    and a term with no listed namespaces selects in the owner's: two keys,
+    two rows, each the full pack of its pod."""
+    cache, snap, m = _slot_cluster()
+    a = _bind(cache, _no_listed_namespaces("a"), "n0")
+    b = _bind(cache, _no_listed_namespaces("b", **(
+        {"labels": {**WEB, "tier": "back"}} if differ == "labels"
+        else {"namespace": "team-b"})), "n0")
+    assert (PodInfo(a).required_affinity_terms
+            == PodInfo(b).required_affinity_terms)
+    assert Mirror._slot_row_key(PodInfo(a)) != Mirror._slot_row_key(PodInfo(b))
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    st = m.slot_row_cache_stats()
+    assert (st["hits"], st["misses"], st["entries"]) == (0, 2, 2)
+    sa, sb = (m._pod_slot[p.metadata.uid] for p in (a, b))
+    field = "pod_aff_sel_vals" if differ == "labels" else "pod_aff_ns"
+    off, size = m.table_codec._i32_off[field]
+    assert (m.pods_i32[sa, off:off + size].tobytes()
+            != m.pods_i32[sb, off:off + size].tobytes())
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+
+
+def test_a_namespace_selector_term_bypasses_the_slot_row_cache():
+    """Such a row reads the namespace store and the known namespaces: it
+    is packed in full every time, is in _uids_with_nssel, and is packed
+    again when the namespaces move, as before the cache."""
+    make = lambda n: _pod(n, WEB, affinity=Affinity(
+        pod_affinity=PodAffinity(required=[_term(
+            namespace_selector=_sel({"team": "a"}))])))
+    assert Mirror._slot_row_key(PodInfo(make("x"))) is None
+    assert Mirror._slot_row_key(PodInfo(
+        BYPASS_SHAPES["empty-namespace-selector-term"]("y"))) is None
+    cache, snap, m = _slot_cluster()
+    pods = [_bind(cache, make(f"s{i}"), "n0") for i in range(3)]
+    uids = {p.metadata.uid for p in pods}
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert m.slot_row_cache_stats() == {
+        "hits": 0, "misses": 0, "bypass": 3, "clears": 0, "entries": 0}
+    assert m._uids_with_nssel == uids
+    off, size = m.table_codec._i32_off["pod_aff_ns"]
+
+    def listed():
+        return {frozenset(m.pods_i32[m._pod_slot[u], off:off + size]) - {-1}
+                for u in uids}
+
+    assert listed() == {frozenset()}, "no namespace carries the label yet"
+    # a Namespace object the selector matches: the three are packed again
+    cache.set_namespace("alpha", {"team": "a"})
+    cache.update_snapshot(snap)
+    packed = m.slots_packed
+    m.sync(snap)
+    assert m.slots_packed == packed + 3
+    assert listed() == {frozenset({m._i("alpha")})}
+    # a pod in a namespace no packed pod lived in: again, and the new slot
+    _bind(cache, _pod("newcomer", namespace="other"), "n1")
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert m.slots_packed == packed + 7
+    st = m.slot_row_cache_stats()
+    assert (st["hits"], st["misses"], st["bypass"]) == (0, 0, 9)
+    assert m.sync_stats()["slots_packed_terms"] == 9
+    assert m._uids_with_nssel == uids
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+    # the empty selector matches every namespace whatever the store holds:
+    # still a bypass (the key draws _pod_row_key's line), and not in
+    # _uids_with_nssel, since nothing can move its row
+    e = _bind(cache, BYPASS_SHAPES["empty-namespace-selector-term"]("e"),
+              "n2")
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    assert m.slot_row_cache_stats()["bypass"] == 10
+    assert m._uids_with_nssel == uids
+    assert e.metadata.uid in m._uids_with_terms
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+
+
+def test_slot_rows_are_read_only_bounded_and_left_behind_by_a_rebucket(
+        monkeypatch):
+    monkeypatch.setattr(mirror_mod, "POD_ROW_CACHE_ENTRIES", 4)
+    cache, snap, m = _slot_cluster()
+    first = _bind(cache, _no_listed_namespaces("first"), "n0")
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    (kept,) = m._slot_rows.values()
+    with pytest.raises(ValueError):
+        kept[0] = 7
+    assert (kept.tobytes()
+            == m.pods_i32[m._pod_slot[first.metadata.uid]].tobytes())
+    # pods that all differ in a label the term copies: a miss each
+    for i in range(12):
+        _bind(cache, _no_listed_namespaces(
+            f"u{i}", labels={**WEB, "tier": f"t{i}"}), f"n{i % 3}")
+        cache.update_snapshot(snap)
+        m.sync(snap)
+        assert len(m._slot_rows) <= 5
+    st = m.slot_row_cache_stats()
+    assert (st["hits"], st["misses"], st["clears"]) == (0, 13, 2)
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+    # after a clear the old content is a miss again, then a hit
+    for name in ("again", "and-again"):
+        _bind(cache, _no_listed_namespaces(name), "n1")
+    cache.update_snapshot(snap)
+    m.sync(snap)
+    st = m.slot_row_cache_stats()
+    assert (st["hits"], st["misses"]) == (1, 14)
+    _assert_same_tables(m, _fresh_mirror(m, snap, {}))
+    # a re-bucketed mirror: other row widths, an empty cache, the counts
+    grown = Mirror(caps=Capacities(nodes=16, pods=256, aff_terms=8))
+    grown.adopt_hysteresis(m)
+    assert grown.slot_row_cache_stats() == {**st, "entries": 0}
+    assert grown.pods_i32.shape[1] != m.pods_i32.shape[1]
+    grown.sync(snap)
+    after = grown.slot_row_cache_stats()
+    assert after["hits"] + after["misses"] == st["hits"] + st["misses"] + 15
+    assert (grown.sync_stats()["slots_packed_terms"]
+            == m.sync_stats()["slots_packed_terms"] + 15)

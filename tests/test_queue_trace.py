@@ -276,6 +276,86 @@ def test_pack_row_cache_counts_reach_the_registry_and_debug_trace():
         sched.close()
 
 
+def test_slot_row_cache_counts_reach_the_registry_and_debug_trace():
+    """Mirror.slot_row_* -> scheduler_mirror_slot_row_cache_total{result}
+    (by delta, at maintenance) and /debug/trace's "slot_row_cache"; every
+    slot with terms is a hit, a miss or a bypass, and a re-bucketed mirror
+    carries the totals on."""
+    from kubernetes_tpu.api.objects import (
+        Affinity,
+        LABEL_HOSTNAME,
+        LabelSelector,
+        PodAffinity,
+        PodAffinityTerm,
+    )
+    from kubernetes_tpu.backend.mirror import CapacityError
+
+    def affinity_pod(name, namespace_selector=None):
+        pod = mk_sched_pod(name)
+        pod.metadata.labels = {"color": "blue"}
+        pod.spec.affinity = Affinity(pod_affinity=PodAffinity(required=[
+            PodAffinityTerm(
+                topology_key=LABEL_HOSTNAME,
+                label_selector=LabelSelector(match_labels={"color": "blue"}),
+                namespace_selector=namespace_selector)]))
+        return pod
+
+    hub = Hub()
+    sched = _sched(hub)
+    try:
+        hub.create_node(mknode(0))
+        hub.create_pod(affinity_pod("anywhere", LabelSelector()))
+        for rnd in range(3):            # every round syncs the one node
+            for i in range(4):
+                hub.create_pod(affinity_pod(f"p{rnd}-{i}"))
+            sched.run_until_idle()
+        # the slots of the last round's pods wait for the next sync
+        hub.create_pod(affinity_pod("next"))
+        sched.run_until_idle()
+        sched.run_maintenance()
+        m, mirror = sched.metrics, sched.mirror
+        st = mirror.slot_row_cache_stats()
+        assert set(st) == {"hits", "misses", "bypass", "clears", "entries"}
+        assert (st["misses"], st["bypass"], st["clears"]) == (1, 1, 0)
+        assert st["hits"] >= 11 and st["entries"] == 1
+        assert (st["hits"] + st["misses"] + st["bypass"]
+                == mirror.sync_stats()["slots_packed_terms"])
+        for result, key in (("hit", "hits"), ("miss", "misses"),
+                            ("bypass", "bypass")):
+            assert m.mirror_slot_row_cache.value(result=result) == st[key]
+        text = m.registry.render_text()
+        assert (f'scheduler_mirror_slot_row_cache_total{{result="hit"}} '
+                f'{st["hits"]}') in text
+        assert ('scheduler_mirror_slot_row_cache_total{result="bypass"} 1'
+                in text)
+        sched._grow(CapacityError("pod_labels", sched.caps.pod_labels + 1))
+        assert sched.mirror.slot_row_cache_stats() == {**st, "entries": 0}
+        hub.create_pod(affinity_pod("late"))
+        sched.run_until_idle()
+        sched.run_maintenance()
+        after = sched.mirror.slot_row_cache_stats()
+        # the fresh mirror packs the cluster again: one more miss, then hits
+        assert after["misses"] == 2 and after["bypass"] == 2
+        assert (after["hits"] + after["misses"] + after["bypass"]
+                == sched.mirror.sync_stats()["slots_packed_terms"])
+        assert m.mirror_slot_row_cache.value(result="hit") == after["hits"]
+        assert m.mirror_slot_row_cache.value(result="miss") == 2
+        assert m.mirror_slot_terms.value() == (
+            after["hits"] + after["misses"] + after["bypass"])
+        srv = ServingEndpoints(sched, port=0, debug_auth=token_auth("t"))
+        srv.start()
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{srv.port}/debug/trace?n=1")
+            req.add_header("Authorization", "Bearer t")
+            tr = json.loads(urllib.request.urlopen(req, timeout=5).read())
+        finally:
+            srv.stop()
+        assert tr["slot_row_cache"] == after
+    finally:
+        sched.close()
+
+
 def test_mirror_sync_counts_reach_the_registry_and_debug_trace():
     """Mirror.sync_stats() -> scheduler_mirror_slot_total{result} (by
     delta, at maintenance) and /debug/trace's "mirror_sync"; a pod that
