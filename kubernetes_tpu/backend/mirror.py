@@ -196,6 +196,14 @@ BUCKET_DECAY_LAUNCHES = 64
 # that never repeat holds a few MiB at most
 POD_ROW_CACHE_ENTRIES = 4096
 
+# widest dirty set whose scatter bucket is compiled ahead (_warm_scatter): a
+# launch dirties at most a node row and a pod slot a pod, and no
+# configuration launches more than 4,096. Warming up to the bound where a
+# push becomes a full upload (a quarter of the table) cost 1.2 to 2 s and
+# 10 to 273 MB of peak device memory a start on the chip, for buckets of
+# 8,192 to 65,536 rows that only a bulk change can reach (PERF.md 6, PR 34)
+SCATTER_WARM_ROWS = 4096
+
 
 def _selector_key(sel):
     """A LabelSelector's content for Mirror._pod_row_key (None stays
@@ -1238,10 +1246,13 @@ class Mirror:
         UpdateSnapshot (a few hundred KB per cycle instead of the whole
         multi-MB mirror over the host<->TPU link)."""
         dev = self._dev.get(key)
-        if dev is None or full or len(dirty) > max(64, host_buf.shape[0] // 4):
+        limit = max(64, host_buf.shape[0] // 4)
+        if dev is None or full or len(dirty) > limit:
             sh = self._dev_sharding.get(key)
             self._dev[key] = (jnp.asarray(host_buf) if sh is None
                               else jax.device_put(host_buf, sh))
+            if dev is None:
+                self._warm_scatter(key, host_buf, limit)
             return
         if not dirty:
             return
@@ -1257,6 +1268,30 @@ class Mirror:
         scatter = self._scatter_fns.get(key, _scatter_rows_jit)
         self._dev[key] = scatter(dev, jnp.asarray(arr),
                                  jnp.asarray(host_buf[arr]))
+
+    def _warm_scatter(self, key: str, host_buf: np.ndarray,
+                      limit: int) -> None:
+        """Compile (or load from the compile cache) the row scatter of
+        one freshly uploaded buffer at every pow2 bucket a launch's dirty
+        set can take: up to SCATTER_WARM_ROWS, or to `limit` where that
+        is less (a dirty set over `limit` rows is a full upload). Each is
+        a write that changes nothing: row 0 onto itself. The launch cache
+        is blind to these programs, so a bucket first met mid-drain was a
+        compile nobody counted: under steady arrivals every launch
+        dirties 10 or 15 rows, and the one launch that a pause made carry
+        20 met the 32-row bucket inside the measured window (PERF.md 7,
+        fault 3)."""
+        scatter = self._scatter_fns.get(key, _scatter_rows_jit)
+        limit = min(limit, SCATTER_WARM_ROWS)
+        k = 1
+        while True:
+            self._dev[key] = scatter(
+                self._dev[key], jnp.asarray(np.zeros(k, np.int32)),
+                jnp.asarray(np.broadcast_to(host_buf[0],
+                                            (k, host_buf.shape[1]))))
+            if k >= limit:
+                return
+            k *= 2
 
     def to_blobs(self) -> ClusterBlobs:
         """Refresh the device-resident mirror (incremental row scatter or

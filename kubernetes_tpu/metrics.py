@@ -412,6 +412,11 @@ class SchedulerMetrics:
         self.device_launch_shapes = r.register(Gauge(
             "scheduler_device_launch_shapes",
             "Distinct launch bucket shapes this process has dispatched"))
+        self.device_launch_fill = r.register(Gauge(
+            "scheduler_device_launch_fill",
+            "Pods carried over rows launched (launches times the batch "
+            "bucket) by launch shape, 0 to 1: a launch costs its full "
+            "width, so a shape near 0 pays for rows it does not use"))
         self.device_live_buffer_bytes = r.register(Gauge(
             "scheduler_device_live_buffer_bytes",
             "Resident device-buffer bytes by buffer family (cluster "

@@ -250,7 +250,7 @@ def _guard_reduction(scores: jnp.ndarray, free: jnp.ndarray) -> jnp.ndarray:
 # per-kernel device time.
 KERNEL_SCOPES = ("static_filters", "auction_rounds", "soft_topology_auction",
                  "commit_scan", "patch_chain", "scatter_rows",
-                 "inter_pod_affinity")
+                 "inter_pod_affinity", "scan_queries", "scan_map_updates")
 
 
 @jax.named_scope("static_filters")
@@ -1181,6 +1181,7 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
     topo_dom = ct.topo_dom
     tk_cap = topo_dom.shape[1]
 
+    @jax.named_scope("scan_queries")
     def queries(g, forbid1_n, map2_n, pres_n, any3, wscore_n, cntmap,
                 cnt_match_n):
         """Per-step topology verdicts for a group-g pod from the carry maps
@@ -1244,6 +1245,7 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
         paff_w_soft = soft_st.paff_w_g
         panti_w_soft = soft_st.panti_w_g
 
+        @jax.named_scope("scan_map_updates")
         def soft_map_updates(g, r, do, wscore_n, cnt_match_n):
             dom_row = topo_dom[r]                              # [TK]
             same_dom = ((topo_dom == dom_row[None])
@@ -1279,6 +1281,7 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
         oh_panti_own = tk_onehot(panti_tk_g)
         oh_tsc_own = tk_onehot(tsc_tk_g)    # [G, C, TK]
 
+    @jax.named_scope("scan_map_updates")
     def map_updates(g, r, do, forbid1_n, map2_n, pres_n, any3, wscore_n,
                     cntmap, cnt_match_n):
         """Fold ONE commit (group-g pod on node row r) into the carry maps.

@@ -1900,7 +1900,7 @@ class Scheduler:
                 not use_auction, spec.dra is not None,
                 learned_params is not None, want_feats,
                 alts=want_alts, soft=spec.topo_soft)
-            compiled = prof.note_launch(pshape)
+            compiled = prof.note_launch(pshape, len(runnable))
             if compiled or prof.launches == 1:
                 # buffer footprints are bucket-static: re-measure only
                 # when a compile (= a bucket/flag change) happened
@@ -2172,7 +2172,7 @@ class Scheduler:
             pshape = shape_key(self.caps, g_bucket, False, d_bucket, 0,
                                True, False, False, False,
                                gang=g_bucket)
-            prof.note_launch(pshape)
+            prof.note_launch(pshape, len(units))
         # ONE pull for the whole wave: verdicts + placements + capacity
         # bounds + spans (+ any PreFilter capacity reductions awaiting
         # their ride — the folded gang_capacity D2H)

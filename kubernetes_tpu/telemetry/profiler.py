@@ -16,6 +16,10 @@ and execution indistinguishably. This instrument attributes it:
   is forcing recompiles.
 * **Per-launch walltime** per shape (count/total/max), so "one shape is
   slow" and "one shape keeps recompiling" read differently.
+* **Pods and fill** per shape: the pods its launches carried, and their
+  share of the rows the program was compiled for (launches times the
+  batch bucket). A launch costs its full width whatever it carries, so a
+  shape that runs 1% full is where a narrower program would pay.
 * **Live buffer bytes** — the HBM footprint of what the scheduler keeps
   resident: the nodes×resources cluster tensors, the per-batch pod
   tensors, the dense DRA inventories, the learned-scorer params
@@ -57,6 +61,25 @@ def shape_key(caps, b_bucket: int, enable_topology: bool, d_cap,
             ("learned", bool(learned)), ("feats", bool(with_feats)),
             ("gang", gang), ("alts", bool(alts)), ("soft", bool(soft)),
             *cap_t)
+
+
+def shape_label(shape: tuple) -> str:
+    """The shape as /debug/trace and the fill gauge name it: the batch
+    and capacity buckets, and what tells one topology program from
+    another (the domain bucket, the serial scan, the soft-only scan)."""
+    d = dict(shape)
+    base = (f"b={d.get('b')} nodes={d.get('nodes')} "
+            f"pods={d.get('pods')} topo={int(d.get('topo', 0))} "
+            f"d_cap={d.get('d_cap')} serial={int(d.get('serial', 0))} "
+            f"soft={int(d.get('soft', 0))} dra={int(d.get('dra', 0))}")
+    gang = d.get("gang", 0)
+    return f"{base} gang={gang}" if gang else base
+
+
+def _fill(shape: tuple, rec: dict) -> float:
+    """Pods carried over rows launched, 0.0 to 1.0."""
+    rows = rec["launches"] * (dict(shape).get("b") or 0)
+    return rec["pods"] / rows if rows else 0.0
 
 
 def _diff_cause(prev: Optional[tuple], cur: tuple) -> str:
@@ -104,23 +127,26 @@ class DeviceProfiler:
         self.compiles = 0
         self.compile_causes: dict[str, int] = {}
         self.compile_events: list[dict] = []   # ring, newest last
-        # shape -> {"launches", "compiles", "walltime_s", "max_s"}
+        # shape -> {"launches", "pods", "compiles", "walltime_s", "max_s"}
         self.shapes: dict[tuple, dict] = {}
         self.buffer_bytes: dict[str, int] = {}
 
     # ------------- recording (loop thread) -------------
 
-    def note_launch(self, shape: tuple) -> bool:
-        """Record one dispatched launch; returns True when the jit
-        executable cache grew (a real XLA compile happened while
-        tracing this launch)."""
+    def note_launch(self, shape: tuple, pods: int = 0) -> bool:
+        """Record one dispatched launch that carried ``pods`` rows of its
+        batch bucket (pods; gang units for a gang-pack launch); returns
+        True when the jit executable cache grew (a real XLA compile
+        happened while tracing this launch)."""
         self.launches += 1
         rec = self.shapes.get(shape)
         first_of_shape = rec is None
         if rec is None:
-            rec = self.shapes[shape] = {"launches": 0, "compiles": 0,
+            rec = self.shapes[shape] = {"launches": 0, "pods": 0,
+                                        "compiles": 0,
                                         "walltime_s": 0.0, "max_s": 0.0}
         rec["launches"] += 1
+        rec["pods"] += pods
         cache = self._cache_size_fn()
         compiled = cache > self._last_cache
         if compiled:
@@ -148,6 +174,8 @@ class DeviceProfiler:
         if self._metrics is not None:
             self._metrics.device_launch_shapes.set(
                 float(len(self.shapes)))
+            self._metrics.device_launch_fill.set(
+                _fill(shape, rec), shape=shape_label(shape))
         return compiled
 
     def observe_walltime(self, shape: tuple, secs: float) -> None:
@@ -169,14 +197,6 @@ class DeviceProfiler:
 
     def snapshot(self, events: int = 16) -> dict:
         """The /debug/trace and perf-harness payload."""
-        def label(shape: tuple) -> str:
-            d = dict(shape)
-            base = (f"b={d.get('b')} nodes={d.get('nodes')} "
-                    f"pods={d.get('pods')} topo={int(d.get('topo', 0))} "
-                    f"dra={int(d.get('dra', 0))}")
-            gang = d.get("gang", 0)
-            return f"{base} gang={gang}" if gang else base
-
         return {
             "launches": self.launches,
             "compiles": self.compiles,
@@ -184,7 +204,8 @@ class DeviceProfiler:
             "unattributed_compiles":
                 self.compile_causes.get("unattributed", 0),
             "shapes": [
-                {"shape": label(s), **rec,
+                {"shape": shape_label(s), **rec,
+                 "fill": round(_fill(s, rec), 4),
                  "walltime_s": round(rec["walltime_s"], 4),
                  "max_s": round(rec["max_s"], 4)}
                 for s, rec in self.shapes.items()],

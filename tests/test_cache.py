@@ -1,3 +1,5 @@
+import pytest
+
 from kubernetes_tpu.api.objects import (
     LABEL_ZONE,
     Container,
@@ -202,6 +204,62 @@ def test_incremental_device_push_matches_full_upload():
     np.testing.assert_array_equal(np.asarray(blobs.pods_i32), mirror.pods_i32)
 
 
+@pytest.mark.parametrize("dirty", [1, 2, 3, 5, 8, 13, 21, 40, 64])
+def test_no_dirty_set_meets_a_scatter_bucket_for_the_first_time(dirty):
+    """The first upload of a buffer compiles its row scatter at every pow2
+    bucket a launch's dirty set can take (up to SCATTER_WARM_ROWS, here up
+    to where a push becomes a full upload), with writes that change
+    nothing; after it a push of any such number of dirty rows compiles
+    nothing (a bucket first met mid-drain was a compile inside a benchmark
+    window, PERF.md 7 fault 3)."""
+    import numpy as np
+
+    from kubernetes_tpu.backend.mirror import Mirror, _scatter_rows_jit
+    from kubernetes_tpu.models.testbed import build_cluster
+    from kubernetes_tpu.ops.features import Capacities
+
+    caps = Capacities(nodes=128, pods=256)      # pushes scatter up to 64 rows
+    _cache, _snap, mirror = build_cluster(10, caps=caps)
+    blobs = mirror.to_blobs()                   # first upload, and the warm
+    for got, host in ((blobs.node_f32, mirror.node_f32),
+                      (blobs.node_i32, mirror.node_i32),
+                      (blobs.pods_i32, mirror.pods_i32)):
+        np.testing.assert_array_equal(np.asarray(got), host)
+    compiled = _scatter_rows_jit._cache_size()
+    rows = list(range(60, 60 + dirty))
+    mirror.node_f32[rows, 0] = 7.0
+    mirror.pods_i32[rows, 0] = 7
+    mirror._dirty_rows.update(rows)
+    mirror._dirty_slots.update(rows)
+    blobs = mirror.to_blobs()
+    assert _scatter_rows_jit._cache_size() == compiled
+    np.testing.assert_array_equal(np.asarray(blobs.node_f32), mirror.node_f32)
+    np.testing.assert_array_equal(np.asarray(blobs.pods_i32), mirror.pods_i32)
+    # a second mirror of the same shapes warms from the programs that exist
+    Mirror(caps=caps).to_blobs()
+    assert _scatter_rows_jit._cache_size() == compiled
+
+
+def test_scatter_warm_stops_at_its_bound(monkeypatch):
+    """Buckets over SCATTER_WARM_ROWS are left to the push that needs one:
+    with the bound at 8 a fresh mirror compiles four buckets a buffer, a
+    push of 5 dirty rows compiles nothing and a push of 13 its own."""
+    from kubernetes_tpu.backend import mirror as mirror_mod
+    from kubernetes_tpu.ops.features import Capacities
+
+    monkeypatch.setattr(mirror_mod, "SCATTER_WARM_ROWS", 8)
+    before = mirror_mod._scatter_rows_jit._cache_size()
+    m = mirror_mod.Mirror(caps=Capacities(nodes=320, pods=640))  # own shapes
+    m.to_blobs()
+    warmed = mirror_mod._scatter_rows_jit._cache_size()
+    assert warmed - before == 3 * 4             # 1, 2, 4, 8 rows; 3 buffers
+    for dirty, grew in ((5, 0), (13, 3)):
+        m._dirty_rows.update(range(dirty))
+        m._dirty_slots.update(range(dirty))
+        m.to_blobs()
+        assert mirror_mod._scatter_rows_jit._cache_size() - warmed == grew
+
+
 def test_cache_comparer_against_hub():
     """backend/cache/debugger/comparer.go CompareNodes/ComparePods."""
     from kubernetes_tpu.hub import Hub
@@ -236,5 +294,4 @@ def test_cache_comparer_against_hub():
 
 
 # suite-tier discipline (tests/test_markers.py): area marker
-import pytest  # noqa: E402
 pytestmark = pytest.mark.core

@@ -34,11 +34,16 @@ from tests.test_soft_auction import soft_pod
 pytestmark = pytest.mark.core
 
 CAPS = Capacities(nodes=64, pods=256)
+BODY_SCOPES = ("scan_queries", "scan_map_updates")
 
 
 def _scoped(name, text):
     """The scope is part of an operation's name stack below the jitted
-    program: "jit(f)/name/op", or "jit(f)/vmap(name)/op" under a vmap."""
+    program: "jit(f)/name/op", or "jit(f)/vmap(name)/op" under a vmap. A
+    scope inside the commit scan's body heads a name stack of its own
+    there ("name/op"): the body is lowered as its own function."""
+    if name in BODY_SCOPES:
+        return re.search(rf'loc\("{name}/', text) is not None
     return re.search(rf'"jit\([^"]*[/(]{name}[/)]', text) is not None
 
 
@@ -93,11 +98,12 @@ def _soft_spec():
     (_plain_spec, False, ("static_filters", "auction_rounds"),
      ("commit_scan", "soft_topology_auction")),
     (_plain_spec, True, ("static_filters", "commit_scan"),
-     ("auction_rounds",)),
+     ("auction_rounds", "scan_queries", "scan_map_updates")),
     (_soft_spec, False, ("static_filters", "soft_topology_auction"),
      ("auction_rounds", "commit_scan")),
     (_affinity_spec, True, ("static_filters", "inter_pod_affinity",
-                            "commit_scan"), ("auction_rounds",)),
+                            "commit_scan", "scan_queries",
+                            "scan_map_updates"), ("auction_rounds",)),
 ])
 def test_schedule_batch_kernels_carry_their_scope(make, serial_scan, scopes,
                                                   absent):
@@ -120,4 +126,5 @@ def test_chain_and_mirror_scatters_carry_their_scope():
         free, idx, rows[0]).as_text(debug_info=True))
     assert set(KERNEL_SCOPES) == {
         "static_filters", "auction_rounds", "soft_topology_auction",
-        "commit_scan", "patch_chain", "scatter_rows", "inter_pod_affinity"}
+        "commit_scan", "patch_chain", "scatter_rows", "inter_pod_affinity",
+        "scan_queries", "scan_map_updates"}

@@ -550,6 +550,50 @@ def test_device_profiler_attributes_compiles():
     assert any(s["walltime_s"] == 0.5 for s in snap["shapes"])
 
 
+@pytest.mark.parametrize("d_cap, serial, soft, label", [
+    # the zone scan of the spread and affinity cells, the hostname scan of
+    # the anti-affinity cell, a soft-only topology launch, an auction
+    (8, True, False, "topo=1 d_cap=8 serial=1 soft=0"),
+    (8192, True, False, "topo=1 d_cap=8192 serial=1 soft=0"),
+    (8192, True, True, "topo=1 d_cap=8192 serial=1 soft=1"),
+    (8, False, False, "topo=1 d_cap=8 serial=0 soft=0"),
+])
+def test_device_profiler_keeps_pods_and_fill_per_shape(d_cap, serial, soft,
+                                                       label):
+    """Each shape keeps the pods its launches carried beside their number;
+    the snapshot gives their share of the rows launched and names what
+    tells one topology program from another."""
+    from kubernetes_tpu.metrics import SchedulerMetrics
+    from kubernetes_tpu.ops.features import Capacities
+
+    caps = Capacities(nodes=8192, pods=131072)
+    metrics = SchedulerMetrics()
+    prof = DeviceProfiler(metrics=metrics, cache_size_fn=lambda: 0,
+                          now=lambda: 0.0)
+    shape = shape_key(caps, 1024, True, d_cap, 2, serial, False, False,
+                      False, soft=soft)
+    other = shape_key(caps, 1024, False, 0, 0, False, False, False, False)
+    for pods in (13, 12, 14, 13):
+        prof.note_launch(shape, pods)
+    prof.note_launch(other, 1024)
+    prof.note_launch(other)             # a caller that gives no count
+    assert prof.shapes[shape]["launches"] == 4
+    assert prof.shapes[shape]["pods"] == 52
+    snap = {s["shape"]: s for s in prof.snapshot()["shapes"]}
+    mine = f"b=1024 nodes=8192 pods=131072 {label} dra=0"
+    plain = "b=1024 nodes=8192 pods=131072 topo=0 d_cap=0 serial=0 soft=0 dra=0"
+    assert set(snap) == {mine, plain}
+    assert (snap[mine]["launches"], snap[mine]["pods"]) == (4, 52)
+    assert snap[mine]["fill"] == round(52 / 4096, 4)
+    assert (snap[plain]["pods"], snap[plain]["fill"]) == (1024, 0.5)
+    # the gauge carries the same share under the same label
+    fills = {dict(k)["shape"]: v
+             for k, v in metrics.device_launch_fill.collect().items()}
+    assert fills == {mine: 52 / 4096, plain: 0.5}
+    text = metrics.registry.render_text()
+    assert f'scheduler_device_launch_fill{{shape="{mine}"}}' in text
+
+
 def test_device_profiler_on_live_scheduler_rebucket():
     """Every recompile in a churn-with-growth run attributes to a
     bucket-shape transition (the MixedChurn acceptance criterion in
@@ -575,6 +619,11 @@ def test_device_profiler_on_live_scheduler_rebucket():
         snap = sched.profiler.snapshot()
         assert snap["launches"] >= 2
         assert snap["compiles"] >= 1
+        # every pod rode a launch, 16 rows wide; the gauge has each shape
+        assert sum(s["pods"] for s in snap["shapes"]) >= 40
+        assert all(0.0 < s["fill"] <= 1.0 for s in snap["shapes"])
+        assert len(sched.metrics.device_launch_fill.collect()) \
+            == len(snap["shapes"])
         assert snap["unattributed_compiles"] == 0, snap
         assert snap["buffer_bytes"].get("cluster", 0) > 0
         # the compile counter mirrored into the registry
