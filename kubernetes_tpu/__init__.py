@@ -12,7 +12,7 @@ TPU-first:
   Score, normalization, weighted aggregation and masked argmax over a dense
   ``nodes x features`` tensor resident in HBM, with pending pods batched
   along a second axis so one XLA launch schedules a whole batch
-  (as-if-serial semantics via a lax.scan commit loop).
+  (as-if-serial semantics via a commit loop over the batch's rows).
 
 Layer map (mirrors SURVEY.md section 1, scheduler-internal layering):
 

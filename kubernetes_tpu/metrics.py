@@ -417,6 +417,12 @@ class SchedulerMetrics:
             "Pods carried over rows launched (launches times the batch "
             "bucket) by launch shape, 0 to 1: a launch costs its full "
             "width, so a shape near 0 pays for rows it does not use"))
+        self.device_scan_steps = r.register(Counter(
+            "scheduler_device_scan_steps_total",
+            "Rows of serial-scan launches by what the commit scan did "
+            "with them: run (whole blocks up to the last row that "
+            "carries a pod) or skipped (the rest of the batch bucket)",
+            ("result",)))
         self.device_live_buffer_bytes = r.register(Gauge(
             "scheduler_device_live_buffer_bytes",
             "Resident device-buffer bytes by buffer family (cluster "

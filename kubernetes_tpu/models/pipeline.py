@@ -12,12 +12,17 @@ phases:
    affinity/selectors, host ports, unschedulable, image locality — is
    evaluated for ALL (pod, node) pairs at once. This is where the FLOPs
    are, and it is embarrassingly parallel over both axes.
-2. **Commit scan** (lax.scan over pods): a deliberately tiny sequential
-   pass that re-evaluates only what a previous pod's commit can invalidate
-   — the resource fit predicate and the utilization scores — then
-   normalizes, aggregates, argmaxes, and commits the winner's resources to
-   the scan carry. Pod b+1 therefore sees pod b's placement exactly as the
-   serial loop's assume step would provide ("as-if-serial").
+2. **Commit scan** (a loop over the batch's rows): a deliberately tiny
+   sequential pass that re-evaluates only what a previous pod's commit can
+   invalidate — the resource fit predicate and the utilization scores —
+   then normalizes, aggregates, argmaxes, and commits the winner's
+   resources to the scan carry. Pod b+1 therefore sees pod b's placement
+   exactly as the serial loop's assume step would provide ("as-if-serial").
+   The scan's length follows the batch, not the bucket it was compiled at:
+   it runs blocks of ``scan_unroll()`` steps up to the last row that
+   carries a pod and stops (``BatchResult.scan_steps``), so a launch of 13
+   pods in a 1,024-row program pays for 16 steps. A padding step changes
+   no carry, so the placements are those of the full-length scan.
 
 The node axis is the sharding axis: under a ``jax.sharding.Mesh`` the
 per-node work is data-parallel; argmax and normalization reductions become
@@ -98,8 +103,9 @@ ALT_K = 4
 # ALT_NONE/2 is "no candidate" on the host side
 ALT_NONE = -1e9
 
-# commit-scan unroll factor (see the lax.scan call): amortizes per-iteration
-# dispatch overhead, which dominates the topology scan at these shapes.
+# commit-scan unroll factor (the steps in one block of the scan's loop, see
+# schedule_batch): amortizes per-iteration dispatch overhead, which
+# dominates the topology scan at these shapes.
 # 16 on TPU (+15-25% on the topology workloads on the round-5 rig; the
 # full-width [2048 x 8192] program compiles and runs at 16 on a v5e —
 # chip_smoke.py leg B); 4 on CPU, where the only effect of a bigger body
@@ -113,6 +119,21 @@ def scan_unroll() -> int:
     if _SCAN_UNROLL is None:
         _SCAN_UNROLL = 4 if jax.default_backend() == "cpu" else 16
     return _SCAN_UNROLL
+
+
+def _scan_block(b: int) -> int:
+    """Steps in one block of the commit scan for a batch bucket of ``b``
+    rows: scan_unroll(), or the whole batch where that is shorter."""
+    return min(scan_unroll(), b)
+
+
+def scan_steps_for(n_rows: int, b: int) -> int:
+    """The commit-scan steps a serial launch runs (BatchResult.scan_steps,
+    as host arithmetic): whole blocks up to row ``n_rows`` (1 + the index
+    of the last row that carries a pod; pods are packed as a prefix, so
+    the number of pods), never past the batch bucket ``b``."""
+    u = _scan_block(b)
+    return min(-(-n_rows // u) * u, b)
 
 
 # auction-round unroll factor (see _rounds_commit): how many K-accept
@@ -221,6 +242,11 @@ class BatchResult:
     # (it is top-1 in the common case); the offline consumer filters it.
     alt_row: jax.Array
     alt_score: jax.Array
+    # [] i32: commit-scan steps this launch ran (whole blocks of
+    # scan_unroll() up to the last row that carries a pod; 0 on the
+    # auction path, which has no scan). scan_steps_for() is the same
+    # number on the host; nobody pulls this one.
+    scan_steps: jax.Array
 
 
 # workload-activity flags (STATIC, host-derived per launch by
@@ -832,7 +858,8 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
                        dra_reject=(jnp.zeros((B,), jnp.int32)
                                    if dra_reject is None else dra_reject),
                        learned_mag=learned_mag, chosen_feat=chosen_feat,
-                       alt_row=alt_row, alt_score=alt_score)
+                       alt_row=alt_row, alt_score=alt_score,
+                       scan_steps=jnp.int32(0))
 
 
 def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
@@ -1559,12 +1586,54 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
         # BatchResult.pct_start so rotation persists ACROSS batches
         init = init + (jnp.int32(0) if pct_start is None
                        else jnp.asarray(pct_start, jnp.int32),)
-    # unroll: the body is many small fused kernels; per-iteration dispatch
-    # overhead (not FLOPs) is a real cost at these shapes, so unrolling
-    # amortizes it
+    # The scan's length follows the batch, not the bucket: blocks of u
+    # steps (the body is many small fused kernels, so per-iteration
+    # dispatch overhead is a real cost at these shapes and a block
+    # amortizes it), as many as reach the last row that carries a pod. A
+    # step on a padding row changes no carry (static_ok all false, row -1,
+    # do false; under pct_nodes `start` comes back where it was), so the
+    # rows past the last block keep what such a step writes.
+    u = _scan_block(B)
+    n_live = jnp.max(jnp.where(pods.valid,
+                               jnp.arange(1, B + 1, dtype=jnp.int32), 0))
+    n_blocks = (n_live + (u - 1)) // u
+    pad = (-B) % u
+    if pad:
+        # direct callers only (the host's buckets are multiples of u): the
+        # last block's steps past B see rows no node accepts
+        xs = (jnp.arange(B + pad),) + jax.tree.map(
+            lambda x: jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)),
+            xs[1:])
+    ys_fill = [-1, 0.0, 0, 0, 0, 0, 0]       # row, win, feas, 4 rejects
+    if learned is not None:
+        ys_fill.append(0.0)
+    if with_feats:
+        ys_fill.append(0.0)
+    if with_alts:
+        ys_fill += [-1, ALT_NONE]
+    ys_sds = jax.eval_shape(lambda c, x: body(c, x)[1], init,
+                            jax.tree.map(lambda x: x[0], xs))
+    ys_init = tuple(jnp.full((B + pad,) + sd.shape, fill, sd.dtype)
+                    for sd, fill in zip(ys_sds, ys_fill, strict=True))
+
+    # traced once and called u times a block, as lax.scan's unroll did
+    step = jax.jit(body)
+
+    def block(k, state):
+        carry, ys_buf = state
+        for j in range(u):
+            i = k * u + j
+            carry, ys = step(carry, jax.tree.map(
+                lambda x: jax.lax.dynamic_index_in_dim(x, i, 0, False), xs))
+            ys_buf = tuple(jax.lax.dynamic_update_index_in_dim(buf, y, i, 0)
+                           for buf, y in zip(ys_buf, ys))
+        return carry, ys_buf
+
     with jax.named_scope("commit_scan"):
-        (carry_out, ys_out) = jax.lax.scan(body, init, xs,
-                                           unroll=scan_unroll())
+        (carry_out, ys_out) = jax.lax.fori_loop(0, n_blocks, block,
+                                                (init, ys_init))
+    if pad:
+        ys_out = tuple(y[:B] for y in ys_out)
     (rows, win_scores, feas, port_rejects, fit_rejects, sp_rejects,
      ipa_rejects) = ys_out[:7]
     extra = list(ys_out[7:])
@@ -1596,7 +1665,8 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                        guard=_guard_reduction(win_scores, free_out),
                        dra_reject=dra_reject, learned_mag=learned_mag,
                        chosen_feat=chosen_feat,
-                       alt_row=alt_row, alt_score=alt_score)
+                       alt_row=alt_row, alt_score=alt_score,
+                       scan_steps=jnp.minimum(n_blocks * u, B))
 
 
 @partial(jax.jit, static_argnames=("caps", "enable_topology", "d_cap",

@@ -68,6 +68,7 @@ from kubernetes_tpu.models.pipeline import (
     extract_state_jit,
     launch_batch,
     patch_chain,
+    scan_steps_for,
     warm_patch_chain,
 )
 from kubernetes_tpu.metrics import AsyncRecorder, SchedulerMetrics
@@ -1900,7 +1901,10 @@ class Scheduler:
                 not use_auction, spec.dra is not None,
                 learned_params is not None, want_feats,
                 alts=want_alts, soft=spec.topo_soft)
-            compiled = prof.note_launch(pshape, len(runnable))
+            compiled = prof.note_launch(
+                pshape, len(runnable),
+                None if use_auction else scan_steps_for(
+                    len(runnable), spec.pblobs.f32.shape[0]))
             if compiled or prof.launches == 1:
                 # buffer footprints are bucket-static: re-measure only
                 # when a compile (= a bucket/flag change) happened

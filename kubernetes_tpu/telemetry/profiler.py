@@ -20,6 +20,11 @@ and execution indistinguishably. This instrument attributes it:
   share of the rows the program was compiled for (launches times the
   batch bucket). A launch costs its full width whatever it carries, so a
   shape that runs 1% full is where a narrower program would pay.
+* **Scan steps** per shape: the commit-scan steps its serial launches
+  ran (the scan stops after the last row that carries a pod,
+  ``pipeline.scan_steps_for``), and the share of the rows launched that
+  it skipped. Near 0 in a backlog, near 1 where launches run almost
+  empty: what the scan no longer pays for of the width above.
 * **Live buffer bytes** — the HBM footprint of what the scheduler keeps
   resident: the nodes×resources cluster tensors, the per-batch pod
   tensors, the dense DRA inventories, the learned-scorer params
@@ -76,10 +81,27 @@ def shape_label(shape: tuple) -> str:
     return f"{base} gang={gang}" if gang else base
 
 
+def _rows(shape: tuple, rec: dict) -> int:
+    """Rows the shape's launches were compiled for: launches times the
+    batch bucket."""
+    return rec["launches"] * (dict(shape).get("b") or 0)
+
+
 def _fill(shape: tuple, rec: dict) -> float:
     """Pods carried over rows launched, 0.0 to 1.0."""
-    rows = rec["launches"] * (dict(shape).get("b") or 0)
+    rows = _rows(shape, rec)
     return rec["pods"] / rows if rows else 0.0
+
+
+def _steps_skipped(shape: tuple, rec: dict) -> float:
+    """Share of the rows launched that the commit scan did not step
+    over, 0.0 to 1.0 (0.0 for a shape that runs no scan, an auction or a
+    gang pack: nothing to skip)."""
+    d = dict(shape)
+    rows = _rows(shape, rec)
+    if not rows or not d.get("serial") or d.get("gang"):
+        return 0.0
+    return 1.0 - rec["steps"] / rows
 
 
 def _diff_cause(prev: Optional[tuple], cur: tuple) -> str:
@@ -127,26 +149,31 @@ class DeviceProfiler:
         self.compiles = 0
         self.compile_causes: dict[str, int] = {}
         self.compile_events: list[dict] = []   # ring, newest last
-        # shape -> {"launches", "pods", "compiles", "walltime_s", "max_s"}
+        # shape -> {"launches", "pods", "steps", "compiles", "walltime_s",
+        #           "max_s"}
         self.shapes: dict[tuple, dict] = {}
         self.buffer_bytes: dict[str, int] = {}
 
     # ------------- recording (loop thread) -------------
 
-    def note_launch(self, shape: tuple, pods: int = 0) -> bool:
+    def note_launch(self, shape: tuple, pods: int = 0,
+                    steps: Optional[int] = None) -> bool:
         """Record one dispatched launch that carried ``pods`` rows of its
-        batch bucket (pods; gang units for a gang-pack launch); returns
-        True when the jit executable cache grew (a real XLA compile
-        happened while tracing this launch)."""
+        batch bucket (pods; gang units for a gang-pack launch) and, where
+        it ran the serial commit scan, the ``steps`` the scan took
+        (pipeline.scan_steps_for; None for an auction or a gang pack);
+        returns True when the jit executable cache grew (a real XLA
+        compile happened while tracing this launch)."""
         self.launches += 1
         rec = self.shapes.get(shape)
         first_of_shape = rec is None
         if rec is None:
             rec = self.shapes[shape] = {"launches": 0, "pods": 0,
-                                        "compiles": 0,
+                                        "steps": 0, "compiles": 0,
                                         "walltime_s": 0.0, "max_s": 0.0}
         rec["launches"] += 1
         rec["pods"] += pods
+        rec["steps"] += steps or 0
         cache = self._cache_size_fn()
         compiled = cache > self._last_cache
         if compiled:
@@ -176,6 +203,10 @@ class DeviceProfiler:
                 float(len(self.shapes)))
             self._metrics.device_launch_fill.set(
                 _fill(shape, rec), shape=shape_label(shape))
+            if steps is not None:
+                self._metrics.device_scan_steps.inc(steps, result="run")
+                self._metrics.device_scan_steps.inc(
+                    (dict(shape).get("b") or 0) - steps, result="skipped")
         return compiled
 
     def observe_walltime(self, shape: tuple, secs: float) -> None:
@@ -206,6 +237,7 @@ class DeviceProfiler:
             "shapes": [
                 {"shape": shape_label(s), **rec,
                  "fill": round(_fill(s, rec), 4),
+                 "steps_skipped": round(_steps_skipped(s, rec), 4),
                  "walltime_s": round(rec["walltime_s"], 4),
                  "max_s": round(rec["max_s"], 4)}
                 for s, rec in self.shapes.items()],

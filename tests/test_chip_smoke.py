@@ -111,6 +111,11 @@ def test_compile_cache_directory_is_placed_from_outside(tmp_path):
     assert _cache_dir_seen(env) == str(outside)
     assert os.listdir(outside), "the program was not cached where told"
     after = set(os.listdir(default)) if os.path.isdir(default) else set()
-    assert after == before, f".jax_cache gained {sorted(after - before)}"
+    # the suite's other workers compile into the default directory all the
+    # while: only an entry of a program the probe ran is the probe's
+    probe = {name.rsplit("-", 2)[0] for name in os.listdir(outside)}
+    gained = {name for name in after - before
+              if name.rsplit("-", 2)[0] in probe}
+    assert not gained, f".jax_cache gained {sorted(gained)}"
     env.pop("JAX_COMPILATION_CACHE_DIR")
     assert _cache_dir_seen(env) == default

@@ -41,7 +41,8 @@ def _scoped(name, text):
     """The scope is part of an operation's name stack below the jitted
     program: "jit(f)/name/op", or "jit(f)/vmap(name)/op" under a vmap. A
     scope inside the commit scan's body heads a name stack of its own
-    there ("name/op"): the body is lowered as its own function."""
+    there ("name/op"): the body is lowered as its own function, called
+    from "jit(f)/commit_scan/while/body/jit(body)"."""
     if name in BODY_SCOPES:
         return re.search(rf'loc\("{name}/', text) is not None
     return re.search(rf'"jit\([^"]*[/(]{name}[/)]', text) is not None

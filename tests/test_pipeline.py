@@ -195,6 +195,243 @@ def test_pct_nodes_start_carries_across_launches():
     assert int(out2.pct_start) != start1
 
 
-# suite-tier discipline (tests/test_markers.py): area marker
+# ---- the scan's length follows the batch (PR 35) ----
+#
+# The commit scan runs whole blocks of scan_unroll() steps up to the last
+# row that carries a pod and stops. A step on a padding row changes no
+# carry, so the same pods must land the same way whatever the width of the
+# program they ride in, and BatchResult.scan_steps must read what
+# scan_steps_for() computes on the host.
+
 import pytest  # noqa: E402
+
+from kubernetes_tpu.api.objects import (  # noqa: E402
+    LABEL_HOSTNAME,
+    LABEL_ZONE,
+    Affinity,
+    ContainerPort,
+    LabelSelector,
+    PodAffinityTerm,
+    PodAntiAffinity,
+    TopologySpreadConstraint,
+)
+from kubernetes_tpu.backend.mirror import Mirror  # noqa: E402
+from kubernetes_tpu.backend.snapshot import Snapshot  # noqa: E402
+from kubernetes_tpu.models.pipeline import (  # noqa: E402
+    extract_state_jit,
+    launch_batch,
+    scan_steps_for,
+    scan_unroll,
+)
+from kubernetes_tpu.ops.features import PodBlobs  # noqa: E402
+
+WIDTHS = (16, 64, 1024)
+SCAN_CAPS = Capacities(nodes=16, pods=64, domains=16)
+PCT_CAPS = Capacities(nodes=256, pods=64)
+
+
+def _same_pod(i):
+    """Pods of one Deployment: they differ in name and uid alone, so a
+    launch of any size has the same topology groups (and one program a
+    width serves every size)."""
+    p = make_pod(i)
+    p.metadata.uid = p.metadata.name
+    p.metadata.labels = {"app": "s"}
+    p.spec.containers[0].image = "img-0"
+    return p
+
+
+def _green(i, ns="sched-1"):
+    """The anti-affinity cell's pod: green, and no green pod of either
+    namespace on its node (benchmark/templates/pod-with-pod-anti-affinity)."""
+    p = _same_pod(i)
+    p.metadata.name = p.metadata.uid = f"green-{ns}-{i}"
+    p.metadata.namespace = ns
+    p.metadata.labels = {"color": "green"}
+    p.spec.affinity = Affinity(pod_anti_affinity=PodAntiAffinity(required=[
+        PodAffinityTerm(topology_key=LABEL_HOSTNAME,
+                        namespaces=["sched-1", "sched-0"],
+                        label_selector=LabelSelector(
+                            match_labels={"color": "green"}))]))
+    return p
+
+
+def _anti_affinity_case(n):
+    """16 nodes, six of them already hold a green pod: ten are left, so of
+    13 pods three find every node forbidden, by the table or by an earlier
+    pod of their own launch."""
+    cache, snap, mirror = build_cluster(16, caps=SCAN_CAPS)
+    for i in range(6):
+        init = _green(i, ns="sched-0")
+        init.spec.node_name = f"node-{2 * i}"
+        cache.add_pod(init)
+    cache.update_snapshot(snap)
+    mirror.sync(snap)
+    return mirror, [_green(i) for i in range(n)], SCAN_CAPS, {}
+
+
+def _zone_spread_case(n):
+    """12 nodes in 3 zones, maxSkew 1 over the zone, and the third zone's
+    nodes are full: its count stays 0, so the first two pods take a zone
+    each and every later one is held off by the commits before it."""
+    cache, snap, mirror = build_cluster(12, caps=SCAN_CAPS, zones=3)
+    for i in range(2, 12, 3):
+        full = make_pod(100 + i, cpu="32", mem="1Gi")
+        full.metadata.uid = full.metadata.name
+        full.spec.node_name = f"node-{i}"
+        cache.add_pod(full)
+    cache.update_snapshot(snap)
+    mirror.sync(snap)
+    pods = []
+    for i in range(n):
+        p = _same_pod(i)
+        p.spec.topology_spread_constraints = [TopologySpreadConstraint(
+            max_skew=1, topology_key=LABEL_ZONE,
+            when_unsatisfiable="DoNotSchedule",
+            label_selector=LabelSelector(match_labels={"app": "s"}))]
+        pods.append(p)
+    return mirror, pods, SCAN_CAPS, {}
+
+
+def _host_ports_case(n):
+    """No topology, three host ports over four nodes: the serial scan is
+    what keeps two pods of one port apart, and the 13th pod has no node."""
+    _, _, mirror = build_cluster(4, caps=SCAN_CAPS)
+    pods = []
+    for i in range(n):
+        p = _same_pod(i)
+        p.spec.containers[0].ports = [ContainerPort(host_port=8080 + i % 3)]
+        pods.append(p)
+    return mirror, pods, SCAN_CAPS, {}
+
+
+def _pct_nodes_case(n):
+    """percentageOfNodesToScore 50 over 200 nodes: the rotating start is a
+    carry of its own, and a padding step must leave it where it was."""
+    _, _, mirror = build_cluster(200, caps=PCT_CAPS)
+    return mirror, [_same_pod(i) for i in range(n)], PCT_CAPS, \
+        {"pct_nodes": 50}
+
+
+# the case's builder, and how many pods find a node there
+SCAN_CASES = {"anti_affinity_hostname": (_anti_affinity_case, 10),
+              "zone_spread": (_zone_spread_case, 2),
+              "host_ports": (_host_ports_case, 12),
+              "pct_nodes": (_pct_nodes_case, 13)}
+
+
+def _launch(mirror, pods, width, caps, **kw):
+    return launch_batch(mirror.prepare_launch(pods, width),
+                        mirror.well_known(), default_weights(), caps, **kw)
+
+
+@pytest.mark.parametrize("n", [1, scan_unroll() - 1, scan_unroll(),
+                               scan_unroll() + 1, 13])
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_placements_do_not_depend_on_the_launch_width(case, n):
+    build, room = SCAN_CASES[case]
+    mirror, pods, caps, kw = build(n)
+    outs = [_launch(mirror, pods, w, caps, **kw) for w in WIDTHS]
+    u = scan_unroll()
+    for w, out in zip(WIDTHS, outs):
+        assert int(out.scan_steps) == min(-(-n // u) * u, w) \
+            == scan_steps_for(n, w), (w, int(out.scan_steps))
+        assert (np.asarray(out.node_row)[n:] == -1).all()
+    ref = outs[0]
+    rows = np.asarray(ref.node_row)[:n]
+    placed = rows[rows >= 0]
+    assert len(placed) == min(n, room), rows
+    if case == "anti_affinity_hostname":
+        assert len(set(placed.tolist())) == len(placed)
+        assert not set(placed.tolist()) & {
+            mirror.row_of(f"node-{2 * i}") for i in range(6)}
+    for out in outs[1:]:
+        for field in ("node_row", "score", "feasible_count",
+                      "reject_counts", "unresolvable_count"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(ref, field))[:n],
+                np.asarray(getattr(out, field))[:n], err_msg=field)
+        for field in ("free", "nzr", "pct_start", "guard"):
+            np.testing.assert_array_equal(np.asarray(getattr(ref, field)),
+                                          np.asarray(getattr(out, field)),
+                                          err_msg=field)
+
+
+def test_scan_steps_of_a_full_batch_is_its_width():
+    mirror, pods, caps, _ = _anti_affinity_case(16)
+    out = _launch(mirror, pods, 16, caps)
+    assert int(out.scan_steps) == 16 == scan_steps_for(16, 16)
+    rows = np.asarray(out.node_row)
+    assert (rows[:10] >= 0).all() and (rows[10:] == -1).all()
+
+
+def test_an_empty_batch_runs_no_scan_step():
+    """No row carries a pod (the mirror refuses to pack such a batch; a
+    direct caller can hand one in): the loop's trip count is 0 and every
+    row reads what a padding step writes."""
+    _, _, mirror = build_cluster(3, caps=CAPS)
+    pb = mirror.pack_batch_blobs([make_pod(0)], 8)
+    pb = PodBlobs(f32=pb.f32.at[:].set(0), i32=pb.i32.at[:].set(0))
+    cb = mirror.to_blobs()
+    out = schedule_batch_jit(cb, pb, mirror.well_known(), default_weights(),
+                             CAPS)
+    assert int(out.scan_steps) == 0 == scan_steps_for(0, 8)
+    assert (np.asarray(out.node_row) == -1).all()
+    # the four plugins the scan attributes to: no step ran, none counted
+    assert not np.asarray(out.reject_counts)[
+        :, FILTER_PLUGINS.index("NodePorts"):].any()
+    assert not np.asarray(out.feasible_count).any()
+    free0, nzr0 = extract_state_jit(cb, CAPS)
+    np.testing.assert_array_equal(np.asarray(out.free), np.asarray(free0))
+    np.testing.assert_array_equal(np.asarray(out.nzr), np.asarray(nzr0))
+
+
+def test_an_auction_launch_runs_no_scan_step():
+    _, _, mirror = build_cluster(4, caps=SCAN_CAPS)
+    out = _launch(mirror, [make_pod(i) for i in range(5)], 16, SCAN_CAPS,
+                  serial_scan=False)
+    assert int(out.scan_steps) == 0
+    assert (np.asarray(out.node_row)[:5] >= 0).all()
+
+
+def test_a_hole_in_the_valid_rows_is_stepped_over_not_cut_off():
+    """Rows 0 and 5 carry a pod, 1 to 4 are padding: the scan runs to the
+    last row that carries one (two blocks at unroll 4) and places both."""
+    _, _, mirror = build_cluster(3, caps=CAPS)
+    pods = [make_pod(i, cpu="20", mem="100Gi") for i in range(6)]
+    pb = mirror.pack_batch_blobs(pods, 16)
+    pb = PodBlobs(f32=pb.f32.at[1:5].set(0), i32=pb.i32.at[1:5].set(0))
+    out = schedule_batch_jit(mirror.to_blobs(), pb, mirror.well_known(),
+                             default_weights(), CAPS)
+    rows = np.asarray(out.node_row)
+    assert rows[0] >= 0 and rows[5] >= 0 and rows[0] != rows[5]
+    assert (np.delete(rows, [0, 5]) == -1).all()
+    assert int(out.scan_steps) == scan_steps_for(6, 16)
+
+
+@pytest.mark.parametrize("width, n, steps", [
+    (6, 6, 6),      # a full batch of one whole block and a part of one
+    (6, 3, 4),      # one block
+    (6, 5, 6),      # the second block ends with the batch, not past it
+    (2, 2, 2),      # a batch shorter than a block
+    (2, 1, 2),
+])
+def test_a_width_that_is_no_multiple_of_the_unroll(width, n, steps):
+    """Direct callers only: the host's buckets are powers of two. One big
+    pod a node, so every pod's node shows that it saw the commits before."""
+    assert scan_unroll() == 4
+    _, _, mirror = build_cluster(5, caps=CAPS)
+    pods = [make_pod(i, cpu="20", mem="100Gi") for i in range(n)]
+    out = _run(mirror, pods, batch=width)
+    rows = np.asarray(out.node_row)
+    assert rows.shape == (width,)
+    assert len(set(rows[:min(n, 5)].tolist())) == min(n, 5)
+    assert (rows[:min(n, 5)] >= 0).all() and (rows[5:] == -1).all()
+    assert (rows[n:] == -1).all()
+    assert int(out.scan_steps) == steps == scan_steps_for(n, width)
+    wide = np.asarray(_run(mirror, pods, batch=16).node_row)
+    np.testing.assert_array_equal(rows[:n], wide[:n])
+
+
+# suite-tier discipline (tests/test_markers.py): area marker
 pytestmark = pytest.mark.core

@@ -594,6 +594,56 @@ def test_device_profiler_keeps_pods_and_fill_per_shape(d_cap, serial, soft,
     assert f'scheduler_device_launch_fill{{shape="{mine}"}}' in text
 
 
+@pytest.mark.parametrize("b, carried, skipped", [
+    # the anti-affinity cell: 10 or 15 pods a 1,024-row launch, one block
+    # of 16 steps each; a backlog's full launches; a half-full drain tail
+    (1024, (13, 10, 15, 5), 1 - 64 / 4096),
+    (1024, (1024, 1024, 1011), 1 - (1024 + 1024 + 1024) / 3072),
+    (64, (33, 64), 1 - (48 + 64) / 128),
+])
+def test_device_profiler_keeps_scan_steps_per_serial_shape(b, carried,
+                                                           skipped,
+                                                           monkeypatch):
+    """A serial launch's scan runs whole blocks up to its last pod; the
+    shape keeps the steps, the snapshot the share of the rows launched
+    that the scan skipped, the counter both sides of it."""
+    from kubernetes_tpu.metrics import SchedulerMetrics
+    from kubernetes_tpu.models import pipeline
+    from kubernetes_tpu.ops.features import Capacities
+
+    monkeypatch.setattr(pipeline, "_SCAN_UNROLL", 16)   # the chip's
+    caps = Capacities(nodes=8192, pods=131072)
+    metrics = SchedulerMetrics()
+    prof = DeviceProfiler(metrics=metrics, cache_size_fn=lambda: 0,
+                          now=lambda: 0.0)
+    scan = shape_key(caps, b, True, 8192, 2, True, False, False, False)
+    auction = shape_key(caps, b, False, 0, 0, False, False, False, False)
+    steps = [pipeline.scan_steps_for(n, b) for n in carried]
+    assert all(s % 16 == 0 and n <= s < n + 16
+               for n, s in zip(carried, steps))
+    for n, s in zip(carried, steps):
+        prof.note_launch(scan, n, s)
+    prof.note_launch(auction, 7)        # no scan: no steps, none skipped
+    rec = prof.shapes[scan]
+    assert (rec["launches"], rec["steps"]) == (len(carried), sum(steps))
+    snap = {s["shape"]: s for s in prof.snapshot()["shapes"]}
+    mine = snap[f"b={b} nodes=8192 pods=131072 topo=1 d_cap=8192 "
+                "serial=1 soft=0 dra=0"]
+    assert mine["steps"] == sum(steps)
+    assert mine["steps_skipped"] == round(skipped, 4)
+    other = snap[f"b={b} nodes=8192 pods=131072 topo=0 d_cap=0 "
+                 "serial=0 soft=0 dra=0"]
+    assert (other["steps"], other["steps_skipped"]) == (0, 0.0)
+    run = metrics.device_scan_steps.value(result="run")
+    gone = metrics.device_scan_steps.value(result="skipped")
+    assert (run, gone) == (sum(steps), len(carried) * b - sum(steps))
+    assert gone / (run + gone) == pytest.approx(skipped)
+    text = metrics.registry.render_text()
+    assert 'scheduler_device_scan_steps_total{result="run"}' in text
+    assert 'scheduler_device_scan_steps_total{result="skipped"}' in text
+    parse_exposition(text)
+
+
 def test_device_profiler_on_live_scheduler_rebucket():
     """Every recompile in a churn-with-growth run attributes to a
     bucket-shape transition (the MixedChurn acceptance criterion in
@@ -633,6 +683,48 @@ def test_device_profiler_on_live_scheduler_rebucket():
         # the device_compile view phase recorded for compiling cycles
         phases = [tr.phases for tr in sched.flight.ring]
         assert any("device_compile" in p for p in phases)
+    finally:
+        sched.close()
+        hub.close()
+
+
+def test_live_scheduler_counts_the_scan_steps_its_launches_ran():
+    """Five pods with a hostname anti-affinity term ride one 16-row launch
+    of the serial scan: the profiler's host arithmetic reads two blocks of
+    four steps (the CPU's unroll), the rest of the bucket skipped, and
+    /debug/trace's device block and the exported counter say the same."""
+    from kubernetes_tpu.config.types import default_config
+    from kubernetes_tpu.models.pipeline import scan_steps_for
+    from kubernetes_tpu.ops.features import Capacities
+    from kubernetes_tpu.scheduler import Scheduler
+
+    hub = Hub()
+    for i in range(8):
+        hub.create_node(MakeNode().name(f"sn-{i}").capacity(cpu="64").obj())
+    cfg = default_config()
+    cfg.batch_size = 16
+    sched = Scheduler(hub, cfg, caps=Capacities(nodes=8, pods=16))
+    try:
+        for i in range(5):
+            hub.create_pod(MakePod().name(f"s{i}").label("color", "green")
+                           .req(cpu="50m").pod_anti_affinity(
+                               "kubernetes.io/hostname",
+                               {"color": "green"}).obj())
+        sched.run_until_idle()
+        nodes = {p.spec.node_name for p in hub.list_pods()}
+        assert len(nodes) == 5 and "" not in nodes
+        shapes = sched.profiler.snapshot()["shapes"]
+        scans = [s for s in shapes if s["steps"]]
+        assert scans and all("serial=1" in s["shape"] for s in scans)
+        assert sum(s["pods"] for s in scans) == 5
+        assert scan_steps_for(5, 16) == 8
+        one = [s for s in scans if s["launches"] == 1 and s["pods"] == 5]
+        assert one and one[0]["steps"] == 8
+        assert one[0]["steps_skipped"] == 0.5
+        run = sched.metrics.device_scan_steps.value(result="run")
+        gone = sched.metrics.device_scan_steps.value(result="skipped")
+        assert run == sum(s["steps"] for s in scans)
+        assert run + gone == sum(s["launches"] for s in scans) * 16
     finally:
         sched.close()
         hub.close()
