@@ -1073,19 +1073,21 @@ class Scheduler:
             self.metrics.condition_patches_dropped.inc(reason="fenced")
 
     def _flush_evictions_safe(self) -> None:
-        # only a flush with queued work is a measurable phase (this runs
-        # every cycle; an empty flush is a couple of attribute reads)
-        busy = self.preemption.has_pending()
-        sp = self.flight.span("eviction_flush") if busy else None
+        # only a flush with queued work is a phase (this runs every
+        # cycle): with none it is these two attribute reads, not an
+        # empty coalescing window around an empty flush (20 us a call,
+        # and loop time no span held)
+        if not self.preemption.has_pending():
+            return
+        sp = self.flight.span("eviction_flush")
         try:
-            if busy:
-                # evictions fire only over durably-bound state: a victim
-                # whose own bind still rides the binder backlog would be
-                # deleted BEFORE its bind lands, losing the pod (the
-                # bind-after-delete fails and the deleted pod can't
-                # requeue). The strict path orders wait-drain before
-                # flush for the same reason (schedule_one_batch).
-                self._drain_bind_results(wait=True)
+            # evictions fire only over durably-bound state: a victim
+            # whose own bind still rides the binder backlog would be
+            # deleted BEFORE its bind lands, losing the pod (the
+            # bind-after-delete fails and the deleted pod can't
+            # requeue). The strict path orders wait-drain before
+            # flush for the same reason (schedule_one_batch).
+            self._drain_bind_results(wait=True)
             # the queue's coalescing window batches the wave's delete
             # events into ONE requeue pass (in-process hubs dispatch
             # them inline on this thread); the whole wave — deletes AND
@@ -1096,8 +1098,7 @@ class Scheduler:
         except Unavailable:
             self._note_hub_down()
         finally:
-            if sp is not None:
-                sp.end()
+            sp.end()
 
     # ------------- fault containment (the self-healing ladder) -------------
     #
@@ -1557,14 +1558,14 @@ class Scheduler:
             sp.end(report=False)
             return len(batch), runnable, None
         tr = self.flight.begin(sp.t0, len(runnable))
-        sp.end(tr=tr)
         if self.flight.enabled:
-            # the span's closing clock read stamps the whole batch's
-            # pop events
-            tl = self.timelines
+            # one clock read stamps the whole batch's pop events; the
+            # stamping is the pop's own work, inside its span
+            tl, t_pop = self.timelines, self.now()
             for qp in runnable:
                 tl.event(qp.pod, "popped", f"attempt {qp.attempts}",
-                         t=sp.t1)
+                         t=t_pop)
+        sp.end(tr=tr)
         return len(batch), runnable, tr
 
     def _chain_eligible(self, pods: list[Pod]) -> bool:
@@ -1880,8 +1881,6 @@ class Scheduler:
                 # trigger an XLA compile mid-drain
                 self._patch_warmed = True
                 warm_patch_chain(out.free, out.nzr, CHAIN_PATCH_MAX)
-        disp.end()
-        t_done = disp.t1
         # device-launch profiler: the jit call above traced (and, on a
         # new bucket shape, COMPILED) synchronously before dispatching,
         # so reading the executable-cache size here attributes any
@@ -1922,6 +1921,14 @@ class Scheduler:
                  want_feats, want_alts)
         fut = (self._commit_pool.submit(self._pull_launch, out, flags, tr)
                if self._commit_pool is not None else None)
+        # the span closes on the hand-over: the profiler's note and the
+        # submit are the dispatch's own work (a commit_pull that began
+        # meanwhile is the commit thread's, an overlap phase), and so is
+        # letting go of the launch's inputs, which a frame that frees
+        # them on return does in nobody's phase (0.07 ms)
+        del spec, state
+        disp.end()
+        t_done = disp.t1
         return (runnable, out, t_done, t_done - t_cycle0, tr,
                 flags, pshape, compiled, fut)
 
@@ -2709,51 +2716,52 @@ class Scheduler:
                 c = int(dra_rej[i])
                 if c:
                     runnable[i].host_reject_counts["DynamicResources"] = c
-        with span("commit", tr) as done:
+        n_fail = len(fail_is)
+        last = span("commit", tr)
+        try:
             for qp, row in zip(runnable, rows):
                 if row >= 0:
                     self._commit(qp, self.mirror.name_of_row(row))
-        n_fail = len(fail_is)
-        if fail_is:
-            with span("failure_handling", tr) as done:
+            if fail_is:
+                last.end()
+                last = span("failure_handling", tr)
                 self._handle_failures([(runnable[i], rejects[i].tolist())
                                        for i in fail_is])
-        commit_s = done.t1 - t1
-        cycle_s = pack_s + launch_s + commit_s
-        if self.profiler is not None and pshape is not None:
-            self.profiler.observe_walltime(pshape, launch_s)
-            if compiled:
-                # attribution view: this cycle's launch walltime was
-                # (mostly) an XLA compile — the stall MixedChurn's
-                # re-bucketing pays, now visible per phase
-                tr.add("device_compile", launch_s)
-        tr.scheduled = n - n_fail
-        tr.failed = n_fail
+            # the cycle's own accounts ride its last span (what runs
+            # after a cycle's last span is nobody's phase): all but the
+            # record itself, which flushes the spans and so follows them
+            commit_s = self.now() - t1
+            cycle_s = pack_s + launch_s + commit_s
+            if self.profiler is not None and pshape is not None:
+                self.profiler.observe_walltime(pshape, launch_s)
+                if compiled:
+                    # attribution view: this cycle's launch walltime was
+                    # (mostly) an XLA compile — the stall MixedChurn's
+                    # re-bucketing pays, now visible per phase
+                    tr.add("device_compile", launch_s)
+            tr.scheduled = n - n_fail
+            tr.failed = n_fail
+            m = self.metrics
+            m.algorithm_duration.observe(launch_s)
+            m.batch_duration.observe(cycle_s)
+            m.extension_point_duration.observe(
+                pack_s, extension_point="PreFilter")
+            m.extension_point_duration.observe(
+                launch_s, extension_point="Filter")
+            m.extension_point_duration.observe(
+                commit_s, extension_point="Reserve")
+            per_pod = cycle_s / max(n, 1)
+            if n - n_fail:
+                m.attempt_duration.observe(per_pod, n=n - n_fail,
+                                           result="scheduled")
+            if n_fail:
+                m.attempt_duration.observe(per_pod, n=n_fail,
+                                           result="unschedulable")
+        finally:
+            last.end()
         self.flight.record(tr)
-        m = self.metrics
-        m.algorithm_duration.observe(launch_s)
-        m.batch_duration.observe(cycle_s)
-        m.extension_point_duration.observe(pack_s, extension_point="PreFilter")
-        m.extension_point_duration.observe(launch_s, extension_point="Filter")
-        m.extension_point_duration.observe(commit_s, extension_point="Reserve")
-        per_pod = cycle_s / max(n, 1)
-        if n - n_fail:
-            m.attempt_duration.observe(per_pod, n=n - n_fail,
-                                       result="scheduled")
-        if n_fail:
-            m.attempt_duration.observe(per_pod, n=n_fail,
-                                       result="unschedulable")
-        if cycle_s > SLOW_CYCLE_SECONDS:
-            # schedule_one.go:404's slow-attempt trace, batch-shaped
-            from kubernetes_tpu.utils.tracing import Trace
-
-            tr = Trace("schedule_cycle", pods=n,
-                       scheduled=sum(1 for r in rows if r >= 0))
-            tr.start -= cycle_s     # reconstruct from measured phases
-            tr.steps = [("pack+host_plugins", 0.0, pack_s, 0),
-                        ("device_launch", pack_s, launch_s, 0),
-                        ("commit+bind", pack_s + launch_s, commit_s, 0)]
-            tr.log_if_long(SLOW_CYCLE_SECONDS, logger)
+        tr.log_if_slow(cycle_s, SLOW_CYCLE_SECONDS, logger,
+                       pods=n, scheduled=n - n_fail)
 
     def schedule_one_batch(self) -> int:
         """Pop up to batch_size pods, run one device launch, commit results.
@@ -3024,32 +3032,40 @@ class Scheduler:
             self._bind_backlog.append((qp, state, assumed, node_name,
                                        fargs))
 
-    def _submit_bind_backlog(self) -> None:
+    def _submit_bind_backlog(self) -> bool:
+        """Chunk the bind backlog across the binder pool; whether there
+        was any."""
         backlog, self._bind_backlog = self._bind_backlog, []
         if not backlog:
-            return
+            return False
         workers = max(1, self.config.binding_workers)
         chunk = max(1, -(-len(backlog) // workers))
 
         def run_chunk(items):
-            return [self._bind_task(state, qp.pod, node_name, fargs)
-                    for qp, state, assumed, node_name, fargs in items]
+            # on a binder worker, beside the loop: an overlap phase, as
+            # commit_pull is on the commit thread
+            with self.flight.span("bind_chunk"):
+                return [self._bind_task(state, qp.pod, node_name, fargs)
+                        for qp, state, assumed, node_name, fargs in items]
 
         for i in range(0, len(backlog), chunk):
             items = backlog[i:i + chunk]
             self._inflight_binds.append(
                 (items, self._binder.submit(run_chunk, items)))
+        return True
 
     def _drain_bind_results(self, wait: bool = False) -> None:
         """Collect finished binding cycles (all of them when ``wait``);
         the binder thread's own hub events replay here, on the loop
         thread, right after each completion."""
-        self._submit_bind_backlog()
-        if not self._inflight_binds:
+        if not self._bind_backlog and not self._inflight_binds:
             return
         queue = self.queue
         scan_s0 = queue.trim_scan_s
         sp = self.flight.span("binder_drain")
+        # handing the last launch's binds to the pool is this phase's
+        # work too, and a phase even where no chunk has finished yet
+        submitted = self._submit_bind_backlog()
         drained = False
         still: list[tuple] = []
         for item in self._inflight_binds:
@@ -3074,7 +3090,7 @@ class Scheduler:
                 float(queue.event_log_len()))
             self._mirror_count("queue_trims", queue.trim_scans,
                                self.metrics.queue_event_trims)
-        sp.end(report=drained)
+        sp.end(report=drained or submitted)
 
     def _finish_binding(self, qp: QueuedPodInfo, state: CycleState,
                         assumed: Pod, node_name: str, s) -> None:

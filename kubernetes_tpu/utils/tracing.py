@@ -1,18 +1,10 @@
-"""Lightweight scheduling traces + the always-on flight recorder.
+"""The always-on flight recorder and the per-pod timelines.
 
-Two generations of tracing live here:
-
-- ``Trace``: the slice of the reference's tracing the scheduler used
-  first (utiltrace in schedule_one.go:404 + the component-base/tracing
-  spans) — nested timed steps collected per operation, logged ONLY when
-  the whole operation exceeds its threshold. Still used for the
-  slow-cycle log line.
-
-- ``CycleTrace`` / ``FlightRecorder``: the always-on successor. EVERY
-  scheduling cycle records its fine-grained phases (queue pop, snapshot
-  sync, host plugins, DRA allocator, pack, device launch, D2H pull,
-  commit, failure handling, binder drain, eviction flush, host
-  fallback) into a bounded ring buffer, and each phase feeds a
+- ``CycleTrace`` / ``FlightRecorder``: EVERY scheduling cycle records
+  its fine-grained phases (queue pop, snapshot sync, host plugins, DRA
+  allocator, pack, device launch, D2H pull, commit, failure handling,
+  binder drain, eviction flush, host fallback) into a bounded ring
+  buffer, and each phase feeds a
   per-phase histogram in the metrics Registry — the continuous
   per-stage latency attribution Kant (arxiv 2510.01256) argues
   large-cluster schedulers need, instead of sampling-on-slow. ONE
@@ -20,10 +12,28 @@ Two generations of tracing live here:
   instants (name, start, end, thread, cycle or loop turn) and delivers
   ``(phase, secs)`` through ``CycleTrace.add`` / ``observe_phase``
   exactly once, at the instant the span ends, on the thread it ran on.
-  The recorder's overhead budget is <2% of p50 cycle time (not yet
-  measured on the chip's host, ROADMAP S7): a span is two clock reads,
-  one dict write and one list append; while a JAX profiler trace is
-  being taken it is also a ``jax.profiler.TraceAnnotation`` of the same name.
+  Beside the wall clock a span takes a reading of its thread's CPU
+  clock (``time.thread_time``) at each end: the CPU seconds between
+  the two are the part of the interval its thread spent ON the
+  interpreter, the rest it stood off it (waiting for the GIL, a lock,
+  the device, a sleep). At a span's start a reading at most
+  ``CPU_REUSE_S`` old on the wall clock stands for now, so where spans
+  follow each other the end of one and the start of the next share one
+  read of the clock (``FlightRecorder._cpu_at_start``). The CPU seconds land
+  in the same histogram under the phase label ``"<phase>.cpu"``, so a
+  phase's waiting share is ``1 - sum{phase="x.cpu"} / sum{phase="x"}``.
+  ONE rule derives them from the readings,
+  ``FlightRecorder._settle_cpu``: a span delivers at most its own wall
+  seconds, and what its readings gave beyond them (a tick of a coarse
+  CPU clock, the shared reading's age) is carried to the phase's next
+  spans.
+  The recorder's overhead budget is <2% of p50 cycle time (PERF.md §6,
+  PR 36, has the chip reading): a span is two wall reads, at most two
+  CPU reads and as a rule one, two dict writes and one list append;
+  while a JAX profiler trace is being taken it is also a
+  ``jax.profiler.TraceAnnotation`` of the same name.
+  A cycle over the slow threshold is logged from its own ``CycleTrace``
+  (``log_if_slow``: every phase with its CPU beside it).
 
 - ``PodTimelines``: per-pod lifecycle stamps (enqueue, pop/attempt,
   assume, bind, parks) plus the last unschedulable diagnosis (which
@@ -39,7 +49,6 @@ import logging
 import os
 import threading
 import time
-from contextlib import contextmanager
 from typing import Callable, Optional
 
 logger = logging.getLogger("kubernetes_tpu.trace")
@@ -56,12 +65,15 @@ CYCLE_PHASES = (
                           # for a whole-chain invalidate + snapshot_sync)
     "host_plugins",       # host PreFilter/Filter/Score + extenders
     "pack",               # mirror.prepare_launch (pod-side H2D)
-    "device_dispatch",    # async launch_batch dispatch
+    "device_dispatch",    # async launch_batch dispatch, the profiler's
+                          # note of the launch and the hand-over of the
+                          # pull to the commit thread
     "device_launch",      # dispatch -> results pulled (device + queue wait)
     "d2h_pull",           # device_get of rows/guard/reject_counts
     "commit",             # assume/reserve/permit per winner
     "failure_handling",   # diagnoses, PostFilter/preemption, parks
-    "binder_drain",       # collecting finished binding cycles
+    "binder_drain",       # handing a launch's binds to the binder pool
+                          # and collecting finished binding cycles
     "eviction_flush",     # queued preemption evictions
     "host_fallback",      # serial host path after a device fault
     "dra_mask_compile",   # CEL -> bitmask compile + inventory refresh (view)
@@ -141,12 +153,25 @@ DRA_VIEW_PHASES = ("dra_mask_compile", "dra_device_eval", "dra_commit")
 VIEW_PHASES = DRA_VIEW_PHASES + (
     "device_compile", "snapshot_cache", "mirror_sync") + LOOP_VIEW_PHASES
 
-# phases measured on the commit thread, CONCURRENT with loop-thread
-# work. Counting them in totals/host-tail would book overlapped wall
-# time as if serial (the pipelined arm's host-tail share over-reported
-# before these were split out). Like VIEW_PHASES they still render in
-# /debug/trace and phase_percentiles — they are attribution, not cost.
-OVERLAP_PHASES = ("commit_pull",)
+# phases measured on the commit thread or a binder worker, CONCURRENT
+# with loop-thread work. Counting them in totals/host-tail would book
+# overlapped wall time as if serial (the pipelined arm's host-tail share
+# over-reported before these were split out). Like VIEW_PHASES they
+# still render in /debug/trace and phase_percentiles — they are
+# attribution, not cost.
+OVERLAP_PHASES = (
+    "commit_pull",        # a cycle's span, on the commit thread
+    "bind_chunk",         # loop-level, on a binder worker: its run of one
+                          # chunk of a launch's binding cycles
+                          # (Scheduler._submit_bind_backlog; four a
+                          # launch, none a pod). With commit_pull it
+                          # accounts for the threads the program owns
+                          # beside the loop
+)
+
+# the label suffix of a phase's CPU seconds in the phase histogram
+# ("commit" -> "commit.cpu"): the same histogram, a series of its own
+CPU_SUFFIX = ".cpu"
 
 # everything excluded from the serial-cycle-time arithmetic
 EXCLUDED_PHASES = VIEW_PHASES + OVERLAP_PHASES
@@ -164,7 +189,28 @@ UNCOUNTED_PHASES = frozenset(EXCLUDED_PHASES + LOOP_PHASES)
 # v2 rows remain valid replay input (learn/replay.py reads >= 2).
 # v4 adds "spans": every phase span of the cycle as [name, start, end,
 # thread] on the recorder's clock (phases_ms stays: it is their sums).
-EXPORT_VERSION = 4
+# v5 gives each span a fifth element, the milliseconds between its two
+# readings of its thread's CPU clock as read (None where it has none),
+# and the cycle "cpu_ms" beside "phases_ms": what its spans DELIVERED to
+# the "<phase>.cpu" series (FlightRecorder._settle_cpu), which equals
+# the readings' sums only where no span read more than its length.
+# Additive.
+EXPORT_VERSION = 5
+
+# how old a reading of a thread's CPU clock may be and still stand for
+# that thread's now at a span's start (FlightRecorder._cpu_at_start), on
+# the wall clock (then the clock is not read) or by what the thread has
+# burnt since: spans that follow each other share the reading at their
+# boundary, one read a span where there were two, and glue shorter than
+# this counts to the later span. It bounds what the glue ahead of a span
+# can add to its CPU figure; the read it saves costs 6-12 us where the
+# thread's CPU clock is a system call (PERF.md §6, PR 36).
+CPU_REUSE_S = 50e-6
+
+# the most CPU seconds a phase may be owed (FlightRecorder._settle_cpu):
+# two ticks of the coarsest thread CPU clock met (10 ms). More than that
+# is no tick's remainder, and would hide as much real waiting later.
+CPU_CARRY_MAX_S = 0.02
 
 # phases that are host-side Python work (the "host tail" the ROADMAP's
 # sub-10x offenders ask us to attribute); device_launch is device +
@@ -177,61 +223,26 @@ HOST_PHASES = (
 )
 
 
-class Trace:
-    """utiltrace.Trace: nested spans via span(); log_if_long at end."""
-
-    def __init__(self, name: str, now: Callable[[], float] = time.monotonic,
-                 **fields):
-        self.name = name
-        self.fields = fields
-        self._now = now
-        self.start = now()
-        # (name, start offset, secs, depth)
-        self.steps: list[tuple[str, float, float, int]] = []
-        self._depth = 0
-
-    @contextmanager
-    def span(self, name: str):
-        self._depth += 1
-        t0 = self._now()
-        try:
-            yield self
-        finally:
-            self._depth -= 1
-            # (name, start offset, secs, depth): the dump sorts by start
-            # so parents print above their children
-            self.steps.append((name, t0 - self.start,
-                               self._now() - t0, self._depth))
-
-    def total(self) -> float:
-        return self._now() - self.start
-
-    def log_if_long(self, threshold: float,
-                    log: Optional[logging.Logger] = None) -> bool:
-        """Emit the trace when total exceeds ``threshold`` (the reference's
-        100ms slow-attempt log). Returns whether it logged."""
-        total = self.total()
-        if total < threshold:
-            return False
-        log = log or logger
-        fields = " ".join(f"{k}={v}" for k, v in self.fields.items())
-        lines = [f"Trace[{self.name}] {fields} total={total * 1e3:.0f}ms"]
-        for name, _start, secs, depth in sorted(self.steps,
-                                                key=lambda s: (s[1], s[3])):
-            lines.append(f"{'  ' * (depth + 1)}- {name}: {secs * 1e3:.0f}ms")
-        log.info("%s", "\n".join(lines))
-        return True
+def _read_ms(c0: Optional[float], c1: Optional[float]) -> Optional[float]:
+    """The milliseconds between a span's two CPU readings, as read; None
+    where it carries none."""
+    if c0 is None or c1 is None:
+        return None
+    return round((c1 - c0) * 1e3, 3)
 
 
 class CycleTrace:
     """One scheduling cycle's phase durations. ``add`` accumulates (a
     phase may be touched several times per cycle, e.g. the re-bucketing
     retry loop re-syncing); the recorder flushes the whole dict to the
-    phase histogram when the cycle is recorded."""
+    phase histogram when the cycle is recorded. ``cpu`` holds the CPU
+    seconds of the same phases' spans, each on its own thread, flushed
+    beside them under ``"<phase>.cpu"``; it is no part of ``phases``, so
+    no total counts it."""
 
     __slots__ = ("cycle", "start", "pods", "scheduled", "failed",
-                 "chained", "phases", "spans", "plugins", "placements",
-                 "depth")
+                 "chained", "phases", "cpu", "spans", "plugins",
+                 "placements", "depth")
 
     def __init__(self, cycle: int, start: float, pods: int,
                  chained: bool = False):
@@ -245,9 +256,11 @@ class CycleTrace:
         # (how many waves were in flight, the stall detector)
         self.depth = 0
         self.phases: dict[str, float] = {}
-        # (name, start, end, thread ident) of every span of this cycle,
-        # in the order they ended (FlightRecorder.span appends)
-        self.spans: list[tuple[str, float, float, int]] = []
+        self.cpu: dict[str, float] = {}
+        # (name, start, end, thread ident, the thread's CPU clock at the
+        # start and at the end) of every span of this cycle, in the
+        # order they ended (FlightRecorder.span appends)
+        self.spans: list[tuple] = []
         self.plugins: dict[str, float] = {}   # "plugin/point" -> secs
         # per-pod placement rows (export v2+): {"pod", "uid", "node",
         # "score"[, "feat"][, "alt"]} — node None for failed attempts,
@@ -278,8 +291,10 @@ class CycleTrace:
             "total_ms": round(self.total() * 1e3, 3),
             "phases_ms": {k: round(v * 1e3, 3)
                           for k, v in self.phases.items()},
-            "spans": [[n, round(a, 6), round(b, 6), names.get(t, t)]
-                      for n, a, b, t in self.spans],
+            "cpu_ms": {k: round(v * 1e3, 3) for k, v in self.cpu.items()},
+            "spans": [[n, round(a, 6), round(b, 6), names.get(t, t),
+                       _read_ms(c0, c1)]
+                      for n, a, b, t, c0, c1 in self.spans],
         }
         if self.plugins:
             d["plugins_ms"] = {k: round(v * 1e3, 3)
@@ -287,6 +302,25 @@ class CycleTrace:
         if self.placements is not None:
             d["placements"] = self.placements
         return d
+
+    def log_if_slow(self, total: float, threshold: float,
+                    log: logging.Logger, **fields) -> bool:
+        """The slow-cycle line (schedule_one.go:404's slow-attempt trace,
+        batch-shaped): silent unless ``total`` passes ``threshold``, then
+        every phase of the cycle with its CPU seconds beside it, which
+        says whether the slow cycle worked or waited. Returns whether it
+        logged."""
+        if total <= threshold:
+            return False
+        head = " ".join(f"{k}={v}" for k, v in fields.items())
+        lines = [f"Trace[schedule_cycle] {head} total={total * 1e3:.0f}ms"]
+        for phase, secs in self.phases.items():
+            cpu = self.cpu.get(phase)
+            lines.append(
+                f"  - {phase}: {secs * 1e3:.0f}ms"
+                + ("" if cpu is None else f" (cpu {cpu * 1e3:.0f}ms)"))
+        log.info("%s", "\n".join(lines))
+        return True
 
 
 class _NullTrace(CycleTrace):
@@ -311,9 +345,20 @@ class Span:
     very interval (one half of a phase timed in two pieces): it is
     reported with the same seconds just AHEAD of the phase, so a reader
     that rebuilds spans as (now - secs, now) and gives shared time to
-    the earlier one always sees the view."""
+    the earlier one always sees the view. Beside the wall clock an
+    enabled recorder's span takes a reading of its thread's CPU clock
+    at each end, just after the wall reading (``FlightRecorder._cpu_at_start``:
+    at its start the last span's closing reading where that is under
+    ``CPU_REUSE_S`` old, a read of the clock otherwise and at its end):
+    ``c0``/``c1`` are the absolute readings (the CPU a thread spent
+    BETWEEN two spans is the later ``c0`` less the earlier ``c1``),
+    ``cpu`` the seconds between them as read, None where the recorder is
+    off or the span ended on another thread than it began on (two
+    threads' CPU clocks share no origin). What the span delivers to its
+    phase's ``.cpu`` series is ``FlightRecorder._settle_cpu``'s to say."""
 
-    __slots__ = ("_fl", "name", "view", "_tr", "_ann", "t0", "t1")
+    __slots__ = ("_fl", "name", "view", "_tr", "_ann", "_tid",
+                 "t0", "t1", "c0", "c1")
 
     def __init__(self, fl: "FlightRecorder", name: str,
                  tr: Optional[CycleTrace], view: Optional[str] = None):
@@ -328,12 +373,21 @@ class Span:
             # device's operations on the profiler's own clock
             self._ann = ann(name if view is None else view)
             self._ann.__enter__()
-        self.t1 = None
-        self.t0 = fl._now()
+        self.t1 = self.c0 = self.c1 = self._tid = None
+        self.t0 = t0 = fl._now()
+        if fl.enabled:
+            self._tid = tid = threading.get_ident()
+            self.c0 = fl._cpu_at_start(tid, t0)
 
     @property
     def secs(self) -> float:
         return self.t1 - self.t0
+
+    @property
+    def cpu(self) -> Optional[float]:
+        if self.c1 is None:
+            return None
+        return self.c1 - self.c0
 
     def end(self, report: bool = True,
             tr: Optional[CycleTrace] = None) -> float:
@@ -343,7 +397,12 @@ class Span:
         pop that opens its cycle)."""
         if tr is not None:
             self._tr = tr
-        self.t1 = self._fl._now()
+        self.t1 = t1 = self._fl._now()
+        if report and self.c0 is not None \
+                and threading.get_ident() == self._tid:
+            # an unrecorded span leaves the clock unread: what it burnt
+            # counts to the span after it, like the glue between spans
+            self.c1 = self._fl._cpu_at_end(self._tid, t1)
         if report:
             self._fl._report(self)
         if self._ann is not None:
@@ -368,23 +427,39 @@ class FlightRecorder:
     given that one thread at a time writes one phase: a cycle's span
     lands on its CycleTrace (the commit thread's ``commit_pull`` is
     harvested before the loop records the cycle), a loop-level span in
-    its own series of the phase histogram. Readers (``/debug/trace``)
-    take cheap snapshots of the deques."""
+    its own series of the phase histogram, under a lock: the binder
+    workers write the one ``bind_chunk`` series at once. Readers
+    (``/debug/trace``) take cheap snapshots of the deques."""
 
     def __init__(self, phase_hist=None, plugin_hist=None,
                  capacity: int = 256, export_path: Optional[str] = None,
                  enabled: bool = True, export_max_bytes: int = 0,
                  now: Callable[[], float] = time.monotonic,
-                 gc_pause_hist=None):
+                 gc_pause_hist=None,
+                 cpu_now: Callable[[], float] = time.thread_time):
         self.enabled = enabled and capacity > 0
         self.phase_hist = phase_hist
         self.plugin_hist = plugin_hist
         self.gc_pause_hist = gc_pause_hist
         self._now = now
+        # the calling thread's CPU clock, read by the spans of an
+        # enabled recorder beside the wall clock (_cpu_at_start, _cpu_at_end)
+        self._cpu_now = cpu_now
+        # thread ident -> (wall instant, CPU reading) of that thread's
+        # newest read of its CPU clock; each thread writes its own entry
+        self._cpu_read: dict[int, tuple] = {}
+        self._loop_lock = threading.Lock()
+        # phase -> CPU seconds read but not yet delivered (_settle_cpu).
+        # ONE WRITER A PHASE AT A TIME, which is the callers' to keep: a
+        # cycle phase is written by the one thread that runs it (the
+        # loop's, or the commit thread's commit_pull), a loop-level one
+        # under _loop_lock (bind_chunk has four writers)
+        self._cpu_owed: dict[str, float] = {}
         self.ring: collections.deque = collections.deque(
             maxlen=max(1, capacity))
         # loop-level spans (LOOP_PHASES and their views): (name, start,
-        # end, thread ident, loop turn), bounded like the ring
+        # end, thread ident, loop turn, the thread's CPU clock at the
+        # start and at the end or None twice), bounded like the ring
         self.loop_spans: collections.deque = collections.deque(
             maxlen=max(1, capacity) * 16)
         self.turn = 0                # Scheduler.run's loop turn
@@ -424,9 +499,40 @@ class FlightRecorder:
         as a context manager or call ``end()``. With ``tr`` the span
         belongs to that cycle and is delivered through ``tr.add``;
         without, it is loop-level (stamped with the loop turn) and goes
-        through ``observe_phase``. A disabled recorder still times the
-        span (callers read ``secs``) and records nothing."""
+        through ``observe_phase``. Its CPU seconds go beside them, into
+        ``tr.cpu`` or straight into the histogram's ``"<phase>.cpu"``
+        series, through neither of those two methods: what they see is
+        wall time of phases, as before. A disabled recorder still times
+        the span (callers read ``secs``), reads no CPU clock and records
+        nothing."""
         return Span(self, name, tr, view)
+
+    def _cpu_at_start(self, tid: int, t: float) -> float:
+        """Thread ``tid``'s CPU clock for a span that starts at the wall
+        instant ``t``, on that thread: the reading its last span closed
+        with, where that is at most CPU_REUSE_S old on the wall clock
+        (no read) or the thread has burnt at most CPU_REUSE_S since (a
+        read, which a busy machine forces by stretching the gap, not the
+        work in it); else a new reading. So two spans that follow each
+        other share the read at their boundary, glue under CPU_REUSE_S
+        counts to the later span whatever the machine's load, and the
+        readings of a thread's spans tile its CPU time wherever its
+        spans tile its work."""
+        last = self._cpu_read.get(tid)
+        if last is None:
+            return self._cpu_at_end(tid, t)
+        if not 0.0 <= t - last[0] <= CPU_REUSE_S:
+            c = self._cpu_at_end(tid, t)
+            if not 0.0 <= c - last[1] <= CPU_REUSE_S:
+                return c
+        return last[1]
+
+    def _cpu_at_end(self, tid: int, t: float) -> float:
+        """A read of thread ``tid``'s CPU clock, on that thread, kept with
+        the wall instant ``t`` it belongs to for the span that follows."""
+        c = self._cpu_now()
+        self._cpu_read[tid] = (t, c)
+        return c
 
     def _report(self, sp: Span) -> None:
         if not self.enabled:
@@ -436,23 +542,53 @@ class FlightRecorder:
             self._thread_names[tid] = threading.current_thread().name
         tr = sp._tr
         secs = sp.t1 - sp.t0
+        read = None if sp.c1 is None else max(sp.c1 - sp.c0, 0.0)
         for name in ((sp.name,) if sp.view is None else (sp.view, sp.name)):
             if tr is None:
-                self.loop_spans.append((name, sp.t0, sp.t1, tid, self.turn))
-                self.observe_phase(name, secs)
+                self.loop_spans.append((name, sp.t0, sp.t1, tid, self.turn,
+                                        sp.c0, sp.c1))
+                with self._loop_lock:    # bind_chunk: four writers
+                    self.observe_phase(name, secs)
+                    if read is not None and self.phase_hist is not None:
+                        self.phase_hist.observe(
+                            self._settle_cpu(name, secs, read),
+                            phase=name + CPU_SUFFIX)
             elif tr is not _NULL_TRACE:
-                tr.spans.append((name, sp.t0, sp.t1, tid))
+                tr.spans.append((name, sp.t0, sp.t1, tid, sp.c0, sp.c1))
                 tr.add(name, secs)
+                if read is not None:
+                    tr.cpu[name] = tr.cpu.get(name, 0.0) \
+                        + self._settle_cpu(name, secs, read)
+
+    def _settle_cpu(self, name: str, secs: float, read: float) -> float:
+        """The CPU seconds a span DELIVERS to its phase's ``.cpu`` series,
+        the one figure derived from the readings: what its two readings
+        gave plus what earlier spans of the phase read beyond their own
+        length, held to its wall seconds; the rest is carried on, up to
+        CPU_CARRY_MAX_S. Where the thread's CPU clock is exact (a plain
+        Linux kernel) a span reads beyond its length only by the age of
+        a shared reading (CPU_REUSE_S). Where it advances a tick at a
+        time (10 ms on the benchmark's host), a span shorter than the
+        tick reads nothing or a whole tick: cutting the tick down to the
+        span would lose it and every short phase would read as waiting,
+        carrying it keeps the phase's sum unbiased, and still never
+        above its wall sum. One writer a phase (see ``_cpu_owed``)."""
+        owed = self._cpu_owed.get(name, 0.0) + read
+        cpu = min(owed, max(secs, 0.0))
+        self._cpu_owed[name] = min(owed - cpu, CPU_CARRY_MAX_S)
+        return cpu
 
     def observe_view(self, phase: str, secs: float) -> None:
         """A loop-level view whose seconds another component measured
         (LOOP_VIEW_PHASES), reported the instant it ended: kept as a
-        span ending now, delivered through ``observe_phase``."""
+        span ending now with no CPU readings, delivered through
+        ``observe_phase``."""
         if not self.enabled:
             return
         end = self._now()
         self.loop_spans.append((phase, end - secs, end,
-                                threading.get_ident(), self.turn))
+                                threading.get_ident(), self.turn,
+                                None, None))
         self.observe_phase(phase, secs)
 
     def gc_pause(self, secs: float, generation: int) -> None:
@@ -490,6 +626,8 @@ class FlightRecorder:
         if h is not None:
             for phase, secs in tr.phases.items():
                 h.observe(secs, phase=phase)
+            for phase, secs in tr.cpu.items():
+                h.observe(secs, phase=phase + CPU_SUFFIX)
         if self._export_file is not None:
             line = json.dumps(tr.to_dict(self._thread_names)) + "\n"
             if self._export_max_bytes \
@@ -568,17 +706,20 @@ class FlightRecorder:
 
     def last_loop_spans(self, n: int = 256) -> list[list]:
         """The newest loop-level spans as [name, start, end, thread,
-        turn] (``/debug/trace``'s ``loop_spans``)."""
+        turn, milliseconds between the two CPU readings or None]
+        (``/debug/trace``'s ``loop_spans``)."""
         if n <= 0:
             return []
         names = self._thread_names
-        return [[nm, round(a, 6), round(b, 6), names.get(t, t), turn]
-                for nm, a, b, t, turn in list(self.loop_spans)[-n:]]
+        return [[nm, round(a, 6), round(b, 6), names.get(t, t), turn,
+                 _read_ms(c0, c1)]
+                for nm, a, b, t, turn, c0, c1 in list(self.loop_spans)[-n:]]
 
     def phase_percentiles(self) -> dict:
         """{phase: {p50_ms, p90_ms, p99_ms, count, total_s}} from the
         phase histogram (bucket-resolution percentiles, like the rest of
-        the registry)."""
+        the registry); a phase's CPU seconds stand beside it as
+        ``"<phase>.cpu"``."""
         h = self.phase_hist
         if h is None:
             return {}
@@ -631,7 +772,7 @@ class FlightRecorder:
         host = total = 0.0
         for k in list(h._series):
             phase = dict(k).get("phase", "?")
-            if phase in UNCOUNTED_PHASES:
+            if phase in UNCOUNTED_PHASES or phase.endswith(CPU_SUFFIX):
                 continue
             s = h._series.get(k)
             if not s:

@@ -187,25 +187,23 @@ def test_pending_pods_gauge_live():
     assert gauge["{'queue': 'unschedulable'}"] == 1
 
 
-def test_trace_spans_and_threshold():
-    """utiltrace-style spans: silent under threshold, full dump over it."""
+def test_slow_cycle_line_is_silent_under_the_threshold_and_whole_over_it():
+    """CycleTrace.log_if_slow: nothing under the threshold; over it every
+    phase of the cycle, each with its CPU seconds beside it where a span
+    timed it, and the caller's fields in the head."""
     import logging
 
-    from kubernetes_tpu.utils.tracing import Trace
+    from kubernetes_tpu.utils.tracing import FlightRecorder
 
-    t = [0.0]
-
-    def now():
-        return t[0]
-
-    tr = Trace("cycle", now=now, pods=4)
-    with tr.span("launch"):
-        t[0] += 0.08
-        with tr.span("pull"):
-            t[0] += 0.01
-    with tr.span("commit"):
-        t[0] += 0.05
-    assert abs(tr.total() - 0.14) < 1e-9
+    wall, cpu = iter([0.0, 0.08, 1.0, 1.05, 2.0]), iter([0.0, 0.002,
+                                                          5.0, 5.04])
+    rec = FlightRecorder(now=lambda: next(wall), cpu_now=lambda: next(cpu))
+    tr = rec.begin(start=0.0, pods=4)
+    with rec.span("device_launch", tr):
+        pass
+    with rec.span("commit", tr):
+        pass
+    tr.add("device_compile", 0.07)       # a view: no span, no CPU
     records = []
 
     class Cap(logging.Handler):
@@ -215,11 +213,15 @@ def test_trace_spans_and_threshold():
     log = logging.getLogger("trace-test")
     log.addHandler(Cap())
     log.setLevel(logging.INFO)
-    assert tr.log_if_long(1.0, log) is False, "under threshold: silent"
-    assert not records
-    assert tr.log_if_long(0.1, log) is True
-    assert "Trace[cycle]" in records[0]
-    assert "launch" in records[0] and "pull" in records[0]
+    assert tr.log_if_slow(0.13, 1.0, log, pods=4) is False
+    assert tr.log_if_slow(0.1, 0.1, log, pods=4) is False
+    assert not records, "under the threshold: silent"
+    assert tr.log_if_slow(0.13, 0.1, log, pods=4, scheduled=3) is True
+    assert records[0].splitlines() == [
+        "Trace[schedule_cycle] pods=4 scheduled=3 total=130ms",
+        "  - device_launch: 80ms (cpu 2ms)",     # it waited
+        "  - commit: 50ms (cpu 40ms)",           # it worked
+        "  - device_compile: 70ms"]
 
 
 def test_slow_cycle_emits_trace(caplog):
@@ -247,7 +249,13 @@ def test_slow_cycle_emits_trace(caplog):
     hub.create_pod(mkpod("p"))
     with caplog.at_level(logging.INFO, logger="kubernetes_tpu.scheduler"):
         sched.run_until_idle()
-    assert any("Trace[schedule_cycle]" in r.message for r in caplog.records)
+    slow = [r.getMessage() for r in caplog.records
+            if "Trace[schedule_cycle]" in r.getMessage()]
+    assert slow and "pods=1 scheduled=1" in slow[0]
+    # the cycle's own phases, each with the CPU its span read
+    for phase in ("queue_pop", "pack", "device_launch", "commit"):
+        assert f"  - {phase}: " in slow[0], phase
+    assert "(cpu " in slow[0]
     sched.close()
 
 

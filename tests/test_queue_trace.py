@@ -214,11 +214,15 @@ def test_debug_trace_shows_spans_loop_spans_and_queue_counts():
         finally:
             srv.stop()
         cyc = tr["cycles"][-1]
-        assert cyc["v"] == 4
+        assert cyc["v"] == 5
         names = [s[0] for s in cyc["spans"]]
         assert names[0] == "queue_pop" and "commit" in names
-        for name, start, end, thread in cyc["spans"]:
+        for name, start, end, thread, cpu_ms in cyc["spans"]:
             assert end >= start and isinstance(thread, str)
+            # as read: over its length by a shared reading's age at most
+            assert 0.0 <= cpu_ms <= (end - start) * 1e3 + 0.1
+        assert set(cyc["cpu_ms"]) == set(names)
+        assert "commit.cpu" in tr["phases"]
         assert {"snapshot_cache", "snapshot_sync", "mirror_sync"} \
             <= set(names)
         assert {s[0] for s in tr["loop_spans"]} >= {
@@ -462,8 +466,9 @@ def test_forced_collection_is_one_gen2_pause(where):
         assert hist.count(generation="2") == 1
         assert hist.count(generation="0") == hist.count(generation="1") == 0
         assert phase.count(phase="gc_pause") == 1
-        (name, start, end, thread, _turn), = rec.loop_spans
+        (name, start, end, thread, _turn, c0, c1), = rec.loop_spans
         assert name == "gc_pause" and thread == ident and end >= start
+        assert c0 is None and c1 is None     # the guard's own seconds
     finally:
         guard.unwatch(rec)
     gc.collect(2)                        # unwatched: nothing more arrives
