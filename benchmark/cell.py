@@ -30,6 +30,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_SLICE_S = 3.0
+BREAKDOWN_NAMES = 10    # the contract's limit on each list of `breakdown`
 NOT_DEVICE = ".cpu_rehearsal_not_a_device_number"
 
 clock = time.perf_counter
@@ -175,6 +176,19 @@ class PhaseSpans:
 
             CycleTrace.add, FlightRecorder.observe_phase = self._orig
             self._orig = None
+
+
+def pod_table_slots(sched) -> tuple[int, int] | None:
+    """(slots in use, capacity) of the mirror's pod table as it stands:
+    `caps.pods` less the free slots; None where the program keeps no such
+    list. The scheduler's, not the one the run started with: `_grow`
+    replaces the mirror."""
+    mirror = getattr(sched, "mirror", None)
+    free = getattr(mirror, "_free_slots", None)
+    if free is None:
+        return None
+    capacity = int(mirror.caps.pods)
+    return capacity - len(free), capacity
 
 
 def phase_sums(sched) -> dict[str, float]:
@@ -386,6 +400,7 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
         phases1, launches1 = phase_sums(sched), sched.profiler.launches
         cache1 = launch_cache_size()
         bound_at_close = watcher.bound_count()
+        table_at_close = pod_table_slots(sched)
         # ---------------- grace drain, outside the window
         withdrawn: set[str] = set()
         if mix["kind"] == "backlog":
@@ -418,6 +433,7 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
 
         _wait(drained, float(mix["grace_seconds"]))
         t_end = clock()
+        table_at_end = pod_table_slots(sched)
         if tracer is not None:
             tracer.join(timeout=120)
         import jax
@@ -446,6 +462,12 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
                          if t0 <= t < t1],
         "trace": None,
     }
+    if table_at_close is not None and table_at_end is not None:
+        # at the window's close and after the grace drain, in which the
+        # pods kept at the close bind: a full table there is a _grow too
+        obs["pod_table"] = {
+            "capacity": table_at_end[1], "in_use_at_close": table_at_close[0],
+            "in_use_at_end": table_at_end[0]}
     values: dict[str, float] = {"setup_s": setup_s}
     if mix["kind"] == "backlog":
         values["pods_per_s"] = stats.rate_in_window(bind_times, t0, seconds)
@@ -485,8 +507,10 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
             obs["trace"] = red
             device["busy_s"] = red["busy_s"]
             device["window_s"] = red["window_s"]
-            breakdown = {"device_ops": red["device_ops"],
-                         "idle_gaps": red["idle_gaps"]}
+            # the contract's breakdown keeps ten names a list; the thirty
+            # the reduction keeps go into the diag line, for the builder
+            breakdown = {"device_ops": red["device_ops"][:BREAKDOWN_NAMES],
+                         "idle_gaps": red["idle_gaps"][:BREAKDOWN_NAMES]}
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     # ---------------- the comparison with the plain reference
@@ -529,6 +553,7 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
         "bound_at_close": bound_at_close, "offered": len(offered),
         "withdrawn": len(withdrawn),
         "grace_s": t_end - t1, "launches": obs["launches"],
+        "pod_table": obs.get("pod_table"),
         "launch_cache_delta": obs["launch_cache_delta"],
         "compiles_in_window": [[n, round(t - t0, 2), round(s, 3)]
                                for t, n, s in observer.compiles
@@ -540,6 +565,8 @@ def run_cell(workload: str, seed: int, seconds: int, trace: bool,
                                default=0.0),
         "gc_gen2": sum(1 for _p, g in obs["gc_pauses_ms"] if g == 2),
     }
+    if obs["trace"]:
+        diag["device_ops"] = obs["trace"]["device_ops"]   # all thirty
     if mix["kind"] == "arrivals":
         s = obs["bind_ms"]
         diag.update(

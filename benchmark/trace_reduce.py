@@ -17,6 +17,9 @@ OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SYNC_EVENT = "bench_clock_sync"
 UNATTRIBUTED = "scheduler_loop_unattributed"
+NAMES_KEPT = 30     # a list: after a cure the passes that are left stand
+#                     eleventh to thirtieth (PR 35); cell.py cuts the
+#                     breakdown's lists to the contract's ten
 
 
 def find_xplane(trace_dir: str) -> str | None:
@@ -90,10 +93,17 @@ def reduce_events(trace: dict, t0_ns: float, t1_ns: float,
     ``host_spans``: (phase, start_ns, end_ns) of the loop thread's
     flight-recorder phases on the same clock. Returns busy_s (mean over
     device planes of the union of operation intervals), window_s,
-    program_s {program: device seconds of its module events}, device_ops
-    and idle_gaps (the breakdown's two lists, at most 10 entries each)."""
+    program_s {program: device seconds of its module events, each clipped
+    to the slice}, program_launch_s {program: the seconds of each of its
+    module events that lie whole inside the slice, a launch each, in the
+    order they started}, device_ops and idle_gaps (the NAMES_KEPT that
+    took most time of each). A module event that
+    the slice's edge cuts is in program_s with the part inside and is no
+    launch: its length is not known (the profiler may have cut it where
+    the trace starts or stops)."""
     window_s = (t1_ns - t0_ns) / 1e9
     busy, program_s, op_s = [], {}, {}
+    program_launch_s: dict[str, list[float]] = {}
     gaps: list[tuple[float, float]] = []
     for dev in trace["devices"].values():
         clip = [(max(s, t0_ns), min(s + d, t1_ns)) for _n, s, d in dev["ops"]
@@ -109,6 +119,8 @@ def reduce_events(trace: dict, t0_ns: float, t1_ns: float,
         for a, b, prog in mods:
             program_s[prog] = program_s.get(prog, 0.0) + (
                 min(b, t1_ns) - max(a, t0_ns)) / 1e9
+            if a >= t0_ns and b <= t1_ns:
+                program_launch_s.setdefault(prog, []).append((b - a) / 1e9)
         starts = [a for a, _b, _p in mods]
         for n, s, d in dev["ops"]:
             if s + d <= t0_ns or s >= t1_ns or d <= 0:
@@ -147,9 +159,10 @@ def reduce_events(trace: dict, t0_ns: float, t1_ns: float,
                 + left / 1e9 / n_dev
 
     def top(d: dict) -> list[list]:
-        return [[k, v] for k, v in sorted(d.items(),
-                                          key=lambda kv: -kv[1])[:10]]
+        return [[k, v] for k, v in sorted(
+            d.items(), key=lambda kv: -kv[1])[:NAMES_KEPT]]
 
     return {"busy_s": sum(busy) / n_dev if busy else 0.0,
             "window_s": window_s, "program_s": program_s,
+            "program_launch_s": program_launch_s,
             "device_ops": top(op_s), "idle_gaps": top(idle)}

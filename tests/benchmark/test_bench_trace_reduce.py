@@ -43,7 +43,11 @@ def test_recorded_trace_gives_known_busy_idle_and_program_times(recorded):
                                    "convert_element_type"}
     assert r["device_ops"][0][0] == "schedule_batch_jit/while.5"
     assert r["device_ops"][0][1] == pytest.approx(0.016020747, abs=1e-9)
-    assert len(r["device_ops"]) == 10
+    assert len(r["device_ops"]) == trace_reduce.NAMES_KEPT
+    launches = r["program_launch_s"]   # both launches lie whole inside
+    assert [len(launches[p]) for p in sorted(launches)] == [2, 2]
+    assert sum(launches["schedule_batch_jit"]) == pytest.approx(
+        0.018815784, abs=1e-9)
     assert all(len(name) <= 120 for name, _s in r["device_ops"])
     idle = dict(r["idle_gaps"])
     assert idle["commit"] == pytest.approx(0.0005, abs=1e-9)
