@@ -164,6 +164,10 @@ class LaunchSpec:
     # may run the parallel auction with the fused soft-score terms
     # (pipeline._soft_statics) instead of the serial commit scan
     topo_soft: bool = False
+    # 1 + the highest pod-table slot in use when cblobs was taken (0: an
+    # empty table): pipeline.table_blocks_for(table_hi, slots) is the
+    # blocks of the table the launch's phase 1b reads
+    table_hi: int = 0
 
 
 class CapacityError(Exception):
@@ -316,6 +320,7 @@ class Mirror:
             tc_off[name][0] for name in
             ("pod_node", "pod_ns", "pod_uid", "pod_nominated"))
         self._slot_labels_off = tc_off["pt_label_vals"]
+        self._slot_valid_off = tc_off["pod_valid"][0]
         # packed pod-table rows of pods with terms, by the slot's content
         # (_slot_row_key), read-only, and the cache's counts: totals of
         # the scheduler's life, as the packed-row cache's are
@@ -360,6 +365,10 @@ class Mirror:
         self._node_pods: dict[str, dict[str, Pod]] = {}
         self._node_of_pod: dict[str, str] = {}   # uid -> node name
         self._free_slots: list[int] = list(range(caps.pods - 1, -1, -1))
+        # 1 + the highest slot in use (0: none). The lowest free slot goes
+        # first, so the live slots sit under it; phase 1b reads the table
+        # up to it (LaunchSpec.table_hi)
+        self.slots_hi = 0
         self._row_names: list[str | None] = [None] * caps.nodes
         # domain-bucket hysteresis high-water mark + decay counter (see
         # BUCKET_DECAY_LAUNCHES); survives re-bucketing via
@@ -869,6 +878,7 @@ class Mirror:
         if not self._free_slots:
             raise CapacityError("pods", self.caps.pods + 1)
         slot = self._free_slots.pop()
+        self.slots_hi = max(self.slots_hi, slot + 1)
         pod = pi.pod
         has_terms = bool(pi.required_anti_affinity_terms
                          or pi.required_affinity_terms
@@ -1094,6 +1104,9 @@ class Mirror:
             return
         self.pods_i32[slot] = 0  # pod_valid -> False, rest zeroed
         self._free_slots.append(slot)
+        if slot + 1 == self.slots_hi:
+            live = np.flatnonzero(self.pods_i32[:slot, self._slot_valid_off])
+            self.slots_hi = int(live[-1]) + 1 if live.size else 0
         self._dirty_slots.add(slot)
         self.slots_released += 1
         self._uids_with_terms.pop(uid, None)
@@ -2097,4 +2110,5 @@ class Mirror:
                           ptmpl=self.pod_template_blobs(),
                           gid=gid, rep=rep, g_cap=g_cap,
                           topo_soft=(enable and
-                                     self.batch_topology_soft_only(pods)))
+                                     self.batch_topology_soft_only(pods)),
+                          table_hi=self.slots_hi)

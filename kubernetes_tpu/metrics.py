@@ -423,6 +423,11 @@ class SchedulerMetrics:
             "with them: run (whole blocks up to the last row that "
             "carries a pod) or skipped (the rest of the batch bucket)",
             ("result",)))
+        self.device_table_blocks = r.register(Counter(
+            "scheduler_device_table_blocks_total",
+            "Pod-table blocks of topology launches by what phase 1b's "
+            "passes did with them: run (whole blocks up to the last live "
+            "slot) or skipped (the rest of the table)", ("result",)))
         self.device_live_buffer_bytes = r.register(Gauge(
             "scheduler_device_live_buffer_bytes",
             "Resident device-buffer bytes by buffer family (cluster "

@@ -24,6 +24,11 @@ phases:
    pods in a 1,024-row program pays for 16 steps. A padding step changes
    no carry, so the placements are those of the full-length scan.
 
+Between the two, phase 1b reads the pod table once a topology group: the
+table's passes run over blocks of it up to the last block that holds a
+live slot (``ops.topology.fold_table``, ``BatchResult.table_blocks``), so
+their cost follows the pods in the table, not its capacity.
+
 The node axis is the sharding axis: under a ``jax.sharding.Mesh`` the
 per-node work is data-parallel; argmax and normalization reductions become
 XLA collectives over ICI (SURVEY.md §5.8).
@@ -47,6 +52,7 @@ from kubernetes_tpu.ops import filters as FL
 from kubernetes_tpu.ops import learned as LN
 from kubernetes_tpu.ops import scores as SC
 from kubernetes_tpu.ops import topology as T
+from kubernetes_tpu.ops.topology import table_blocks_for  # noqa: F401
 from kubernetes_tpu.utils.interner import NONE
 from kubernetes_tpu.ops.features import (
     Capacities,
@@ -247,6 +253,11 @@ class BatchResult:
     # auction path, which has no scan). scan_steps_for() is the same
     # number on the host; nobody pulls this one.
     scan_steps: jax.Array
+    # [] i32: pod-table blocks phase 1b's passes ran (ops.topology
+    # .fold_table: whole blocks up to the last live slot; 0 on a launch
+    # without topology). table_blocks_for() is the same number on the
+    # host; nobody pulls this one either.
+    table_blocks: jax.Array
 
 
 # workload-activity flags (STATIC, host-derived per launch by
@@ -276,7 +287,8 @@ def _guard_reduction(scores: jnp.ndarray, free: jnp.ndarray) -> jnp.ndarray:
 # per-kernel device time.
 KERNEL_SCOPES = ("static_filters", "auction_rounds", "soft_topology_auction",
                  "commit_scan", "patch_chain", "scatter_rows",
-                 "inter_pod_affinity", "scan_queries", "scan_map_updates")
+                 "inter_pod_affinity", "scan_queries", "scan_map_updates",
+                 "table_block")
 
 
 @jax.named_scope("static_filters")
@@ -372,11 +384,12 @@ class _SoftTopo:
     d_cap: int = 0
 
 
-def _soft_statics(ct, pods, pods_rep, gid, g_cap, d_cap, tds, wk,
+def _soft_statics(ct, table, caps, pods, pods_rep, gid, g_cap, d_cap, wk,
                   enabled_filters, act, ipa_on, chunked_vmap):
     """Per-GROUP static halves of the soft topology scores (the auction's
     phase-1b): the table's contribution to each group's ipa mask/score and
-    soft-spread counts — placement-independent, computed once per launch."""
+    soft-spread counts — placement-independent, computed once per launch
+    over the table's live blocks (``table`` is ClusterBlobs.pods_i32)."""
     valid = ct.node_valid
 
     def per_group_soft(pod: PodFeatures):
@@ -387,7 +400,11 @@ def _soft_statics(ct, pods, pods_rep, gid, g_cap, d_cap, tds, wk,
         used_soft = used_c & ~pod.tsc_hard
         el_soft = T.spread_eligible(ct, pod, nodeaff_ok, taint_ok,
                                     used_soft)
-        cnt = T.spread_cnt(ct, pod, tds, el_soft, d_cap)         # [C, D]
+        ts = T.table_statics(
+            ct, table, caps, pod, d_cap, forbid=ipa_on,
+            hard_weight=jnp.float32(HARD_POD_AFFINITY_WEIGHT),
+            spread_el=el_soft)
+        cnt = ts.cnt                                             # [C, D]
         node_dom = T.take_cols(ct.topo_dom, pod.tsc_tk, jnp.int32(-1))
         ign = jnp.any((node_dom == jnp.int32(-1))
                       & used_soft[None], axis=1)                 # [N]
@@ -406,17 +423,13 @@ def _soft_statics(ct, pods, pods_rep, gid, g_cap, d_cap, tds, wk,
         dom_ok = node_dom != jnp.int32(-1)                       # [N, C]
         all_s = jnp.all(dom_ok | ~used_soft[None], axis=1)       # [N]
         el_node = pol & all_s[:, None] & dom_ok & used_soft[None]
-        anti_ok, _pres, _any = T.inter_pod_affinity_static(
-            ct, pod, tds, d_cap)
-        ipa_raw = T.inter_pod_affinity_score(
-            ct, pod, tds, d_cap, jnp.float32(HARD_POD_AFFINITY_WEIGHT))
-        return (anti_ok, ipa_raw, match_static, tpw, used_soft,
+        # the ipa filter disabled: its static mask all-True
+        anti_ok = ts.anti_ok if ipa_on else jnp.ones_like(valid)
+        return (anti_ok, ts.ipa_raw, match_static, tpw, used_soft,
                 dom_ok, ign, jnp.any(used_soft), el_node)
 
     (anti_g, ipa_raw_g, match_g, tpw_g, soft_g, dom_ok_g, ign_g,
      has_soft_g, el_node_g) = chunked_vmap(per_group_soft, pods_rep, g_cap)
-    if not ipa_on:
-        anti_g = jnp.ones_like(anti_g)
     tk_cap = ct.topo_dom.shape[1]
 
     def nd_of(tk_g):
@@ -859,7 +872,9 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
                                    if dra_reject is None else dra_reject),
                        learned_mag=learned_mag, chosen_feat=chosen_feat,
                        alt_row=alt_row, alt_score=alt_score,
-                       scan_steps=jnp.int32(0))
+                       scan_steps=jnp.int32(0),
+                       table_blocks=(T.table_blocks(ct) if soft is not None
+                                     else jnp.int32(0)))
 
 
 def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
@@ -971,7 +986,6 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                  and enabled_filters[FILTER_PLUGINS.index("PodTopologySpread")])
     ipa_on = (enable_topology
               and enabled_filters[FILTER_PLUGINS.index("InterPodAffinity")])
-    tds = T.slot_topo_dom(ct)  # [PT, TK], shared across the batch
     if enable_topology and gid is None:
         # direct callers without host grouping: every pod its own group.
         # NOTE: at large B this materializes O(B*N)-sized scan-carry maps —
@@ -1073,9 +1087,9 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
             # structure holds — the table halves are per-group statics,
             # the in-batch halves recompute per round (_soft_scores)
             pods_rep = jax.tree.map(lambda x: x[rep], pods)
-            soft = _soft_statics(ct, pods, pods_rep, gid, g_cap, d_cap,
-                                 tds, wk, enabled_filters, act, ipa_on,
-                                 chunked_vmap)
+            soft = _soft_statics(ct, cblobs.pods_i32, caps, pods, pods_rep,
+                                 gid, g_cap, d_cap, wk, enabled_filters,
+                                 act, ipa_on, chunked_vmap)
         return _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw,
                               aff_raw, img, unres, weights, free0, nzr0,
                               host_score, fit_strategy, fit_shape,
@@ -1086,9 +1100,9 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
         # ---- phase 1b (SOFT): the reduced per-group statics — exactly
         # what the soft scores need; none of the hard-constraint maps
         pods_rep = jax.tree.map(lambda x: x[rep], pods)
-        soft_st = _soft_statics(ct, pods, pods_rep, gid, g_cap, d_cap,
-                                tds, wk, enabled_filters, act, ipa_on,
-                                chunked_vmap)
+        soft_st = _soft_statics(ct, cblobs.pods_i32, caps, pods, pods_rep,
+                                gid, g_cap, d_cap, wk, enabled_filters, act,
+                                ipa_on, chunked_vmap)
     if enable_topology and not topo_soft:
         # ---- phase 1b: topology statics per GROUP (representatives) ----
         pods_rep = jax.tree.map(lambda x: x[rep], pods)  # leaves [G, ...]
@@ -1105,7 +1119,13 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
             el_soft = T.spread_eligible(ct, pod, nodeaff_ok, taint_ok,
                                         used_soft)
             el_mixed = jnp.where(pod.tsc_hard[None], el_hard, el_soft)
-            cnt = T.spread_cnt(ct, pod, tds, el_mixed, d_cap)      # [C, D]
+            # every read of the pod table, in one fold over its live blocks
+            ts = T.table_statics(
+                ct, cblobs.pods_i32, caps, pod, d_cap, forbid=True,
+                presence=True,
+                hard_weight=jnp.float32(HARD_POD_AFFINITY_WEIGHT),
+                spread_el=el_mixed)
+            cnt = ts.cnt                                            # [C, D]
             exists_hard = T.spread_exists(ct, pod, el_hard, d_cap)  # [C, D]
             node_dom = T.take_cols(ct.topo_dom, pod.tsc_tk, jnp.int32(-1))
             spread_ignored = jnp.any((node_dom == jnp.int32(-1))
@@ -1119,10 +1139,6 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
             tp_weight = jnp.log(jnp.sum(exists_score, axis=1)
                                 .astype(jnp.float32) + 2.0)         # [C]
             tsc_self = T._tsc_self_match(pod).astype(jnp.float32)   # [C]
-            ipa_anti_ok, aff_present, aff_any = T.inter_pod_affinity_static(
-                ct, pod, tds, d_cap)
-            ipa_raw = T.inter_pod_affinity_score(
-                ct, pod, tds, d_cap, jnp.float32(HARD_POD_AFFINITY_WEIGHT))
             has_soft = jnp.any(used_soft)
             # in-batch spread eligibility of ANY node as a commit target for
             # this group's constraints (policies + topology-label presence;
@@ -1141,11 +1157,11 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
             # spread match counts at each node's domain, domain presence
             aff_node_dom = T.take_cols(ct.topo_dom, pod.aff_tk, NONE)  # [N, A]
             has_lbl = aff_node_dom != NONE
-            term_static = has_lbl & T.gather_rows(aff_present, aff_node_dom)
+            term_static = has_lbl & T.gather_rows(ts.present, aff_node_dom)
             match_static = T.gather_rows(cnt, node_dom)              # [N, C]
             num_domains = jnp.sum(exists_hard, axis=1)               # [C]
             return (cnt, exists_hard, spread_ignored, tp_weight, tsc_self,
-                    ipa_anti_ok, aff_any, ipa_raw, has_soft,
+                    ts.anti_ok, ts.any_match, ts.ipa_raw, has_soft,
                     el_node, term_static, has_lbl, match_static, dom_ok,
                     num_domains)
 
@@ -1666,7 +1682,9 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                        dra_reject=dra_reject, learned_mag=learned_mag,
                        chosen_feat=chosen_feat,
                        alt_row=alt_row, alt_score=alt_score,
-                       scan_steps=jnp.minimum(n_blocks * u, B))
+                       scan_steps=jnp.minimum(n_blocks * u, B),
+                       table_blocks=(T.table_blocks(ct) if enable_topology
+                                     else jnp.int32(0)))
 
 
 @partial(jax.jit, static_argnames=("caps", "enable_topology", "d_cap",

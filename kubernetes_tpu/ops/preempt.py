@@ -153,15 +153,21 @@ def preempt_feasible(cblobs: ClusterBlobs, pblobs: PodBlobs,
         ok = ok & jnp.all(pod.req[None] <= eff, axis=-1)
     if not enable_topology:
         return ok
-    tds = T.slot_topo_dom(ct)
     taint_ok, nodeaff_ok = masks[2], masks[3]
     spread_on = enabled_filters[FILTER_PLUGINS.index("PodTopologySpread")]
     ipa_on = enabled_filters[FILTER_PLUGINS.index("InterPodAffinity")]
+    if not (spread_on or ipa_on):
+        return ok
     if spread_on:
         used_c = pod.tsc_tk != jnp.int32(-1)
         used_hard = used_c & pod.tsc_hard
         el_hard = T.spread_eligible(ct, pod, nodeaff_ok, taint_ok, used_hard)
-        cnt = T.spread_cnt(ct, pod, tds, el_hard, d_cap)        # [C, D]
+    # the victims are out of ct.pod_valid, so out of the fold's blocks
+    ts = T.table_statics(ct, cblobs.pods_i32, caps, pod, d_cap,
+                         forbid=ipa_on, presence=ipa_on,
+                         spread_el=el_hard if spread_on else None)
+    if spread_on:
+        cnt = ts.cnt                                            # [C, D]
         exists_hard = T.spread_exists(ct, pod, el_hard, d_cap)
         min_cnt = jnp.min(jnp.where(exists_hard, cnt, jnp.inf), axis=1)
         min_cnt = jnp.where(jnp.isfinite(min_cnt), min_cnt, 0.0)
@@ -177,17 +183,15 @@ def preempt_feasible(cblobs: ClusterBlobs, pblobs: PodBlobs,
             & (skew <= pod.tsc_max_skew[None])
         ok = ok & jnp.all(ok_c | ~used_hard[None], axis=1)
     if ipa_on:
-        anti_ok, present, any_match = T.inter_pod_affinity_static(
-            ct, pod, tds, d_cap)
         term_used = pod.aff_tk != NONE
         node_dom3 = T.take_cols(ct.topo_dom, pod.aff_tk, NONE)
         has_lbl = node_dom3 != NONE
-        term_ok = has_lbl & T.gather_rows(present, node_dom3)
+        term_ok = has_lbl & T.gather_rows(ts.present, node_dom3)
         pods_exist = jnp.all(term_ok | ~term_used[None], axis=1)
         all_lbl = jnp.all(has_lbl | ~term_used[None], axis=1)
-        self_ok = pod.aff_self_match & ~any_match & all_lbl
+        self_ok = pod.aff_self_match & ~ts.any_match & all_lbl
         aff_ok = jnp.where(jnp.any(term_used), pods_exist | self_ok, True)
-        ok = ok & anti_ok & aff_ok
+        ok = ok & ts.anti_ok & aff_ok
     return ok
 
 

@@ -32,15 +32,20 @@ Reference semantics implemented here:
 
 from __future__ import annotations
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import common as C
 from kubernetes_tpu.ops.features import (  # noqa: F401  (IMPOSSIBLE re-export)
     IMPOSSIBLE,
+    Capacities,
     ClusterTensors,
     PodFeatures,
+    codecs,
 )
+from kubernetes_tpu.ops.blobs import Blobs
 from kubernetes_tpu.utils.interner import NONE
 
 
@@ -201,16 +206,13 @@ def pair_tsc_match(pods: PodFeatures) -> jnp.ndarray:
 
 
 @jax.named_scope("inter_pod_affinity")
-def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
-                              tds: jnp.ndarray, d_cap: int):
-    """Pre-batch-table part of the Filter (filtering.go): returns
-    (anti_ok [N] — rules 1+2 vs the table, present [A, D] — affinity
-    presence map from the table, any_match — scalar). The commit scan layers
-    in-batch deltas on top (step_terms_forbid/step_own_terms_forbid/
-    step_affinity_ok)."""
+def ipa_forbid_map(ct: ClusterTensors, pod: PodFeatures, tds: jnp.ndarray,
+                   d_cap: int) -> jnp.ndarray:
+    """[TK, D] bool: the domains the table forbids the incoming pod, rules
+    1 and 2 of the Filter (filtering.go satisfyExistingPodsAntiAffinity,
+    satisfyPodAntiAffinity). forbid_ok takes it to node space."""
     tk_cap = ct.topo_dom.shape[1]
     anti_ok_tbl = table_mask(ct, pod, include_nominated=True)
-    pres_tbl = table_mask(ct, pod, include_nominated=False)
 
     # 1. existing pods' required anti-affinity vs incoming pod
     m1 = table_terms_vs_incoming(ct, anti_ok_tbl, ct.pod_anti_tk,
@@ -222,7 +224,6 @@ def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
                                axis=1)
     dom1 = jnp.where(ct.pod_anti_tk != NONE, dom1, NONE)
     f1 = scatter_or(ct.pod_anti_tk, dom1, m1, tk_cap, d_cap)       # [TK, D]
-    fail1 = jnp.any(gather_rows(f1, ct.topo_dom), axis=1)    # [N]
 
     # 2. incoming pod's required anti-affinity vs existing pods
     m2 = incoming_terms_vs_table(ct, anti_ok_tbl, pod.anti_tk, pod.anti_ns,
@@ -232,10 +233,24 @@ def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
     dom2 = jnp.where(pod.anti_tk[None] != NONE, dom2, NONE)
     tk2 = jnp.broadcast_to(pod.anti_tk[None], m2.shape)
     f2 = scatter_or(tk2, dom2, m2, tk_cap, d_cap)
-    fail2 = jnp.any(gather_rows(f2, ct.topo_dom), axis=1)
+    # a node fails if either map holds its domain under some key: the OR
+    # of the maps gathers to the OR of the two node verdicts
+    return f1 | f2
 
-    # 3. incoming pod's required affinity: every term needs a matching pod
-    #    in the node's domain (node must carry every term's topology label)
+
+def forbid_ok(ct: ClusterTensors, forbid: jnp.ndarray) -> jnp.ndarray:
+    """[N]: no domain of the node is in the forbid map."""
+    return ~jnp.any(gather_rows(forbid, ct.topo_dom), axis=1)
+
+
+@jax.named_scope("inter_pod_affinity")
+def ipa_presence_map(ct: ClusterTensors, pod: PodFeatures, tds: jnp.ndarray,
+                     d_cap: int):
+    """Rule 3, the incoming pod's required affinity: (present [A, D] — the
+    domains holding a table pod that term a selects, any_match — some
+    table pod matches some term; the first-pod-of-a-group rule)."""
+    tk_cap = ct.topo_dom.shape[1]
+    pres_tbl = table_mask(ct, pod, include_nominated=False)
     a_cap = pod.aff_tk.shape[0]
     m3 = incoming_terms_vs_table(ct, pres_tbl, pod.aff_tk, pod.aff_ns,
                                  pod.aff_ns_all, pod.aff_sel_cols,
@@ -246,15 +261,29 @@ def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
     present = scatter_or(rows3, dom3, m3, a_cap, d_cap)            # [A, D]
     term_used = pod.aff_tk != NONE                                 # [A]
     any_match = jnp.any(m3 & (dom3 != NONE) & term_used[None])
-    return ~fail1 & ~fail2, present, any_match
+    return present, any_match
+
+
+def inter_pod_affinity_static(ct: ClusterTensors, pod: PodFeatures,
+                              tds: jnp.ndarray, d_cap: int):
+    """Pre-batch-table part of the Filter (filtering.go) in one pass over
+    the whole table: returns (anti_ok [N] — rules 1+2 vs the table,
+    present [A, D] — affinity presence map from the table, any_match —
+    scalar). The launch runs the same maps over the table's live blocks
+    (table_statics); this form is the reference its tests hold it to. The
+    commit scan layers in-batch deltas on top."""
+    present, any_match = ipa_presence_map(ct, pod, tds, d_cap)
+    return (forbid_ok(ct, ipa_forbid_map(ct, pod, tds, d_cap)), present,
+            any_match)
 
 
 @jax.named_scope("inter_pod_affinity")
-def inter_pod_affinity_score(ct: ClusterTensors, pod: PodFeatures,
-                             tds: jnp.ndarray, d_cap: int,
-                             hard_weight: jnp.ndarray) -> jnp.ndarray:
-    """[N] raw score (scoring.go processExistingPod); normalized max-min at
-    aggregation (NormalizeScore :258)."""
+def ipa_score_map(ct: ClusterTensors, pod: PodFeatures, tds: jnp.ndarray,
+                  d_cap: int, hard_weight: jnp.ndarray) -> jnp.ndarray:
+    """[TK, D] f32: the raw score the table gives each domain under each
+    key (scoring.go processExistingPod); score_nodes takes it to node
+    space. Whole-number terms (weights 1-100), so a sum in any order is
+    the same f32 while it stays under 2**24."""
     tk_cap = ct.topo_dom.shape[1]
     score = jnp.zeros((tk_cap * d_cap,), jnp.float32)
     tbl_ok = table_mask(ct, pod, include_nominated=False)
@@ -295,9 +324,21 @@ def inter_pod_affinity_score(ct: ClusterTensors, pod: PodFeatures,
                       ct.pod_panti_ns_all, ct.pod_panti_sel_cols,
                       ct.pod_panti_sel_ops, ct.pod_panti_sel_vals,
                       ct.pod_panti_weight, -1.0)
+    return score.reshape(tk_cap, d_cap)
 
-    per_tk = gather_rows(score.reshape(tk_cap, d_cap), ct.topo_dom)
-    return jnp.sum(per_tk, axis=1)                                 # [N]
+
+def score_nodes(ct: ClusterTensors, score: jnp.ndarray) -> jnp.ndarray:
+    """[N]: a [TK, D] score map summed over each node's domains."""
+    return jnp.sum(gather_rows(score, ct.topo_dom), axis=1)
+
+
+def inter_pod_affinity_score(ct: ClusterTensors, pod: PodFeatures,
+                             tds: jnp.ndarray, d_cap: int,
+                             hard_weight: jnp.ndarray) -> jnp.ndarray:
+    """[N] raw score (scoring.go processExistingPod) in one pass over the
+    whole table, the reference of table_statics; normalized max-min at
+    aggregation (NormalizeScore :258)."""
+    return score_nodes(ct, ipa_score_map(ct, pod, tds, d_cap, hard_weight))
 
 
 # --------------------------- PodTopologySpread ---------------------------
@@ -371,3 +412,132 @@ def spread_exists(ct: ClusterTensors, pod: PodFeatures,
                       node_dom, node_mask, c_cap, d_cap)
 
 
+
+
+# ------------------------- the pod table, by blocks -------------------------
+#
+# Phase 1b's passes over the pod table (the scatters above) cost the
+# table's capacity, whatever it holds: 131,072 slots where a cell keeps
+# 3,010 pods. The mirror hands out the lowest free slot first, so the live
+# slots sit under a high-water mark; fold_table runs the passes over
+# fixed-width blocks of the table and stops after the last block that holds
+# a live slot. Its trip count is read on the device from pod_valid, so one
+# program serves a near-empty table and a full one. The reductions are ORs
+# of booleans and f32 sums of whole numbers, which give the same bits in
+# any order; the gathers to node space run once, after the loop.
+
+# blocks a table is cut into (fewer where the table has fewer slots)
+TABLE_BLOCKS = 16
+
+
+def table_block(pt: int) -> int:
+    """Slots in one block of a pod table of ``pt`` slots."""
+    return -(-pt // TABLE_BLOCKS)
+
+
+def table_blocks_for(hi: int, pt: int) -> int:
+    """The blocks fold_table runs (BatchResult.table_blocks, as host
+    arithmetic): whole blocks up to slot ``hi`` (1 + the highest slot in
+    use; 0 for an empty table) of a table of ``pt`` slots."""
+    return -(-hi // table_block(pt))
+
+
+def fold_table(ct: ClusterTensors, table: jnp.ndarray, caps: Capacities,
+               body):
+    """Run ``body(ctb, tds)`` over the pod table's blocks up to the last
+    that holds a live slot, and reduce what it returns (a pytree of maps):
+    bool leaves by OR, the rest by sum; a table with no live slot gives
+    zeros. ``table`` is the [PT, pi] blob (ClusterBlobs.pods_i32): each
+    block is sliced from it before it is unpacked, so no pass and no
+    unpack copy covers the whole table. ``ctb`` is ``ct`` with the block's
+    pod-table fields, ``tds`` its slot_topo_dom; ``ct.pod_valid`` is the
+    liveness read, so a caller that masks slots out (preemption's victims)
+    masks them out of the blocks and the trip count alike."""
+    pt = table.shape[0]
+    w = table_block(pt)
+    valid = ct.pod_valid
+    pad = (-pt) % w
+    if pad:
+        # direct callers only (the mirror's tables are powers of two): the
+        # last block's slots past the table are dead
+        table = jnp.pad(table, [(0, pad), (0, 0)])
+        valid = jnp.pad(valid, (0, pad))
+    table_codec = codecs(caps)[1]
+    empty = jnp.zeros((w, 0), jnp.float32)
+
+    def block(k):
+        with jax.named_scope("table_block"):
+            f = table_codec.unpack(Blobs(
+                f32=empty, i32=jax.lax.dynamic_slice_in_dim(table, k * w, w)))
+            f["pod_valid"] = jax.lax.dynamic_slice_in_dim(valid, k * w, w)
+            ctb = dataclasses.replace(ct, **f)
+            return body(ctb, slot_topo_dom(ctb))
+
+    def step(k, acc):
+        return jax.tree.map(
+            lambda a, b: a | b if a.dtype == jnp.bool_ else a + b,
+            acc, block(k))
+
+    zero = jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                        jax.eval_shape(block, 0))
+    return jax.lax.fori_loop(0, _live_blocks(valid, w), step, zero)
+
+
+def _live_blocks(valid: jnp.ndarray, w: int) -> jnp.ndarray:
+    """[] i32: blocks of ``w`` slots up to the last True of ``valid``."""
+    hi = jnp.max(jnp.where(valid, jnp.arange(1, valid.shape[0] + 1,
+                                             dtype=jnp.int32), 0))
+    return (hi + (w - 1)) // w
+
+
+def table_blocks(ct: ClusterTensors) -> jnp.ndarray:
+    """[] i32: the blocks fold_table runs over this table (the device's
+    own count of table_blocks_for)."""
+    return _live_blocks(ct.pod_valid, table_block(ct.pod_valid.shape[0]))
+
+
+@dataclasses.dataclass
+class TableStatics:
+    """What phase 1b reads from the pod table for one pod (group), in node
+    space where the commit scan wants nodes; None where not asked for."""
+
+    anti_ok: jnp.ndarray | None = None    # [N] rules 1+2 vs the table
+    present: jnp.ndarray | None = None    # [A, D] affinity presence
+    any_match: jnp.ndarray | None = None  # [] some table pod matches a term
+    ipa_raw: jnp.ndarray | None = None    # [N] raw InterPodAffinity score
+    cnt: jnp.ndarray | None = None        # [C, D] spread match counts
+
+
+def table_statics(ct: ClusterTensors, table: jnp.ndarray, caps: Capacities,
+                  pod: PodFeatures, d_cap: int, *, forbid: bool = False,
+                  presence: bool = False, hard_weight=None,
+                  spread_el=None) -> TableStatics:
+    """Phase 1b's table passes for one pod, folded over the table's live
+    blocks (fold_table): the forbid map of rules 1+2 (``forbid``), the
+    presence map of rule 3 (``presence``), the InterPodAffinity score map
+    (given ``hard_weight``) and the spread counts over the nodes
+    ``spread_el`` [N, C] admits (spread_eligible); each gathered to node
+    space once, after the loop. The same answers as
+    inter_pod_affinity_static, inter_pod_affinity_score and spread_cnt
+    over the whole table, bit for bit."""
+
+    def body(ctb, tds):
+        out = {}
+        if forbid:
+            out["forbid"] = ipa_forbid_map(ctb, pod, tds, d_cap)
+        if presence:
+            out["present"], out["any_match"] = ipa_presence_map(
+                ctb, pod, tds, d_cap)
+        if hard_weight is not None:
+            out["score"] = ipa_score_map(ctb, pod, tds, d_cap, hard_weight)
+        if spread_el is not None:
+            out["cnt"] = spread_cnt(ctb, pod, tds, spread_el, d_cap)
+        return out
+
+    maps = fold_table(ct, table, caps, body)
+    return TableStatics(
+        anti_ok=forbid_ok(ct, maps["forbid"]) if forbid else None,
+        present=maps.get("present"), any_match=maps.get("any_match"),
+        ipa_raw=(score_nodes(ct, maps["score"])
+                 if hard_weight is not None else None),
+        cnt=maps.get("cnt"))

@@ -69,6 +69,7 @@ from kubernetes_tpu.models.pipeline import (
     launch_batch,
     patch_chain,
     scan_steps_for,
+    table_blocks_for,
     warm_patch_chain,
 )
 from kubernetes_tpu.metrics import AsyncRecorder, SchedulerMetrics
@@ -1903,7 +1904,9 @@ class Scheduler:
             compiled = prof.note_launch(
                 pshape, len(runnable),
                 None if use_auction else scan_steps_for(
-                    len(runnable), spec.pblobs.f32.shape[0]))
+                    len(runnable), spec.pblobs.f32.shape[0]),
+                table_blocks_for(spec.table_hi, spec.cblobs.pods_i32.shape[0])
+                if spec.enable_topology else None)
             if compiled or prof.launches == 1:
                 # buffer footprints are bucket-static: re-measure only
                 # when a compile (= a bucket/flag change) happened

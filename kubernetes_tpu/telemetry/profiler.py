@@ -25,6 +25,12 @@ and execution indistinguishably. This instrument attributes it:
   ``pipeline.scan_steps_for``), and the share of the rows launched that
   it skipped. Near 0 in a backlog, near 1 where launches run almost
   empty: what the scan no longer pays for of the width above.
+* **Table blocks** per shape: the pod-table blocks phase 1b of its
+  topology launches read (the passes stop after the last block that
+  holds a live slot, ``pipeline.table_blocks_for``), and the share of the
+  blocks launched that they skipped: near 1 where a big table holds few
+  pods, near 0 where it is full. What the table passes no longer pay for
+  of the table's capacity.
 * **Live buffer bytes** — the HBM footprint of what the scheduler keeps
   resident: the nodes×resources cluster tensors, the per-batch pod
   tensors, the dense DRA inventories, the learned-scorer params
@@ -104,6 +110,24 @@ def _steps_skipped(shape: tuple, rec: dict) -> float:
     return 1.0 - rec["steps"] / rows
 
 
+def _table_size(shape: tuple) -> int:
+    """Blocks of the shape's pod table that a topology launch could read
+    (0 for a shape without topology, which reads no table)."""
+    from kubernetes_tpu.models.pipeline import table_blocks_for
+
+    d = dict(shape)
+    pt = d.get("pods") or 0
+    return table_blocks_for(pt, pt) if d.get("topo") and pt else 0
+
+
+def _table_skipped(shape: tuple, rec: dict) -> float:
+    """Share of the pod-table blocks launched (launches times the blocks
+    of the shape's table) that phase 1b did not read, 0.0 to 1.0 (0.0 for
+    a shape without topology)."""
+    blocks = rec["launches"] * _table_size(shape)
+    return 1.0 - rec["table_blocks"] / blocks if blocks else 0.0
+
+
 def _diff_cause(prev: Optional[tuple], cur: tuple) -> str:
     """Attribute a compile to what changed since the previous launch."""
     if prev is None:
@@ -149,31 +173,36 @@ class DeviceProfiler:
         self.compiles = 0
         self.compile_causes: dict[str, int] = {}
         self.compile_events: list[dict] = []   # ring, newest last
-        # shape -> {"launches", "pods", "steps", "compiles", "walltime_s",
-        #           "max_s"}
+        # shape -> {"launches", "pods", "steps", "table_blocks", "compiles",
+        #           "walltime_s", "max_s"}
         self.shapes: dict[tuple, dict] = {}
         self.buffer_bytes: dict[str, int] = {}
 
     # ------------- recording (loop thread) -------------
 
     def note_launch(self, shape: tuple, pods: int = 0,
-                    steps: Optional[int] = None) -> bool:
+                    steps: Optional[int] = None,
+                    table_blocks: Optional[int] = None) -> bool:
         """Record one dispatched launch that carried ``pods`` rows of its
-        batch bucket (pods; gang units for a gang-pack launch) and, where
-        it ran the serial commit scan, the ``steps`` the scan took
-        (pipeline.scan_steps_for; None for an auction or a gang pack);
-        returns True when the jit executable cache grew (a real XLA
-        compile happened while tracing this launch)."""
+        batch bucket (pods; gang units for a gang-pack launch), where it
+        ran the serial commit scan the ``steps`` the scan took
+        (pipeline.scan_steps_for; None for an auction or a gang pack), and
+        where it ran topology the pod-table ``table_blocks`` phase 1b read
+        (pipeline.table_blocks_for; None without topology); returns True
+        when the jit executable cache grew (a real XLA compile happened
+        while tracing this launch)."""
         self.launches += 1
         rec = self.shapes.get(shape)
         first_of_shape = rec is None
         if rec is None:
             rec = self.shapes[shape] = {"launches": 0, "pods": 0,
-                                        "steps": 0, "compiles": 0,
-                                        "walltime_s": 0.0, "max_s": 0.0}
+                                        "steps": 0, "table_blocks": 0,
+                                        "compiles": 0, "walltime_s": 0.0,
+                                        "max_s": 0.0}
         rec["launches"] += 1
         rec["pods"] += pods
         rec["steps"] += steps or 0
+        rec["table_blocks"] += table_blocks or 0
         cache = self._cache_size_fn()
         compiled = cache > self._last_cache
         if compiled:
@@ -207,6 +236,11 @@ class DeviceProfiler:
                 self._metrics.device_scan_steps.inc(steps, result="run")
                 self._metrics.device_scan_steps.inc(
                     (dict(shape).get("b") or 0) - steps, result="skipped")
+            if table_blocks is not None:
+                self._metrics.device_table_blocks.inc(table_blocks,
+                                                      result="run")
+                self._metrics.device_table_blocks.inc(
+                    _table_size(shape) - table_blocks, result="skipped")
         return compiled
 
     def observe_walltime(self, shape: tuple, secs: float) -> None:
@@ -238,6 +272,7 @@ class DeviceProfiler:
                 {"shape": shape_label(s), **rec,
                  "fill": round(_fill(s, rec), 4),
                  "steps_skipped": round(_steps_skipped(s, rec), 4),
+                 "table_skipped": round(_table_skipped(s, rec), 4),
                  "walltime_s": round(rec["walltime_s"], 4),
                  "max_s": round(rec["max_s"], 4)}
                 for s, rec in self.shapes.items()],

@@ -97,13 +97,14 @@ def _soft_spec():
 
 @pytest.mark.parametrize("make, serial_scan, scopes, absent", [
     (_plain_spec, False, ("static_filters", "auction_rounds"),
-     ("commit_scan", "soft_topology_auction")),
+     ("commit_scan", "soft_topology_auction", "table_block")),
     (_plain_spec, True, ("static_filters", "commit_scan"),
-     ("auction_rounds", "scan_queries", "scan_map_updates")),
-    (_soft_spec, False, ("static_filters", "soft_topology_auction"),
+     ("auction_rounds", "scan_queries", "scan_map_updates", "table_block")),
+    (_soft_spec, False, ("static_filters", "soft_topology_auction",
+                         "table_block"),
      ("auction_rounds", "commit_scan")),
     (_affinity_spec, True, ("static_filters", "inter_pod_affinity",
-                            "commit_scan", "scan_queries",
+                            "table_block", "commit_scan", "scan_queries",
                             "scan_map_updates"), ("auction_rounds",)),
 ])
 def test_schedule_batch_kernels_carry_their_scope(make, serial_scan, scopes,
@@ -128,4 +129,4 @@ def test_chain_and_mirror_scatters_carry_their_scope():
     assert set(KERNEL_SCOPES) == {
         "static_filters", "auction_rounds", "soft_topology_auction",
         "commit_scan", "patch_chain", "scatter_rows", "inter_pod_affinity",
-        "scan_queries", "scan_map_updates"}
+        "scan_queries", "scan_map_updates", "table_block"}

@@ -4,6 +4,8 @@ The key property (SURVEY.md §7.2 hard part 2): scheduling a batch in one
 launch must produce the same placements as running the serial loop pod by
 pod with an assume between pods (schedule_one.go:65 comment)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -222,6 +224,7 @@ from kubernetes_tpu.models.pipeline import (  # noqa: E402
     launch_batch,
     scan_steps_for,
     scan_unroll,
+    table_blocks_for,
 )
 from kubernetes_tpu.ops.features import PodBlobs  # noqa: E402
 
@@ -256,25 +259,25 @@ def _green(i, ns="sched-1"):
     return p
 
 
-def _anti_affinity_case(n):
+def _anti_affinity_case(n, caps=SCAN_CAPS):
     """16 nodes, six of them already hold a green pod: ten are left, so of
     13 pods three find every node forbidden, by the table or by an earlier
     pod of their own launch."""
-    cache, snap, mirror = build_cluster(16, caps=SCAN_CAPS)
+    cache, snap, mirror = build_cluster(16, caps=caps)
     for i in range(6):
         init = _green(i, ns="sched-0")
         init.spec.node_name = f"node-{2 * i}"
         cache.add_pod(init)
     cache.update_snapshot(snap)
     mirror.sync(snap)
-    return mirror, [_green(i) for i in range(n)], SCAN_CAPS, {}
+    return mirror, [_green(i) for i in range(n)], caps, {}
 
 
-def _zone_spread_case(n):
+def _zone_spread_case(n, caps=SCAN_CAPS, when="DoNotSchedule"):
     """12 nodes in 3 zones, maxSkew 1 over the zone, and the third zone's
     nodes are full: its count stays 0, so the first two pods take a zone
     each and every later one is held off by the commits before it."""
-    cache, snap, mirror = build_cluster(12, caps=SCAN_CAPS, zones=3)
+    cache, snap, mirror = build_cluster(12, caps=caps, zones=3)
     for i in range(2, 12, 3):
         full = make_pod(100 + i, cpu="32", mem="1Gi")
         full.metadata.uid = full.metadata.name
@@ -286,11 +289,17 @@ def _zone_spread_case(n):
     for i in range(n):
         p = _same_pod(i)
         p.spec.topology_spread_constraints = [TopologySpreadConstraint(
-            max_skew=1, topology_key=LABEL_ZONE,
-            when_unsatisfiable="DoNotSchedule",
+            max_skew=1, topology_key=LABEL_ZONE, when_unsatisfiable=when,
             label_selector=LabelSelector(match_labels={"app": "s"}))]
         pods.append(p)
-    return mirror, pods, SCAN_CAPS, {}
+    return mirror, pods, caps, {}
+
+
+def _soft_spread_case(n, caps=SCAN_CAPS):
+    """The zone spread as ScheduleAnyway: a score, not a filter, so the
+    launch takes the soft-topology auction (topology-5k.preferred's)."""
+    mirror, pods, caps, _ = _zone_spread_case(n, caps, "ScheduleAnyway")
+    return mirror, pods, caps, {"serial_scan": False}
 
 
 def _host_ports_case(n):
@@ -435,3 +444,57 @@ def test_a_width_that_is_no_multiple_of_the_unroll(width, n, steps):
 
 # suite-tier discipline (tests/test_markers.py): area marker
 pytestmark = pytest.mark.core
+
+
+# ---- phase 1b reads the pod table up to its last live slot (PR 38) ----
+#
+# The table passes run over blocks of the table and stop after the last
+# block that holds a live slot: the same cluster and pods in a table of 64
+# and of 256 slots (blocks of 4 and of 16) land the same way, and
+# BatchResult.table_blocks reads what table_blocks_for() computes on the
+# host from the mirror's highest slot in use.
+
+TABLE_CASES = {"anti_affinity_hostname": _anti_affinity_case,
+               "zone_spread": _zone_spread_case,
+               "soft_spread": _soft_spread_case}
+
+
+@pytest.mark.parametrize("case", sorted(TABLE_CASES))
+def test_placements_do_not_depend_on_the_table_size(case):
+    outs = []
+    for pt in (64, 256):
+        mirror, pods, caps, kw = TABLE_CASES[case](
+            13, dataclasses.replace(SCAN_CAPS, pods=pt))
+        spec = mirror.prepare_launch(pods, 16)
+        assert spec.enable_topology and spec.table_hi == mirror.slots_hi
+        assert 0 < spec.table_hi <= 6        # the init pods' slots
+        out = launch_batch(spec, mirror.well_known(), default_weights(),
+                           caps, **kw)
+        assert int(out.table_blocks) == table_blocks_for(spec.table_hi, pt) \
+            == -(-spec.table_hi // (pt // 16)), (pt, int(out.table_blocks))
+        outs.append(out)
+    ref, big = outs
+    assert (np.asarray(ref.node_row)[:13] >= 0).any()
+    for field in ("node_row", "score", "feasible_count", "reject_counts",
+                  "unresolvable_count", "free", "nzr", "guard"):
+        np.testing.assert_array_equal(np.asarray(getattr(ref, field)),
+                                      np.asarray(getattr(big, field)),
+                                      err_msg=field)
+
+
+@pytest.mark.parametrize("serial_scan", [True, False])
+def test_a_launch_without_topology_reads_no_table_block(serial_scan):
+    """Plain pods over a table that holds plain pods: no topology, so
+    phase 1b and its table passes compile out."""
+    cache, snap, mirror = build_cluster(4, caps=SCAN_CAPS)
+    for i in range(3):
+        bound = _same_pod(100 + i)
+        bound.spec.node_name = f"node-{i}"
+        cache.add_pod(bound)
+    cache.update_snapshot(snap)
+    mirror.sync(snap)
+    assert mirror.slots_hi == 3
+    pods = [_same_pod(i) for i in range(5)]
+    out = _launch(mirror, pods, 16, SCAN_CAPS, serial_scan=serial_scan)
+    assert int(out.table_blocks) == 0
+    assert (np.asarray(out.node_row)[:5] >= 0).all()

@@ -170,7 +170,6 @@ def test_soft_static_ipa_matches_host_oracle(seed):
     import jax
 
     import kubernetes_tpu.models.pipeline as P
-    import kubernetes_tpu.ops.topology as T
     from kubernetes_tpu.ops.features import unpack_cluster, unpack_pods
 
     rng = random.Random(seed)
@@ -183,10 +182,9 @@ def test_soft_static_ipa_matches_host_oracle(seed):
     ct = unpack_cluster(spec.cblobs, CAPS)
     pf = unpack_pods(spec.pblobs, CAPS, spec.pfields, spec.ptmpl)
     pods_rep = jax.tree.map(lambda x: x[spec.rep], pf)
-    tds = T.slot_topo_dom(ct)
     soft = P._soft_statics(
-        ct, pf, pods_rep, spec.gid, spec.g_cap, spec.d_cap, tds,
-        m.well_known(), (True,) * P.NUM_FILTER_PLUGINS,
+        ct, spec.cblobs.pods_i32, CAPS, pf, pods_rep, spec.gid, spec.g_cap,
+        spec.d_cap, m.well_known(), (True,) * P.NUM_FILTER_PLUGINS,
         frozenset(P.ALL_FEATURES), True,
         lambda fn, tree, n: jax.vmap(fn)(tree))
     ipa_raw = np.asarray(soft.ipa_raw_g)

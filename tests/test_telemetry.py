@@ -644,6 +644,54 @@ def test_device_profiler_keeps_scan_steps_per_serial_shape(b, carried,
     parse_exposition(text)
 
 
+@pytest.mark.parametrize("pt, blocks, skipped", [
+    # the anti-affinity cell: 3,010 live slots, all in the first of 16
+    # blocks of 8,192; a backlog's table filling up; an empty table and a
+    # full one
+    (131072, (1, 1, 1, 1), 1 - 4 / 64),
+    (131072, (12, 13, 16), 1 - 41 / 48),
+    (64, (0, 16), 0.5),
+])
+def test_device_profiler_keeps_table_blocks_per_topology_shape(pt, blocks,
+                                                               skipped):
+    """A topology launch's phase 1b reads whole blocks of the pod table up
+    to its last live slot; the shape keeps the blocks, the snapshot the
+    share of the table's blocks it skipped, the counter both sides of it,
+    and a shape without topology reads 0.0."""
+    from kubernetes_tpu.metrics import SchedulerMetrics
+    from kubernetes_tpu.models import pipeline
+    from kubernetes_tpu.ops.features import Capacities
+
+    caps = Capacities(nodes=8192, pods=pt)
+    per_table = pipeline.table_blocks_for(pt, pt)
+    assert per_table == 16
+    assert pipeline.table_blocks_for(3010, 131072) == 1
+    metrics = SchedulerMetrics()
+    prof = DeviceProfiler(metrics=metrics, cache_size_fn=lambda: 0,
+                          now=lambda: 0.0)
+    topo = shape_key(caps, 1024, True, 8192, 2, True, False, False, False)
+    auction = shape_key(caps, 1024, False, 0, 0, False, False, False, False)
+    for n in blocks:
+        prof.note_launch(topo, 9, 16, n)
+    prof.note_launch(auction, 7)       # no topology: no table read
+    assert prof.shapes[topo]["table_blocks"] == sum(blocks)
+    snap = {s["shape"]: s for s in prof.snapshot()["shapes"]}
+    mine = snap[f"b=1024 nodes=8192 pods={pt} topo=1 d_cap=8192 "
+                "serial=1 soft=0 dra=0"]
+    assert mine["table_blocks"] == sum(blocks)
+    assert mine["table_skipped"] == round(skipped, 4)
+    other = snap[f"b=1024 nodes=8192 pods={pt} topo=0 d_cap=0 "
+                 "serial=0 soft=0 dra=0"]
+    assert (other["table_blocks"], other["table_skipped"]) == (0, 0.0)
+    run = metrics.device_table_blocks.value(result="run")
+    gone = metrics.device_table_blocks.value(result="skipped")
+    assert (run, gone) == (sum(blocks), len(blocks) * 16 - sum(blocks))
+    text = metrics.registry.render_text()
+    assert 'scheduler_device_table_blocks_total{result="run"}' in text
+    assert 'scheduler_device_table_blocks_total{result="skipped"}' in text
+    parse_exposition(text)
+
+
 def test_device_profiler_on_live_scheduler_rebucket():
     """Every recompile in a churn-with-growth run attributes to a
     bucket-shape transition (the MixedChurn acceptance criterion in
@@ -858,3 +906,43 @@ def test_hubserver_metrics_and_healthz():
 def test_journal_event_trace_default_none_back_compat():
     ev = JournalEvent(rv=1, kind="pods", type="add", new=None)
     assert ev.trace is None
+
+
+def test_live_scheduler_counts_the_table_blocks_its_launches_read():
+    """Three waves of three green pods with a hostname anti-affinity term
+    over a 16-slot table (blocks of one slot): a bound pod takes its slot
+    at the next launch's sync, so the launches read 0, 3 and 6 blocks, and
+    /debug/trace's device block and the exported counter say so."""
+    from kubernetes_tpu.config.types import default_config
+    from kubernetes_tpu.ops.features import Capacities
+    from kubernetes_tpu.scheduler import Scheduler
+
+    hub = Hub()
+    for i in range(10):
+        hub.create_node(MakeNode().name(f"tn-{i}").capacity(cpu="64").obj())
+    cfg = default_config()
+    cfg.batch_size = 4
+    sched = Scheduler(hub, cfg, caps=Capacities(nodes=16, pods=16))
+    try:
+        for wave in range(3):
+            for i in range(3):
+                hub.create_pod(MakePod().name(f"t{wave}-{i}")
+                               .label("color", "green").req(cpu="50m")
+                               .pod_anti_affinity("kubernetes.io/hostname",
+                                                  {"color": "green"}).obj())
+            sched.run_until_idle()
+        nodes = {p.spec.node_name for p in hub.list_pods()}
+        assert len(nodes) == 9 and "" not in nodes
+        assert sched.mirror.slots_hi == 6
+        topo = [s for s in sched.profiler.snapshot()["shapes"]
+                if "topo=1" in s["shape"]]
+        launches = sum(s["launches"] for s in topo)
+        read = sum(s["table_blocks"] for s in topo)
+        assert (launches, read) == (3, 0 + 3 + 6)
+        assert [s["table_skipped"] for s in topo] == [round(1 - 9 / 48, 4)]
+        run = sched.metrics.device_table_blocks.value(result="run")
+        gone = sched.metrics.device_table_blocks.value(result="skipped")
+        assert (run, run + gone) == (read, launches * 16)
+    finally:
+        sched.close()
+        hub.close()

@@ -332,3 +332,141 @@ def test_preferred_anti_affinity_scores():
 # suite-tier discipline (tests/test_markers.py): area marker
 import pytest  # noqa: E402
 pytestmark = pytest.mark.core
+
+
+# ---- phase 1b's table passes, folded over the live blocks (PR 38) ----
+#
+# table_statics runs the passes over blocks of the pod table up to the last
+# block that holds a live slot. ORs of booleans and f32 sums of whole
+# numbers give the same bits in any order, so every map must equal the
+# one pass over the whole table (inter_pod_affinity_static,
+# inter_pod_affinity_score, spread_cnt) bit for bit, whatever is live.
+
+import functools  # noqa: E402
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+import kubernetes_tpu.ops.topology as T  # noqa: E402
+from kubernetes_tpu.ops.features import (  # noqa: E402
+    ClusterBlobs,
+    unpack_cluster,
+    unpack_pods,
+)
+from kubernetes_tpu.utils.interner import NONE  # noqa: E402
+
+FOLD_CAPS = Capacities(nodes=16, pods=64, domains=16)   # 16 blocks of 4
+APPS = ("web", "db", "cache")
+
+
+def _weighted(weight, topokey, **match):
+    return WeightedPodAffinityTerm(weight=weight, pod_affinity_term=(
+        PodAffinityTerm(topology_key=topokey,
+                        label_selector=LabelSelector(match_labels=match))))
+
+
+def _table_pod(i):
+    """Slot i's pod: every kind of term the passes read, a weight of 1 to
+    100, two namespaces, 16 nodes in three zones."""
+    app = APPS[i % 3]
+    key = LABEL_ZONE if i % 2 else LABEL_HOSTNAME
+    kinds = (None,
+             anti(key, app=APPS[(i + 1) % 3]),
+             aff(key, app=app),
+             Affinity(pod_affinity=PodAffinity(preferred=[
+                 _weighted(1 + 33 * (i % 4), key, app="web")])),
+             Affinity(pod_anti_affinity=PodAntiAffinity(preferred=[
+                 _weighted(100, LABEL_ZONE, app=app)])))
+    return mkpod(f"t{i}", {"app": app}, node=f"n{i % 16}",
+                 affinity=kinds[i % 5], ns="default" if i % 7 else "other")
+
+
+def _incoming():
+    """Three groups: each reads the table through another term."""
+    spread = [TopologySpreadConstraint(
+        max_skew=1, topology_key=LABEL_ZONE,
+        when_unsatisfiable="DoNotSchedule",
+        label_selector=LabelSelector(match_labels={"app": "web"}))]
+    both = anti(LABEL_ZONE, app="db")
+    both.pod_affinity = PodAffinity(
+        required=[PodAffinityTerm(
+            topology_key=LABEL_HOSTNAME,
+            label_selector=LabelSelector(match_labels={"app": "cache"}))],
+        preferred=[_weighted(37, LABEL_ZONE, app="web")])
+    soft = Affinity(pod_anti_affinity=PodAntiAffinity(preferred=[
+        _weighted(100, LABEL_HOSTNAME, app="db")]))
+    return [mkpod("in-web", {"app": "web"}, affinity=both, tsc=spread),
+            mkpod("in-db", {"app": "db"}, affinity=soft, tsc=spread),
+            mkpod("in-plain", {"app": "cache"})]
+
+
+@functools.lru_cache(maxsize=1)
+def _fold_inputs():
+    """A full 64-slot table, the incoming pods' features and d_cap."""
+    nodes = [mknode(f"n{i}", f"z{i % 3}") for i in range(16)]
+    cl = Cluster(nodes, [_table_pod(i) for i in range(64)])
+    assert cl.mirror.slots_hi == 64
+    spec = cl.mirror.prepare_launch(_incoming(), 8)
+    pods = unpack_pods(spec.pblobs, FOLD_CAPS, spec.pfields, spec.ptmpl)
+    return (np.asarray(spec.cblobs.pods_i32), spec.cblobs, pods,
+            spec.d_cap, cl.mirror._slot_valid_off)
+
+
+@functools.partial(jax.jit, static_argnames=("d_cap",))
+def _fold_and_reference(cblobs, pods, d_cap):
+    ct = unpack_cluster(cblobs, FOLD_CAPS)
+    tds = T.slot_topo_dom(ct)
+    hw = jnp.float32(100.0)
+
+    def one(pod):
+        el = ct.node_valid[:, None] & (pod.tsc_tk != NONE)[None]
+        anti_ok, present, any_match = T.inter_pod_affinity_static(
+            ct, pod, tds, d_cap)
+        ref = (anti_ok, present, any_match,
+               T.inter_pod_affinity_score(ct, pod, tds, d_cap, hw),
+               T.spread_cnt(ct, pod, tds, el, d_cap))
+        ts = T.table_statics(ct, cblobs.pods_i32, FOLD_CAPS, pod, d_cap,
+                             forbid=True, presence=True, hard_weight=hw,
+                             spread_el=el)
+        return ref, (ts.anti_ok, ts.present, ts.any_match, ts.ipa_raw,
+                     ts.cnt)
+
+    return jax.vmap(one)(pods), T.table_blocks(ct)
+
+
+FOLD_TABLES = {
+    "empty": (),
+    "one_slot_at_0": (0,),
+    "prefix_inside_block_0": range(3),
+    "prefix_across_blocks": range(23),
+    "holes_below_the_mark": sorted(set(range(41))
+                                   - {3, 5, 16, 17, 18, 19, 33}),
+    "only_the_last_slot": (63,),
+    "full": range(64),
+}
+
+
+@pytest.mark.parametrize("table", sorted(FOLD_TABLES))
+def test_the_folded_table_passes_equal_the_full_ones_bit_for_bit(table):
+    full, cblobs, pods, d_cap, valid_off = _fold_inputs()
+    live = sorted(FOLD_TABLES[table])
+    i32 = full.copy()
+    # a dead slot keeps its fields: only pod_valid tells it from a live one
+    i32[np.setdiff1d(np.arange(64), live), valid_off] = 0
+    cb = ClusterBlobs(node_f32=cblobs.node_f32, node_i32=cblobs.node_i32,
+                      pods_i32=jnp.asarray(i32))
+    (ref, fold), blocks = _fold_and_reference(cb, pods, d_cap)
+    hi = live[-1] + 1 if live else 0
+    assert int(blocks) == T.table_blocks_for(hi, 64) == -(-hi // 4)
+    names = ("anti_ok", "present", "any_match", "ipa_raw", "cnt")
+    for name, r, f in zip(names, ref, fold, strict=True):
+        r, f = np.asarray(r), np.asarray(f)
+        assert r.dtype == f.dtype, name
+        np.testing.assert_array_equal(r.view(np.uint8), f.view(np.uint8),
+                                      err_msg=name)
+    if table == "full":
+        # the table decides something here: forbidden nodes, a present
+        # domain, scores of both signs, counts
+        anti_ok, present, _any, ipa_raw, cnt = map(np.asarray, ref)
+        assert not anti_ok[0].all() and present[0].any()
+        assert (ipa_raw > 0).any() and (ipa_raw < 0).any() and cnt.any()
