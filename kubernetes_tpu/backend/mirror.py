@@ -311,6 +311,10 @@ class Mirror:
         self.row_cache_misses = 0
         self.row_cache_bypass = 0
         self.row_cache_clears = 0
+        # seconds _pack_batch_np spent on the rows the cache did not serve
+        # (bypass and miss alike), by its own clock pair around each such
+        # row (the flight recorder's pack_full view); a hit reads no clock
+        self.pack_full_s = 0.0
         self._table_i32_tmpl: np.ndarray | None = None
         # pt_label_vals rows by the labels' content (pod_labels_row), and
         # the offsets the term-free arm of _pack_pod_slot writes at
@@ -1354,14 +1358,16 @@ class Mirror:
         capacity re-bucket (scheduler._grow builds a FRESH mirror):
         without this a rebuilt mirror re-derives a smaller bucket from
         its still-empty domain tables and the next churn swing pays the
-        compile again. The two row caches' counts and sync_stats'
-        come along too: they are totals the registry mirrors by delta,
-        and the caches themselves start empty."""
+        compile again. The two row caches' counts, pack_full_s and
+        sync_stats' come along too: they are totals the registry and the
+        flight recorder read by delta, and the caches themselves start
+        empty."""
         self._d_hw = prev._d_hw
         self.row_cache_hits = prev.row_cache_hits
         self.row_cache_misses = prev.row_cache_misses
         self.row_cache_bypass = prev.row_cache_bypass
         self.row_cache_clears = prev.row_cache_clears
+        self.pack_full_s = prev.pack_full_s
         self.slot_row_hits = prev.slot_row_hits
         self.slot_row_misses = prev.slot_row_misses
         self.slot_row_bypass = prev.slot_row_bypass
@@ -1879,6 +1885,8 @@ class Mirror:
         cacheable = name_ent is not None and uid_ent is not None
         cache = self._pod_rows.setdefault(fields, {})
         hits = misses = 0
+        full_s = 0.0
+        clock = time.perf_counter
         for b, pod in enumerate(pods):
             key = self._pod_row_key(pod) if cacheable else None
             row = cache.get(key) if key is not None else None
@@ -1887,6 +1895,7 @@ class Mirror:
                 f32[b] = row[0]
                 i32[b] = row[1]
             else:
+                t0 = clock()
                 self.pod_codec.pack_into_subset(
                     fields, f32[b], i32[b],
                     self.pack_pod(pod, active_only=True))
@@ -1896,12 +1905,14 @@ class Mirror:
                         cache.clear()
                         self.row_cache_clears += 1
                     cache[key] = (f32[b].copy(), i32[b].copy())
+                full_s += clock() - t0
             if cacheable:
                 i32[b, name_ent[0]] = self._i(pod.metadata.name)
                 i32[b, uid_ent[0]] = self._i(pod.metadata.uid)
         self.row_cache_hits += hits
         self.row_cache_misses += misses
         self.row_cache_bypass += len(pods) - hits - misses
+        self.pack_full_s += full_s
         return f32, i32
 
     def slot_row_cache_stats(self) -> dict:

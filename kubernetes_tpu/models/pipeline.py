@@ -288,7 +288,7 @@ def _guard_reduction(scores: jnp.ndarray, free: jnp.ndarray) -> jnp.ndarray:
 KERNEL_SCOPES = ("static_filters", "auction_rounds", "soft_topology_auction",
                  "commit_scan", "patch_chain", "scatter_rows",
                  "inter_pod_affinity", "scan_queries", "scan_map_updates",
-                 "table_block")
+                 "table_block", "node_affinity")
 
 
 @jax.named_scope("static_filters")

@@ -18,6 +18,7 @@ Reference algorithms:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import common as C
@@ -129,22 +130,26 @@ def node_affinity(ct: ClusterTensors, pod: PodFeatures,
     pin_ok = (pod.aff_pin == NONE) | (ct.node_name_id == pod.aff_pin)  # [N]
     if not full:
         return pin_ok
-    # nodeSelector pairs: node's value in the pair's label column must equal
-    # the pair's value (col NONE -> key on no node -> never matches)
-    node_val = _take_cols(ct.label_col_vals, pod.nodesel_cols, NONE)  # [N, PL]
-    used_pair = pod.nodesel_vals != NONE
-    hit = node_val == pod.nodesel_vals[None]
-    sel_ok = jnp.all(hit | ~used_pair[None], axis=-1)     # [N]
+    with jax.named_scope("node_affinity"):
+        # nodeSelector pairs: node's value in the pair's label column must
+        # equal the pair's value (col NONE -> key on no node -> never
+        # matches)
+        node_val = _take_cols(ct.label_col_vals, pod.nodesel_cols,
+                              NONE)                              # [N, PL]
+        used_pair = pod.nodesel_vals != NONE
+        hit = node_val == pod.nodesel_vals[None]
+        sel_ok = jnp.all(hit | ~used_pair[None], axis=-1)     # [N]
 
-    match = _selector_match(ct, pod.sel_col, pod.sel_op, pod.sel_is_field,
-                            pod.sel_vals, pod.sel_num)  # [N, T, E]
-    used = pod.sel_op != NONE  # [T, E]
-    term_ok = jnp.all(match | ~used[None], axis=-1)  # [N, T]
-    term_nonempty = jnp.any(used, axis=-1)  # [T]
-    term_ok = term_ok & term_nonempty[None] & pod.sel_term_valid[None]
-    any_term = jnp.any(pod.sel_term_valid)
-    affinity_ok = jnp.where(any_term, jnp.any(term_ok, axis=-1), True)
-    return sel_ok & affinity_ok & pin_ok
+        match = _selector_match(ct, pod.sel_col, pod.sel_op,
+                                pod.sel_is_field, pod.sel_vals,
+                                pod.sel_num)  # [N, T, E]
+        used = pod.sel_op != NONE  # [T, E]
+        term_ok = jnp.all(match | ~used[None], axis=-1)  # [N, T]
+        term_nonempty = jnp.any(used, axis=-1)  # [T]
+        term_ok = term_ok & term_nonempty[None] & pod.sel_term_valid[None]
+        any_term = jnp.any(pod.sel_term_valid)
+        affinity_ok = jnp.where(any_term, jnp.any(term_ok, axis=-1), True)
+        return sel_ok & affinity_ok & pin_ok
 
 
 def node_ports(ct: ClusterTensors, pod: PodFeatures,

@@ -15,6 +15,7 @@ Reference algorithms:
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from kubernetes_tpu.ops import common as C
@@ -107,6 +108,7 @@ def balanced_allocation(ct: ClusterTensors, pod: PodFeatures) -> jnp.ndarray:
     return balanced_allocation_from_fractions(_requested_fractions(ct, pod))
 
 
+@jax.named_scope("node_affinity")
 def node_affinity_score(ct: ClusterTensors, pod: PodFeatures) -> jnp.ndarray:
     """Sum of weights of matching PreferredSchedulingTerms (raw; normalized by
     max across nodes at aggregation)."""

@@ -1723,10 +1723,16 @@ class Scheduler:
                             "slot_pack_terms",
                             self.mirror.slot_terms_s - terms_s0)
                 with span("pack", tr):
+                    full_s0 = self.mirror.pack_full_s
                     self.mirror.set_nominated(self.nominator.by_node())
                     spec = self.mirror.prepare_launch(
                         [qp.pod for qp in runnable],
                         self.config.batch_size)
+                    # the mirror's own view of this pack, reported just
+                    # before its parent: seconds on the rows the packed-row
+                    # cache did not serve (0.0 = every row a hit)
+                    self.flight.observe_view(
+                        "pack_full", self.mirror.pack_full_s - full_s0)
                 break
             except CapacityError as e:
                 if flush_pending is not None:
@@ -1900,7 +1906,7 @@ class Scheduler:
                 spec.enable_topology, spec.d_cap, spec.g_cap,
                 not use_auction, spec.dra is not None,
                 learned_params is not None, want_feats,
-                alts=want_alts, soft=spec.topo_soft)
+                alts=want_alts, soft=spec.topo_soft, active=spec.active)
             compiled = prof.note_launch(
                 pshape, len(runnable),
                 None if use_auction else scan_steps_for(

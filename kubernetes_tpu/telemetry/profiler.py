@@ -56,14 +56,19 @@ _CAP_FIELDS = ("nodes", "pods", "pod_labels", "node_labels", "domains",
 def shape_key(caps, b_bucket: int, enable_topology: bool, d_cap,
               g_cap: int, serial_scan: bool, dra: bool, learned: bool,
               with_feats: bool, gang: int = 0,
-              alts: bool = False, soft: bool = False) -> tuple:
+              alts: bool = False, soft: bool = False,
+              active: tuple[str, ...] = ()) -> tuple:
     """The launch's compile-relevant shape: static jit args + input
     shape buckets, as a flat hashable tuple. ``gang`` is the gang-pack
     launch's gang-row bucket (0 for the normal scheduling launch) — a
     gang-shape recompile attributes to its own row instead of landing
     in "unattributed". ``alts`` is the with_alts static flag (the
     export v3 top-K candidate kernels); ``soft`` is the topo_soft
-    static flag (the reduced soft-topology program, ISSUE 15)."""
+    static flag (the reduced soft-topology program);
+    ``active`` the launch features compiled into the program
+    (``LaunchSpec.active``: "nodeaffinity", "taints", ...), last, so a
+    key built without it is the older tuple with ("active", ())
+    appended."""
     cap_t = tuple((f, getattr(caps, f)) for f in _CAP_FIELDS
                   if hasattr(caps, f))
     return (("b", b_bucket), ("topo", bool(enable_topology)),
@@ -71,7 +76,7 @@ def shape_key(caps, b_bucket: int, enable_topology: bool, d_cap,
             ("serial", bool(serial_scan)), ("dra", bool(dra)),
             ("learned", bool(learned)), ("feats", bool(with_feats)),
             ("gang", gang), ("alts", bool(alts)), ("soft", bool(soft)),
-            *cap_t)
+            *cap_t, ("active", tuple(active)))
 
 
 def shape_label(shape: tuple) -> str:

@@ -140,6 +140,11 @@ LOOP_VIEW_PHASES = (
                           # pack), the mirror's own clock;
                           # reported with (and inside) mirror_sync once
                           # a sync, 0.0 when it packed no such slot
+    "pack_full",          # Mirror._pack_batch_np's rows the packed-row
+                          # cache did not serve (bypass and miss: the full
+                          # pack_pod), the mirror's own clock; reported
+                          # with (and inside) pack once a launch, 0.0 when
+                          # every row was a hit
 )
 
 # the dra_* attribution views, excluded from total/host-tail arithmetic

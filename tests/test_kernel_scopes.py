@@ -12,8 +12,13 @@ from kubernetes_tpu.api.objects import (
     LABEL_ZONE,
     Affinity,
     LabelSelector,
+    NodeAffinity,
+    NodeSelector,
+    NodeSelectorRequirement,
+    NodeSelectorTerm,
     PodAffinity,
     PodAffinityTerm,
+    PreferredSchedulingTerm,
 )
 
 from kubernetes_tpu.backend.mirror import _scatter_rows_jit
@@ -82,6 +87,26 @@ def _affinity_spec():
     return spec, mirror.well_known(), CAPS
 
 
+def _node_affinity_spec():
+    """Pods with a required zone nodeAffinity term: the launch compiles the
+    full node-affinity kernels (the `nodeaffinity` launch feature)."""
+    _cache, _snap, mirror = build_cluster(16, caps=CAPS)
+    pods = [make_pod(i) for i in range(8)]
+    for p in pods:
+        p.spec.affinity = Affinity(node_affinity=NodeAffinity(
+            required=NodeSelector(node_selector_terms=[NodeSelectorTerm(
+                match_expressions=[NodeSelectorRequirement(
+                    key=LABEL_ZONE, operator="In",
+                    values=["zone-0", "zone-1"])])]),
+            preferred=[PreferredSchedulingTerm(
+                weight=10, preference=NodeSelectorTerm(match_expressions=[
+                    NodeSelectorRequirement(key=LABEL_ZONE, operator="In",
+                                            values=["zone-1"])]))]))
+    spec = mirror.prepare_launch(pods, 8)
+    assert "nodeaffinity" in spec.active and not spec.enable_topology
+    return spec, mirror.well_known(), CAPS
+
+
 def _soft_spec():
     rng = random.Random(7)
     _table, _snap, mirror = build_soft(rng)
@@ -97,7 +122,8 @@ def _soft_spec():
 
 @pytest.mark.parametrize("make, serial_scan, scopes, absent", [
     (_plain_spec, False, ("static_filters", "auction_rounds"),
-     ("commit_scan", "soft_topology_auction", "table_block")),
+     ("commit_scan", "soft_topology_auction", "table_block",
+      "node_affinity")),
     (_plain_spec, True, ("static_filters", "commit_scan"),
      ("auction_rounds", "scan_queries", "scan_map_updates", "table_block")),
     (_soft_spec, False, ("static_filters", "soft_topology_auction",
@@ -106,6 +132,9 @@ def _soft_spec():
     (_affinity_spec, True, ("static_filters", "inter_pod_affinity",
                             "table_block", "commit_scan", "scan_queries",
                             "scan_map_updates"), ("auction_rounds",)),
+    (_node_affinity_spec, False, ("static_filters", "node_affinity",
+                                  "auction_rounds"),
+     ("commit_scan", "soft_topology_auction", "table_block")),
 ])
 def test_schedule_batch_kernels_carry_their_scope(make, serial_scan, scopes,
                                                   absent):
@@ -129,4 +158,4 @@ def test_chain_and_mirror_scatters_carry_their_scope():
     assert set(KERNEL_SCOPES) == {
         "static_filters", "auction_rounds", "soft_topology_auction",
         "commit_scan", "patch_chain", "scatter_rows", "inter_pod_affinity",
-        "scan_queries", "scan_map_updates", "table_block"}
+        "scan_queries", "scan_map_updates", "table_block", "node_affinity"}

@@ -550,6 +550,37 @@ def test_device_profiler_attributes_compiles():
     assert any(s["walltime_s"] == 0.5 for s in snap["shapes"])
 
 
+def test_device_profiler_tells_launches_apart_by_their_active_features():
+    """`active` is a static argument of the launch program: two launches
+    that differ only in it are two programs and two shapes. A key built
+    without it is the older tuple with ("active", ()) appended."""
+    from kubernetes_tpu.ops.features import Capacities
+    from kubernetes_tpu.telemetry.profiler import _CAP_FIELDS
+
+    caps = Capacities(nodes=8192, pods=262144)
+    plain = shape_key(caps, 4096, False, 0, 8, False, False, False, False)
+    older = (("b", 4096), ("topo", False), ("d_cap", 0), ("g_cap", 8),
+             ("serial", False), ("dra", False), ("learned", False),
+             ("feats", False), ("gang", 0), ("alts", False), ("soft", False),
+             *((f, getattr(caps, f)) for f in _CAP_FIELDS
+               if hasattr(caps, f)))
+    assert plain == older + (("active", ()),)
+    aff = shape_key(caps, 4096, False, 0, 8, False, False, False, False,
+                    active=("nodeaffinity",))
+    assert aff != plain and dict(aff)["active"] == ("nodeaffinity",)
+    sizes = [1]
+    prof = DeviceProfiler(cache_size_fn=lambda: sizes[0], now=lambda: 0.0)
+    prof.note_launch(plain, 4096)
+    sizes[0] = 2
+    assert prof.note_launch(aff, 4096) is True
+    prof.note_launch(aff, 4000)
+    assert len(prof.shapes) == 2
+    assert (prof.shapes[aff]["launches"], prof.shapes[aff]["pods"]) \
+        == (2, 8096)
+    assert prof.compile_causes == {"flags": 1}
+    assert prof.compile_events[-1]["shape"]["active"] == ("nodeaffinity",)
+
+
 @pytest.mark.parametrize("d_cap, serial, soft, label", [
     # the zone scan of the spread and affinity cells, the hostname scan of
     # the anti-affinity cell, a soft-only topology launch, an auction
