@@ -20,6 +20,7 @@ of them in-flight with concurrent-event replay per pod.
 
 from __future__ import annotations
 
+import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -153,6 +154,10 @@ class PriorityQueue:
         # caller batches requeue reaction across a burst (an eviction
         # flush's multi-delete wave) — see coalescing()
         self._coalesce: Optional[list] = None
+        # set whenever a pod enters the activeQ, from any thread (the
+        # reference's cond.Broadcast on add): the scheduling loop clears
+        # it before a drain and waits on it when the drain found nothing
+        self.wake = threading.Event()
 
     # ------------- backoff (backoff_queue.go:248) -------------
 
@@ -235,6 +240,8 @@ class PriorityQueue:
             self._active.add(qp)
             self._pop_parked(qp.uid)
             self._backoff.delete(qp.uid)
+            if not self.wake.is_set():
+                self.wake.set()
         else:
             qp.gated_plugin = s.plugin
             qp.unschedulable_plugins.add(s.plugin)

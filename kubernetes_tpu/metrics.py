@@ -485,6 +485,11 @@ class SchedulerMetrics:
             "scheduler_cycle_crashes_total",
             "Scheduling-loop exceptions survived by the daemon "
             "keep-alive (each backs the loop off before retrying)"))
+        self.loop_idle_waits = r.register(Counter(
+            "scheduler_loop_idle_waits_total",
+            "Idle waits of the scheduling loop (a drain that found no pod) "
+            "by how they ended: event (a pod entered the activeQ, or "
+            "stop()) or timeout (the idle sleep ran out)", ("end",)))
         self.condition_patches_dropped = r.register(Counter(
             "scheduler_condition_patches_dropped_total",
             "Pod condition patches dropped (degraded mode or fenced) "

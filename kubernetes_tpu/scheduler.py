@@ -3775,9 +3775,19 @@ class Scheduler:
         informer state warm but mutates nothing. Exceptions are logged and
         retained (daemon_error); the loop backs off with decorrelated
         jitter (a persistent error must not busy-spin the keep-alive)
-        and keeps serving."""
+        and keeps serving.
+
+        A drain that finds no pod waits on the queue's wake event (set
+        when a pod enters the activeQ, or by ``stop()``), at most
+        ``idle_sleep``, so the maintenance timers still tick; each such
+        wait is counted by how it ended. Events that put no pod in the
+        activeQ (node heartbeats, bind echoes) leave it alone. The event
+        is cleared before the drain, so a pod that arrives between an
+        empty drain and the wait ends the wait."""
         self.daemon_error: Optional[BaseException] = None
         self._elector = elector
+        wake = self.queue.wake
+        idle_waits = self.metrics.loop_idle_waits
         # a SliceManager is the scale-out elector: leadership over a
         # SLICE of the pending-pod space instead of the whole ring
         self._slices = (elector if getattr(elector, "is_slice_manager",
@@ -3809,9 +3819,11 @@ class Scheduler:
                     # the lease while still binding pods)
                     on_step = (None if elector is None
                                else (lambda: not tick_gate()))
+                    wake.clear()
                     if self.run_until_idle(on_step=on_step) == 0:
                         with span("idle_wait"):
-                            stop.wait(idle_sleep)
+                            woke = wake.wait(idle_sleep)
+                            idle_waits.inc(end="event" if woke else "timeout")
                     crash_bo.reset()
                 except Exception as e:  # noqa: BLE001 — keep daemon alive
                     logger.exception("scheduling loop error: %s", e)
@@ -3838,6 +3850,7 @@ class Scheduler:
         if self._daemon is None:
             return
         self._stop.set()
+        self.queue.wake.set()
         self._daemon.join(timeout=30)
         self._daemon = None
         self._stop = None

@@ -124,6 +124,11 @@ class ServingEndpoints:
                         # the queue's in-flight event log: entries,
                         # high-water, trims that scanned and their seconds
                         "queue": sched.queue.trim_stats(),
+                        # the loop's idle waits by how they ended: a pod
+                        # event woke it, or the idle sleep ran out
+                        "idle_waits": {
+                            end: sched.metrics.loop_idle_waits.value(end=end)
+                            for end in ("event", "timeout")},
                         # the mirror's packed-row cache: pods packed
                         # = hits + misses + bypass, clears at its bound
                         "pack_row_cache": sched.mirror.row_cache_stats(),

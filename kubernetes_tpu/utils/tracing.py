@@ -115,7 +115,8 @@ CYCLE_PHASES = (
 # thread's wall time. Left out of cycle totals and the host-tail share,
 # so no headline changed its meaning when they arrived.
 LOOP_PHASES = (
-    "idle_wait",          # stop.wait(idle_sleep) and the elector's wait
+    "idle_wait",          # the wake event's wait (at most idle_sleep),
+                          # the elector's wait and the crash backoff
     "maintenance",        # run_maintenance
     "lock_wait",          # acquiring the scheduler lock for a drain
     "event_intake",       # deferred informer events, the Permit wait

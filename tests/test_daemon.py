@@ -3,8 +3,12 @@ cycle (reference: scheduler.go Run, scheduling_queue.go:378-386 flush
 goroutines, runtime/waiting_pods_map.go, schedule_one.go:124/270 binding
 goroutine + :337 bind-failure requeue)."""
 
+import copy
+import functools
 import threading
 import time
+
+import pytest
 
 from kubernetes_tpu.api.objects import (
     Container,
@@ -218,6 +222,15 @@ def test_unschedulable_timeout_flush_without_events():
     assert counts["active"] + counts["backoff"] == 1
 
 
+def wait_for(cond, seconds):
+    deadline = time.monotonic() + seconds
+    while time.monotonic() < deadline:
+        if cond():
+            return True
+        time.sleep(0.02)
+    return bool(cond())
+
+
 def test_daemon_thread_schedules_and_stops():
     """start()/stop(): pods created from a foreign thread while the daemon
     runs are scheduled without explicit drains."""
@@ -231,17 +244,180 @@ def test_daemon_thread_schedules_and_stops():
         pods = [mkpod(f"p{i}") for i in range(10)]
         for p in pods:
             hub.create_pod(p)
-        deadline = time.time() + 30
-        while time.time() < deadline:
-            if all(bound_node(hub, p) for p in pods):
-                break
-            time.sleep(0.02)
-        assert all(bound_node(hub, p) for p in pods)
+        assert wait_for(lambda: all(bound_node(hub, p) for p in pods), 30)
     finally:
         sched.stop()
     assert sched._daemon is None
 
 
+def idle_daemon(monkeypatch, idle_sleep):
+    """A warmed scheduler over one node whose start() runs the daemon with
+    ``idle_sleep``. Returns (hub, sched, idle, hooks): ``idle`` is set
+    after each drain that found no pod, and each callable put in ``hooks``
+    runs once on the loop thread right there, between that drain and the
+    idle wait."""
+    hub = Hub()
+    cfg = default_config()
+    cfg.batch_size = 16
+    sched = Scheduler(hub, cfg, caps=Capacities(nodes=16, pods=64))
+    hub.create_node(mknode(0))
+    warm = mkpod("warm")
+    hub.create_pod(warm)
+    sched.run_until_idle()                  # the compile, before the daemon
+    assert bound_node(hub, warm) == "node-0"
+    idle = threading.Event()
+    hooks = []
+    drain = sched.run_until_idle
+
+    def run_until_idle(**kw):
+        n = drain(**kw)
+        if n == 0:
+            while hooks:
+                hooks.pop(0)()
+            idle.set()
+        return n
+
+    monkeypatch.setattr(sched, "run_until_idle", run_until_idle)
+    monkeypatch.setattr(sched, "run", functools.partial(
+        Scheduler.run, sched, idle_sleep=idle_sleep))
+    return hub, sched, idle, hooks
+
+
+def idle_wait_ends(sched):
+    waits = sched.metrics.loop_idle_waits
+    return {end: waits.value(end=end) for end in ("event", "timeout")}
+
+
+def test_an_idle_daemon_binds_a_pod_created_from_another_thread_at_once(
+        monkeypatch):
+    """A pod created while the loop sleeps ends the sleep: it binds well
+    inside an idle_sleep of 5 s, where a polling loop would wait it out."""
+    hub, sched, idle, _ = idle_daemon(monkeypatch, idle_sleep=5.0)
+    sched.start()
+    try:
+        assert idle.wait(30)
+        p = mkpod("p")
+        t0 = time.monotonic()
+        hub.create_pod(p)
+        assert wait_for(lambda: bound_node(hub, p), 30)
+        assert time.monotonic() - t0 < 2.5
+        assert idle_wait_ends(sched)["event"] >= 1
+    finally:
+        sched.stop()
+
+
+def test_an_event_between_the_empty_drain_and_the_wait_is_not_lost(
+        monkeypatch):
+    """The wake event is cleared before the drain, not after it: a pod
+    created by another thread after the drain found nothing and before
+    the loop waits ends that wait at once."""
+    hub, sched, _idle, hooks = idle_daemon(monkeypatch, idle_sleep=5.0)
+    p = mkpod("p")
+    created = []
+
+    def create_from_another_thread():
+        t = threading.Thread(target=hub.create_pod, args=(p,))
+        t.start()
+        t.join()
+        created.append(time.monotonic())
+
+    hooks.append(create_from_another_thread)
+    sched.start()
+    try:
+        assert wait_for(lambda: bound_node(hub, p), 30)
+        assert time.monotonic() - created[0] < 2.5
+        assert idle_wait_ends(sched) == {"event": 1, "timeout": 0}
+    finally:
+        sched.stop()
+
+
+def test_a_binder_workers_event_does_not_end_the_wait(monkeypatch):
+    """An event raised on a binder worker is deferred, not applied: no
+    pod enters the activeQ until the loop replays it, so the wait runs
+    out and the next turn schedules the pod."""
+    hub, sched, _idle, hooks = idle_daemon(monkeypatch, idle_sleep=1.0)
+    p = mkpod("p")
+    hooks.append(lambda: sched._binder.submit(hub.create_pod, p).result())
+    sched.start()
+    try:
+        assert wait_for(lambda: bound_node(hub, p), 30)
+        ends = idle_wait_ends(sched)
+        assert ends["event"] == 0
+        assert ends["timeout"] >= 1
+    finally:
+        sched.stop()
+
+
+def test_node_updates_from_another_thread_do_not_end_the_wait(monkeypatch):
+    """A stream of node heartbeats puts no pod in the activeQ: the idle
+    loop keeps turning once an idle_sleep, not once an event."""
+    hub, sched, idle, _ = idle_daemon(monkeypatch, idle_sleep=0.5)
+    sched.start()
+    try:
+        assert idle.wait(30)
+        turn0, t0 = sched.flight.turn, time.monotonic()
+
+        def heartbeats():
+            for i in range(150):
+                node = copy.deepcopy(hub.get_node("node-0"))
+                node.metadata.labels["heartbeat"] = str(i)
+                hub.update_node(node)
+                time.sleep(0.01)
+
+        t = threading.Thread(target=heartbeats)
+        t.start()
+        t.join()
+        turns = sched.flight.turn - turn0
+        seconds = time.monotonic() - t0
+    finally:
+        sched.stop()
+    assert seconds >= 1.5
+    assert idle_wait_ends(sched)["event"] == 1          # stop() alone
+    assert turns <= seconds / 0.5 + 2, (turns, seconds)
+
+
+def test_stop_ends_a_long_idle_wait_promptly(monkeypatch):
+    _hub, sched, idle, _ = idle_daemon(monkeypatch, idle_sleep=60.0)
+    sched.start()
+    assert idle.wait(30)
+    t0 = time.monotonic()
+    sched.stop()
+    assert time.monotonic() - t0 < 5.0
+    assert sched._daemon is None
+    assert idle_wait_ends(sched)["event"] >= 1
+
+
+def test_the_idle_wait_counter_counts_how_each_wait_ended(monkeypatch):
+    """scheduler_loop_idle_waits_total{end} equals the waits as they
+    ended: one ran out, a pod event ended one, stop() ended the last."""
+    from kubernetes_tpu.telemetry.fleet import parse_exposition
+
+    hub, sched, _idle, _hooks = idle_daemon(monkeypatch, idle_sleep=1.0)
+    ends = []
+    wait = sched.queue.wake.wait
+
+    def recording_wait(timeout=None):
+        woke = wait(timeout)
+        ends.append("event" if woke else "timeout")
+        return woke
+
+    monkeypatch.setattr(sched.queue.wake, "wait", recording_wait)
+    sched.start()
+    try:
+        assert wait_for(lambda: "timeout" in ends, 30)
+        p = mkpod("p")
+        hub.create_pod(p)
+        assert wait_for(lambda: bound_node(hub, p), 30)
+    finally:
+        sched.stop()
+    want = {"event": ends.count("event"), "timeout": ends.count("timeout")}
+    assert want["event"] >= 2 and want["timeout"] >= 1
+    assert idle_wait_ends(sched) == want
+    exp = parse_exposition(sched.metrics.registry.render_text())
+    assert {s.labels["end"]: s.value for s in exp.samples
+            if s.name == "scheduler_loop_idle_waits_total"} == want
+    assert exp.type["scheduler_loop_idle_waits_total"] == "counter"
+
+
 # suite-tier discipline (tests/test_markers.py): area marker
-import pytest  # noqa: E402
 pytestmark = pytest.mark.core

@@ -3,6 +3,7 @@
 queueing hints, in-flight event replay, gates, flush timers. Virtual clock
 throughout (the reference uses testingclock the same way)."""
 
+import dataclasses
 import random
 from collections import deque
 
@@ -398,6 +399,72 @@ def test_done_examines_each_entry_a_bounded_number_of_times():
     assert q.trim_calls == pods_n and q.events_high_water == events_n
     examined = q._events.examined + q._starts.examined
     assert examined <= 6 * (events_n + pods_n), examined
+
+
+def park(q, pod, plugins=("NodeResourcesFit",), count=1):
+    """Add, pop and fail ``pod``: it ends in the unschedulable pool."""
+    q.add(pod)
+    qp = q.pop()
+    qp.unschedulable_count = count
+    qp.unschedulable_plugins = set(plugins)
+    q.add_unschedulable_if_not_present(qp)
+    assert q.pending_counts()["unschedulable"] == 1
+    return qp
+
+
+@pytest.mark.parametrize("path", ["add", "activate", "event_requeue",
+                                  "backoff_flush", "gate_lifted"])
+def test_every_path_into_the_activeq_sets_the_wake_event(path):
+    """The scheduling loop's idle wait ends on q.wake: every way a pod
+    becomes poppable sets it."""
+    hints = {"NodeResourcesFit": [ClusterEventWithHint(NODE_ADD)]}
+    q, clock = mkq(hints=hints)
+    p = mkpod("p", gates=("g",) if path == "gate_lifted" else ())
+    if path == "activate":
+        park(q, p)
+    elif path == "event_requeue":
+        park(q, p, count=0)             # no backoff: straight to active
+    elif path == "backoff_flush":
+        park(q, p)
+        q.move_all_to_active_or_backoff(NODE_ADD)
+        assert q.pending_counts()["backoff"] == 1
+        clock.tick(1.1)
+    elif path == "gate_lifted":
+        q.add(p)
+        assert q.pending_counts()["gated"] == 1
+    q.wake.clear()
+    if path == "add":
+        q.add(p)
+    elif path == "activate":
+        q.activate([p])
+    elif path == "event_requeue":
+        assert q.move_all_to_active_or_backoff(NODE_ADD) == 1
+    elif path == "backoff_flush":
+        assert q.flush_backoff_completed() == 1
+    else:
+        q.update(p, dataclasses.replace(p, spec=PodSpec()))
+    assert q.pending_counts()["active"] == 1
+    assert q.wake.is_set()
+
+
+def test_what_puts_no_pod_in_the_activeq_leaves_the_wake_event_clear():
+    """A gated add, a park, an unrelated event, a move to backoff, an
+    update of a queued pod and a delete leave q.wake as it was."""
+    hints = {"NodeResourcesFit": [ClusterEventWithHint(NODE_ADD)]}
+    q, clock = mkq(hints=hints)
+    q.add(mkpod("gated", gates=("g",)))
+    assert not q.wake.is_set()
+    p = mkpod("p")
+    park(q, p)
+    q.wake.clear()
+    q.move_all_to_active_or_backoff(POD_DELETE)
+    assert not q.wake.is_set()
+    q.move_all_to_active_or_backoff(NODE_ADD)
+    assert q.pending_counts()["backoff"] == 1
+    q.update(p, dataclasses.replace(p))
+    q.delete(p)
+    assert q.flush_backoff_completed() == 0
+    assert len(q) == 1 and not q.wake.is_set()
 
 
 # suite-tier discipline (tests/test_markers.py): area marker

@@ -407,6 +407,8 @@ def test_debug_trace_and_pod_endpoints_authz():
             assert len(tr["cycles"]) <= 4
             assert "commit" in tr["phases"]
             assert 0.0 <= tr["host_tail_share"] <= 1.0
+            # no daemon ran: the loop's idle waits are both zero
+            assert tr["idle_waits"] == {"event": 0.0, "timeout": 0.0}
 
             pd = json.loads(_get(f"{base}/debug/pod?name=p0",
                                  token="s3cret").read())
