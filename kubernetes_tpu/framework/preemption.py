@@ -58,10 +58,12 @@ MAX_VERIFY_CANDIDATES = 8
 MAX_REPRIEVE_STEPS = 16
 
 # Evaluator.sweep_stats()'s keys: the pods swept, the four outcomes they
-# add up to, the victim state's two refresh paths, stranded preemptors
+# add up to, the victim state's two refresh paths, stranded preemptors,
+# and the host kmins a burst computed against those it reused
 SWEEP_STATS = ("preemptors_swept", "nominated", "no_candidates",
                "ineligible", "extender_errors", "victim_state_rebuilds",
-               "victim_state_updates", "stranded_activations")
+               "victim_state_updates", "stranded_activations",
+               "kmin_computed", "kmin_reused")
 
 
 @dataclass
@@ -157,7 +159,10 @@ class Evaluator:
         against its incremental one); stranded_activations (preemptors
         a flush activated because their plan fired no deletion of its
         own: victims already gone or claimed by an earlier plan, or a
-        gang eviction blocked)."""
+        gang eviction blocked); kmin_computed + kmin_reused = the eligible
+        preemptors that reached finish_batch_preempt (one kmin computed a
+        distinct request and own nominated row of a burst, the rest
+        reused)."""
         return dict(self._sweep)
 
     # ---------------- eligibility (default_preemption.go:327) -------------
@@ -1287,9 +1292,11 @@ class Evaluator:
         """[N] i32 minimal victim-prefix making ``pod`` fit per node,
         NONE where eviction cannot help — the HOST evaluation of
         ops.preempt.preempt_sweep's resource half over the incremental
-        cumsum. Runs in ~2ms of numpy: a device sweep here would queue
-        behind the drain's in-flight launches and cost 100-1000ms of
-        wall per burst (measured), pure numpy never touches the device.
+        cumsum. A few ms of numpy at 8,192 nodes, so finish_batch_preempt
+        runs it once per distinct (request, own nominated row) of a
+        burst: a device sweep here would queue behind the drain's
+        in-flight launches and cost 100-1000ms of wall per burst
+        (measured), pure numpy never touches the device.
         Static filters are NOT folded in — the caller checks them lazily
         on visited window rows via _host_static_ok."""
         cumsum = st["cumsum_host"]                    # [N, K+1, C_pad]
@@ -1338,9 +1345,27 @@ class Evaluator:
             if not mirror.holds_nomination(uid)
             and self.nominator.node_of(uid) is not None}
         used_rows: set[int] = set(self._unreserved.values())
+        # kmin reads only the request row and the row of the pod's own
+        # nomination (handed back to base): free_mat, the victim state and
+        # the nominated overlay stay fixed for this call, and the burst's
+        # own nominations act later, through used_rows. So preemptors of
+        # equal requests share one read-only kmin and its rows; the memo
+        # dies with the call, since free_mat and the overlay move between
+        # calls. Everything after it stays per pod, in order.
+        kmins: dict[tuple[bytes, int], tuple[np.ndarray, np.ndarray]] = {}
         for qp in eligible:
-            kmin = self._host_kmin(qp.pod, st, mirror, free_mat)
-            rows = np.nonzero((kmin != NONE) & (kmin >= 1))[0]
+            nnn = qp.pod.status.nominated_node_name
+            key = (self._res_row_cached(qp.pod).tobytes(),
+                   mirror.row_of(nnn) if nnn else -1)
+            memo = kmins.get(key)
+            if memo is None:
+                kmin = self._host_kmin(qp.pod, st, mirror, free_mat)
+                memo = kmins[key] = (
+                    kmin, np.nonzero((kmin != NONE) & (kmin >= 1))[0])
+                self._sweep["kmin_computed"] += 1
+            else:
+                self._sweep["kmin_reused"] += 1
+            kmin, rows = memo
             window: list[tuple[int, int]] = []
             if len(rows):
                 off = self._rng.randrange(len(rows))

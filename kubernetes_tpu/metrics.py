@@ -298,6 +298,12 @@ class SchedulerMetrics:
             "Preemptors an eviction flush activated because their plan "
             "fired no deletion of its own (victims already gone or "
             "claimed by an earlier plan, or a gang eviction blocked)"))
+        self.preemption_kmin = r.register(Counter(
+            "scheduler_preemption_kmin_total",
+            "Host kmin passes of the batched preemption sweep by result: "
+            "computed (one per distinct request and own nominated node of "
+            "a burst) or reused (a preemptor whose equal was computed "
+            "earlier in the same burst)", ("result",)))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

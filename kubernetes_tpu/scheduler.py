@@ -3724,6 +3724,10 @@ class Scheduler:
         self._mirror_count("preemption_stranded",
                            sweep["stranded_activations"],
                            m.preemption_stranded)
+        for result in ("computed", "reused"):
+            self._mirror_count(f"preemption_kmin:{result}",
+                               sweep[f"kmin_{result}"], m.preemption_kmin,
+                               result=result)
         self._mirror_journal_stats()
         if self.jobqueue.active:
             for tenant, st in self.jobqueue.tenant_stats().items():

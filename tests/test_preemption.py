@@ -471,3 +471,149 @@ def test_a_sweep_sees_the_nominations_of_the_sweeps_before_it():
     assert [node for node, _s in second.values()] == [None]
     assert sched.preemption.sweep_stats()["no_candidates"] == 1
     sched.close()
+
+
+def _finish_one_kmin_a_preemptor(ev, handle, rng):
+    """finish_batch_preempt's decisions with _host_kmin computed once per
+    preemptor (no memo), drawing from ``rng``: {uid: (node | None,
+    Status, victim uids)}. Writes nothing of the evaluator's state."""
+    import numpy as np
+
+    from kubernetes_tpu.framework.interface import Status
+    from kubernetes_tpu.framework.preemption import (
+        MAX_VERIFY_CANDIDATES,
+        Candidate,
+        Evaluator,
+    )
+    from kubernetes_tpu.utils.interner import NONE
+
+    eligible, victims_by_row, st, mirror, snapshot = handle
+    ev.cache_snapshot = snapshot.node_info_map
+    free_mat = mirror.free_matrix()
+    pdbs = ev.hub.list_pdbs()
+    used_rows = {row for uid, row in ev._unreserved.items()
+                 if not mirror.holds_nomination(uid)
+                 and ev.nominator.node_of(uid) is not None}
+    out = {}
+    for qp in eligible:
+        kmin = ev._host_kmin(qp.pod, st, mirror, free_mat)
+        rows = np.nonzero((kmin != NONE) & (kmin >= 1))[0]
+        window = []
+        if len(rows):
+            off = rng.randrange(len(rows))
+            for i in range(len(rows)):
+                row = int(rows[(off + i) % len(rows)])
+                vs = victims_by_row.get(row)
+                k = int(kmin[row])
+                if (vs is None or row in used_rows or k > len(vs)
+                        or not ev._host_static_ok(
+                            qp.pod, mirror.name_of_row(row) or "")):
+                    continue
+                window.append((row, k))
+                if len(window) >= MAX_VERIFY_CANDIDATES:
+                    break
+        candidates = []
+        for row, k in window:
+            vs = ev._reprieve_by_resources(
+                [pi.pod for pi in victims_by_row[row][:k]], qp.pod, row,
+                free_mat)
+            candidates.append(Candidate(
+                node_name=mirror.name_of_row(row) or "", row=row,
+                victims=vs, pdb_violations=ev._pdb_violations(vs, pdbs)))
+        if not candidates:
+            out[qp.uid] = (None, Status.unschedulable(
+                "no preemption candidates", plugin="DefaultPreemption"), [])
+            continue
+        best = Evaluator.select_candidate(candidates)
+        used_rows.add(best.row)
+        out[qp.uid] = (best.node_name, Status(),
+                       [v.metadata.uid for v in best.victims])
+    return out
+
+
+@pytest.mark.parametrize("seed", [4200000101, 4200000202, 4200000303])
+def test_one_kmin_a_request_decides_as_one_kmin_a_preemptor(seed):
+    """finish_batch_preempt computes one kmin per distinct (request row,
+    own nominated row) of a burst and reuses it for the preemptors equal to
+    it. Over a seeded cluster, with equal requests, two requests of one
+    priority, a preemptor that carries a nomination (its own request handed
+    back to its row) and a non-empty nominated overlay, its nominations,
+    statuses, victims and rotation draws are those of a loop that computes
+    kmin once per preemptor."""
+    import random
+    import types
+
+    rng = random.Random(seed)
+    hub = Hub()
+    cfg = default_config()
+    cfg.batch_size = 16
+    clock = Clock()
+    sched = Scheduler(hub, cfg, caps=Capacities(nodes=16, pods=128),
+                      now=clock.now)
+    try:
+        for i in range(12):
+            hub.create_node(mknode(i, cpu="4"))
+            # fillers of mixed size and priority: some nodes hold pods the
+            # preemptors may not evict, so candidates differ by node
+            for j in range(4):
+                p = mkpod(f"fill-{i}-{j}",
+                          cpu=rng.choice(("700m", "900m", "950m")),
+                          priority=rng.choice((0, 0, 1, 5, 20)))
+                p.spec.node_name = f"node-{i}"
+                hub.create_pod(p)
+        drain(sched, clock)
+
+        def job(name, cpu, nominated=""):
+            pod = mkpod(name, cpu=cpu, priority=10)
+            hub.create_pod(pod)
+            if nominated:
+                pod.status.nominated_node_name = nominated
+                sched.nominator.add(pod, nominated)
+            return types.SimpleNamespace(pod=pod, uid=pod.metadata.uid)
+
+        # a parked pod's nomination: the overlay reserves it on its node
+        parked = mkpod("parked", cpu="1000m", priority=10)
+        hub.create_pod(parked)
+        sched.nominator.add(parked, f"node-{rng.randrange(12)}")
+        kinds = ["3000m"] * 5 + ["2000m"] * 3
+        rng.shuffle(kinds)
+        jobs = [job(f"high-{j}", cpu) for j, cpu in enumerate(kinds)]
+        # first, so that the plain 3000m preemptors after it would read its
+        # handed-back row if the key left the own row out
+        jobs.insert(0, job("renominated", "3000m",
+                           nominated=f"node-{rng.randrange(12)}"))
+        sched.cache.update_snapshot(sched.snapshot)
+        sched.mirror.sync(sched.snapshot)
+        ev = sched.preemption
+
+        def sweep(jobs, keys):
+            sched.mirror.set_nominated(sched.nominator.by_node())
+            assert sched.mirror._nominated_req_of_row
+            handle, immediate = ev.begin_batch_preempt(jobs, sched.snapshot)
+            assert handle is not None and not immediate
+            ref_rng = random.Random()
+            ref_rng.setstate(ev._rng.getstate())
+            want = _finish_one_kmin_a_preemptor(ev, handle, ref_rng)
+            before = ev.sweep_stats()
+            ev._pending.clear()
+            got = ev.finish_batch_preempt(handle)
+            victims = {pod.metadata.uid: [v.metadata.uid for v in c.victims]
+                       for c, pod in ev._pending}
+            assert {uid: (node, status, victims.get(uid, []))
+                    for uid, (node, status) in got.items()} == want
+            assert ev._rng.getstate() == ref_rng.getstate()
+            after = ev.sweep_stats()
+            assert after["kmin_computed"] - before["kmin_computed"] == keys
+            assert after["kmin_reused"] - before["kmin_reused"] \
+                == len(jobs) - keys
+            return want
+
+        # three keys: 3000m, 2000m, 3000m with its own row handed back
+        first = sweep(jobs, 3)
+        assert any(node for node, _s, _v in first.values())
+        # the next burst sweeps against an overlay that now reserves the
+        # first one's nominations: nothing of the first call's kmins holds
+        again = [job(f"again-{j}", cpu) for j, cpu in enumerate(kinds)]
+        sweep(again, 2)
+    finally:
+        sched.close()

@@ -1015,6 +1015,10 @@ def test_preemption_sweep_stats_add_up_and_reach_the_registry():
                                "extender_errors"))
         assert sweep["victim_state_rebuilds"] >= 1
         assert sweep["stranded_activations"] == 0
+        # every eligible preemptor that found victims reached the kmin memo
+        assert sweep["kmin_computed"] >= 1
+        assert sweep["kmin_computed"] + sweep["kmin_reused"] \
+            >= sweep["nominated"]
         m = sched.metrics
         for result in ("nominated", "no_candidates", "ineligible",
                        "extender_errors"):
@@ -1023,6 +1027,9 @@ def test_preemption_sweep_stats_add_up_and_reach_the_registry():
             == sweep["victim_state_rebuilds"]
         assert m.preemption_victim_state.value(path="update") \
             == sweep["victim_state_updates"]
+        for result in ("computed", "reused"):
+            assert m.preemption_kmin.value(result=result) \
+                == sweep[f"kmin_{result}"]
         text = m.registry.render_text()
         assert ('scheduler_preemption_sweep_total{result="nominated"} 2'
                 in text)
@@ -1038,5 +1045,54 @@ def test_preemption_sweep_stats_add_up_and_reach_the_registry():
         finally:
             srv.stop()
         assert tr["preemption"] == sched.preemption.sweep_stats()
+    finally:
+        sched.close()
+        hub.close()
+
+
+def test_preemption_sweep_computes_one_kmin_a_distinct_request():
+    """A burst of ten equal preemptors computes one host kmin and reuses it
+    nine times; a burst of all-different requests reuses none. Both
+    results reach the registry at maintenance as
+    scheduler_preemption_kmin_total{result="computed|reused"}."""
+    import types
+
+    from tests.test_tracing import _sched, mkpod, preemption_cluster
+
+    hub = Hub()
+    sched = _sched(hub)
+    try:
+        preemption_cluster(hub, nodes=16)
+        sched.run_until_idle()
+        sched.cache.update_snapshot(sched.snapshot)
+        sched.mirror.sync(sched.snapshot)
+        ev = sched.preemption
+
+        def burst(cpus):
+            jobs = []
+            for cpu in cpus:
+                pod = mkpod(f"high-{len(hub.list_pods())}", cpu=cpu)
+                pod.spec.priority = 10
+                hub.create_pod(pod)
+                jobs.append(types.SimpleNamespace(pod=pod,
+                                                  uid=pod.metadata.uid))
+            before = ev.sweep_stats()
+            out = ev.batch_preempt(jobs, sched.snapshot)
+            after = ev.sweep_stats()
+            return out, {k: after[k] - before[k]
+                         for k in ("kmin_computed", "kmin_reused")}
+
+        out, kmin = burst(["3000m"] * 10)
+        assert kmin == {"kmin_computed": 1, "kmin_reused": 9}
+        assert all(node for node, _s in out.values())
+        out, kmin = burst(["2600m", "2800m", "3000m", "3200m"])
+        assert kmin == {"kmin_computed": 4, "kmin_reused": 0}
+        sched.run_maintenance()
+        m = sched.metrics
+        assert m.preemption_kmin.value(result="computed") == 5
+        assert m.preemption_kmin.value(result="reused") == 9
+        text = m.registry.render_text()
+        assert 'scheduler_preemption_kmin_total{result="computed"} 5' in text
+        assert 'scheduler_preemption_kmin_total{result="reused"} 9' in text
     finally:
         sched.close()
