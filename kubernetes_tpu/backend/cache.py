@@ -25,6 +25,10 @@ from kubernetes_tpu.backend.node_tree import NodeTree
 from kubernetes_tpu.backend.snapshot import Snapshot
 from kubernetes_tpu.storage import RvTooOld
 
+# Cache.snapshot_stats()'s keys: update_snapshot's three paths, and the
+# NodeInfos its refreshes re-cloned
+SNAPSHOT_STATS = ("noop", "delta", "full", "cloned")
+
 
 @dataclass
 class _PodState:
@@ -103,6 +107,7 @@ class Cache:
         # bumped when the set of nodes (or node-less nodeinfos) changes, so
         # update_snapshot's no-change fast path can skip the removal scan
         self._node_set_version = 0
+        self._snapshot_stats = dict.fromkeys(SNAPSHOT_STATS, 0)
 
     # ---------------- internal list maintenance ----------------
 
@@ -334,8 +339,10 @@ class Cache:
     def update_snapshot(self, snapshot: Snapshot) -> None:
         """Incremental refresh: walk the MRU list head-first, cloning only
         NodeInfos newer than the snapshot's generation (cache.go:186-280).
-        Rebuilds the zone-interleaved list only when nodes were added/removed
-        or an affinity-relevant change occurred, like the reference."""
+        While the node set stands, the re-clones are written into a copy of
+        the list at their positions, so the refresh costs what changed;
+        an added, updated or removed node rebuilds the zone-interleaved
+        list."""
         with self._lock:
             # no-change fast path: the MRU head carries the max generation,
             # so a clean cache makes the whole refresh O(1) — _ensure_synced
@@ -344,9 +351,9 @@ class Cache:
                  or self._head.info.generation <= snapshot.generation)
                     and snapshot.node_set_version == self._node_set_version
                     and snapshot.ns_generation == self._ns_generation):
+                self._snapshot_stats["noop"] += 1
                 return
             snap_gen = snapshot.generation
-            updated_affinity = False
             changed: Optional[list[str]] = []
             item = self._head
             latest = snap_gen
@@ -354,43 +361,46 @@ class Cache:
                 info = item.info
                 latest = max(latest, info.generation)
                 if info.node is not None:
-                    existing = snapshot.node_info_map.get(info.name)
-                    clone = info.snapshot()
-                    if existing is None:
+                    name = info.name
+                    if name not in snapshot.node_info_map:
                         changed = None
                     elif changed is not None:
-                        changed.append(info.name)
-                    if existing is None or bool(existing.pods_with_affinity) != bool(
-                        clone.pods_with_affinity
-                    ) or bool(existing.pods_with_required_anti_affinity) != bool(
-                        clone.pods_with_required_anti_affinity
-                    ):
-                        updated_affinity = True
-                    snapshot.node_info_map[info.name] = clone
+                        changed.append(name)
+                    snapshot.node_info_map[name] = info.snapshot()
+                    self._snapshot_stats["cloned"] += 1
                 item = item.next
-
-            # removals: any snapshot node no longer in the cache (or node-less)
-            live = {name for name, it in self._nodes.items() if it.info.node is not None}
-            removed = [n for n in snapshot.node_info_map if n not in live]
-            for n in removed:
-                del snapshot.node_info_map[n]
 
             if snapshot.ns_generation != self._ns_generation:
                 snapshot.namespaces = {n: dict(l)
                                        for n, l in self._namespaces.items()}
                 snapshot.ns_generation = self._ns_generation
 
-            if removed or len(snapshot.node_info_list) != len(live) or updated_affinity:
-                self._rebuild_lists(snapshot)
+            if (changed is not None
+                    and snapshot.node_set_version == self._node_set_version):
+                # a node add or removal bumps _node_set_version: none here.
+                # A copy, so a reader holding the last list keeps it whole
+                self._snapshot_stats["delta"] += 1
+                if changed:
+                    lst = snapshot.node_info_list.copy()
+                    for name in changed:
+                        lst[snapshot.node_index[name]] = \
+                            snapshot.node_info_map[name]
+                    snapshot.node_info_list = lst
             else:
-                # same node set: refresh list entries in place from the map
-                snapshot.node_info_list = [
-                    snapshot.node_info_map[ni.name] for ni in snapshot.node_info_list
-                ]
-                self._rebuild_affinity_lists(snapshot)
+                self._snapshot_stats["full"] += 1
+                # removals: any snapshot node no longer in the cache (or
+                # node-less)
+                live = {name for name, it in self._nodes.items()
+                        if it.info.node is not None}
+                gone = [n for n in snapshot.node_info_map if n not in live]
+                for n in gone:
+                    del snapshot.node_info_map[n]
+                if gone:
+                    changed = None
+                self._rebuild_lists(snapshot)
             snapshot.generation = latest
             snapshot.node_set_version = self._node_set_version
-            snapshot.changed_nodes = None if removed else changed
+            snapshot.changed_nodes = changed
             snapshot.version += 1
 
     def _rebuild_lists(self, snapshot: Snapshot) -> None:
@@ -399,16 +409,17 @@ class Cache:
             ni = snapshot.node_info_map.get(name)
             if ni is not None:
                 snapshot.node_info_list.append(ni)
-        self._rebuild_affinity_lists(snapshot)
+        snapshot.node_index = {ni.name: i for i, ni
+                               in enumerate(snapshot.node_info_list)}
 
-    @staticmethod
-    def _rebuild_affinity_lists(snapshot: Snapshot) -> None:
-        snapshot.have_pods_with_affinity_list = [
-            ni for ni in snapshot.node_info_list if ni.pods_with_affinity
-        ]
-        snapshot.have_pods_with_required_anti_affinity_list = [
-            ni for ni in snapshot.node_info_list if ni.pods_with_required_anti_affinity
-        ]
+    def snapshot_stats(self) -> dict:
+        """update_snapshot's refreshes by path, totals of the cache's life:
+        noop (nothing newer than the snapshot), delta (the node set stood:
+        only the re-cloned NodeInfos written), full (a node added, updated
+        or removed: the list rebuilt), and cloned, the NodeInfos the
+        refreshes re-cloned. Read without the lock, as the maintenance
+        tick does every loop turn: one copy of four counts."""
+        return dict(self._snapshot_stats)
 
     # ---------------- introspection (cache debugger, metrics) ----------------
 

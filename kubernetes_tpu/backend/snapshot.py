@@ -1,9 +1,9 @@
 """Per-cycle immutable cluster view.
 
 Equivalent of /root/reference/pkg/scheduler/backend/cache/snapshot.go:29-44:
-a node map plus a zone-interleaved node list and the two affinity sublists
-(HavePodsWithAffinityNodeInfoList / HavePodsWithRequiredAntiAffinityNodeInfoList)
-that let InterPodAffinity's PreFilter scan only relevant nodes.
+a node map plus a zone-interleaved node list. The reference's two affinity
+sublists are left out: nothing reads them (the device kernels read the
+mirror's pod table, the host fallback a NodeInfo's own lists).
 
 The snapshot is refreshed *incrementally* by Cache.update_snapshot (the
 generation-diff walk of cache.go:186 UpdateSnapshot); the device mirror in
@@ -21,8 +21,9 @@ class Snapshot:
     def __init__(self) -> None:
         self.node_info_map: dict[str, NodeInfo] = {}
         self.node_info_list: list[NodeInfo] = []
-        self.have_pods_with_affinity_list: list[NodeInfo] = []
-        self.have_pods_with_required_anti_affinity_list: list[NodeInfo] = []
+        # node name -> its position in node_info_list: a refresh over an
+        # unchanged node set writes the re-cloned NodeInfos there
+        self.node_index: dict[str, int] = {}
         self.generation: int = 0
         # namespace name -> labels, for affinity namespaceSelector unrolling
         # (the nsLister surface of interpodaffinity/plugin.go:123)
@@ -50,8 +51,5 @@ class Snapshot:
         return self.node_info_list
 
     def index_of(self, name: str) -> int:
-        """Stable row index of a node in this snapshot (device tensor row)."""
-        for i, ni in enumerate(self.node_info_list):
-            if ni.name == name:
-                return i
-        return -1
+        """Position of a node in node_info_list, or -1."""
+        return self.node_index.get(name, -1)

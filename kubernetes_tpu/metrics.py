@@ -304,6 +304,12 @@ class SchedulerMetrics:
             "computed (one per distinct request and own nominated node of "
             "a burst) or reused (a preemptor whose equal was computed "
             "earlier in the same burst)", ("result",)))
+        self.cache_snapshot_refresh = r.register(Counter(
+            "scheduler_cache_snapshot_refresh_total",
+            "Snapshot refreshes of the cache by path: noop (nothing "
+            "changed), delta (the node set stood: only the re-cloned "
+            "nodes written) or full (a node added, updated or removed: "
+            "the node list rebuilt)", ("path",)))
         self.pod_e2e_duration = r.register(Histogram(
             "pod_scheduling_duration_seconds",
             "E2e latency from a pod's first scheduling attempt to its "

@@ -3728,6 +3728,11 @@ class Scheduler:
             self._mirror_count(f"preemption_kmin:{result}",
                                sweep[f"kmin_{result}"], m.preemption_kmin,
                                result=result)
+        refresh = self.cache.snapshot_stats()
+        for path in ("noop", "delta", "full"):
+            self._mirror_count(f"cache_snapshot_refresh:{path}",
+                               refresh[path], m.cache_snapshot_refresh,
+                               path=path)
         self._mirror_journal_stats()
         if self.jobqueue.active:
             for tenant, st in self.jobqueue.tenant_stats().items():

@@ -143,6 +143,9 @@ class ServingEndpoints:
                         # the batched preemption sweep's outcomes, its
                         # victim-state refreshes and stranded preemptors
                         "preemption": sched.preemption.sweep_stats(),
+                        # the cache's snapshot refreshes by path, and the
+                        # NodeInfos they re-cloned
+                        "snapshot": sched.cache.snapshot_stats(),
                         "phases": flight.phase_percentiles(),
                         "host_tail_share": round(
                             flight.host_tail_share(), 4),

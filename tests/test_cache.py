@@ -2,11 +2,16 @@ import pytest
 
 from kubernetes_tpu.api.objects import (
     LABEL_ZONE,
+    Affinity,
     Container,
+    LabelSelector,
     Node,
     NodeStatus,
     ObjectMeta,
     Pod,
+    PodAffinity,
+    PodAffinityTerm,
+    PodAntiAffinity,
     PodSpec,
     ResourceRequirements,
 )
@@ -291,6 +296,158 @@ def test_cache_comparer_against_hub():
     assumed.spec.node_name = "n0"
     cache.assume_pod(assumed)
     assert cache.compare_with_hub(hub) == []
+
+
+def _term_pod(name, node, kind, app):
+    """A pod on ``node`` labelled app=<app>, carrying a required term of
+    ``kind`` ("affinity", "anti" or None) over app=<app>."""
+    pod = mkpod(name, node)
+    pod.metadata.labels = {"app": app}
+    term = PodAffinityTerm(topology_key="kubernetes.io/hostname",
+                           label_selector=LabelSelector(
+                               match_labels={"app": app}))
+    if kind == "affinity":
+        pod.spec.affinity = Affinity(pod_affinity=PodAffinity(
+            required=[term]))
+    elif kind == "anti":
+        pod.spec.affinity = Affinity(pod_anti_affinity=PodAntiAffinity(
+            required=[term]))
+    return pod
+
+
+def _snapshot_view(snap):
+    """What a refreshed snapshot holds, by value: the list's name order,
+    and per node its generation, requests and pods."""
+    def info(ni):
+        return (ni.generation, ni.requested.milli_cpu, ni.requested.memory,
+                [pi.pod.metadata.uid for pi in ni.pods],
+                [pi.pod.metadata.uid for pi in ni.pods_with_affinity],
+                [pi.pod.metadata.uid
+                 for pi in ni.pods_with_required_anti_affinity])
+    return ([ni.name for ni in snap.node_info_list],
+            {name: info(ni) for name, ni in snap.node_info_map.items()},
+            snap.namespaces)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5, 6, 7])
+def test_incremental_snapshot_matches_a_fresh_one(seed):
+    """A seeded random run of node adds, updates (zone moves among them)
+    and removals (of nodes that still hold pods too), pods with and
+    without (anti-)affinity terms, assumes, confirmations, forgets and
+    namespace changes. After every refresh the incrementally refreshed
+    snapshot equals a fresh snapshot refreshed once; its list entries are
+    its map's objects at the positions node_index names; changed_nodes
+    is None after a node add or removal, else the nodes whose NodeInfo
+    moved; and a list taken before the refresh is left as it was."""
+    import random
+
+    rng = random.Random(seed)
+    c = Cache()
+    snap = Snapshot()
+    nodes: dict[str, Node] = {}
+    assumed: dict[str, Pod] = {}
+    bound: dict[str, Pod] = {}
+    names = [f"n{i}" for i in range(10)]
+    zones = ["za", "zb", "zc"]
+    for step in range(300):
+        op = rng.choice(["add_node", "update_node", "remove_node",
+                         "assume", "assume", "assume", "confirm", "forget",
+                         "remove_pod", "namespace"])
+        set_moved = False
+        if op == "add_node":
+            name = rng.choice(names)
+            if name not in nodes:
+                nodes[name] = mknode(name, zone=rng.choice(zones))
+                c.add_node(nodes[name])
+                set_moved = True
+        elif op == "update_node" and nodes:
+            name = rng.choice(sorted(nodes))
+            new = mknode(name, zone=rng.choice(zones),
+                         cpu=rng.choice(["4", "8"]))
+            c.update_node(nodes[name], new)
+            nodes[name] = new
+        elif op == "remove_node" and nodes:
+            name = rng.choice(sorted(nodes))
+            c.remove_node(nodes.pop(name))
+            set_moved = True
+        elif op == "assume":
+            # now and then on a node the cache has not seen (yet)
+            node = rng.choice(names)
+            pod = _term_pod(f"p{step}", node,
+                            rng.choice([None, None, "affinity", "anti"]),
+                            rng.choice(["web", "db"]))
+            c.assume_pod(pod)
+            assumed[pod.metadata.uid] = pod
+        elif op == "confirm" and assumed:
+            pod = assumed.pop(rng.choice(sorted(assumed)))
+            c.add_pod(pod)
+            bound[pod.metadata.uid] = pod
+        elif op == "forget" and assumed:
+            c.forget_pod(assumed.pop(rng.choice(sorted(assumed))))
+        elif op == "remove_pod" and bound:
+            c.remove_pod(bound.pop(rng.choice(sorted(bound))))
+        elif op == "namespace":
+            if rng.random() < 0.5:
+                c.set_namespace(rng.choice(["ns-a", "ns-b"]),
+                                {"team": rng.choice(["x", "y"])})
+            else:
+                c.remove_namespace(rng.choice(["ns-a", "ns-b"]))
+
+        held = snap.node_info_list
+        held_items = list(held)
+        before = {n: ni.generation for n, ni in snap.node_info_map.items()}
+        version = snap.version
+        c.update_snapshot(snap)
+        fresh = Snapshot()
+        c.update_snapshot(fresh)
+
+        assert len(held) == len(held_items)
+        assert all(a is b for a, b in zip(held, held_items)), \
+            "a published list was written in place"
+        assert _snapshot_view(snap) == _snapshot_view(fresh)
+        assert snap.node_index == {ni.name: i for i, ni
+                                   in enumerate(snap.node_info_list)}
+        assert all(snap.node_info_map[ni.name] is ni
+                   for ni in snap.node_info_list)
+        if snap.version == version:
+            # nothing changed: the no-change path left everything
+            assert {n: ni.generation for n, ni
+                    in snap.node_info_map.items()} == before
+            continue
+        if set_moved:
+            assert snap.changed_nodes is None
+        if snap.changed_nodes is not None:
+            moved = {n for n, ni in snap.node_info_map.items()
+                     if before.get(n) != ni.generation}
+            assert set(snap.changed_nodes) == moved
+            assert set(snap.node_info_map) == set(before)
+
+
+def test_snapshot_stats_count_refreshes_by_path():
+    """A pod-only change takes the delta path and re-clones its node
+    alone; a node add takes the full path; a refresh with nothing new is
+    a noop."""
+    c = Cache()
+    snap = Snapshot()
+    for i in range(4):
+        c.add_node(mknode(f"n{i}"))
+    c.update_snapshot(snap)
+    assert c.snapshot_stats() == {"noop": 0, "delta": 0, "full": 1,
+                                  "cloned": 4}
+    c.update_snapshot(snap)
+    assert c.snapshot_stats()["noop"] == 1
+    c.assume_pod(mkpod("p1", "n2"))
+    c.update_snapshot(snap)
+    stats = c.snapshot_stats()
+    assert (stats["delta"], stats["full"], stats["cloned"]) == (1, 1, 5)
+    assert snap.changed_nodes == ["n2"]
+    c.add_node(mknode("n4"))
+    c.update_snapshot(snap)
+    stats = c.snapshot_stats()
+    assert (stats["delta"], stats["full"], stats["cloned"]) == (1, 2, 6)
+    assert snap.changed_nodes is None
+    assert snap.index_of("n4") == snap.num_nodes() - 1
+    assert snap.index_of("gone") == -1
 
 
 # suite-tier discipline (tests/test_markers.py): area marker

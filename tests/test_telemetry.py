@@ -1096,3 +1096,53 @@ def test_preemption_sweep_computes_one_kmin_a_distinct_request():
         assert 'scheduler_preemption_kmin_total{result="reused"} 9' in text
     finally:
         sched.close()
+
+
+def test_cache_snapshot_refreshes_reach_the_registry_and_the_trace():
+    """Cache.snapshot_stats() counts update_snapshot's refreshes by path:
+    the first a full one (the nodes are new), a refresh after binds over
+    the same nodes a delta one, a refresh with nothing new a noop (chained
+    launches skip the refresh, so the test refreshes by hand). Exported
+    at maintenance, by delta, as
+    scheduler_cache_snapshot_refresh_total{path="noop|delta|full"}, and
+    on /debug/trace as "snapshot"."""
+    from kubernetes_tpu.backend.cache import SNAPSHOT_STATS
+    from kubernetes_tpu.serving import ServingEndpoints, token_auth
+    from tests.test_tracing import _sched, mknode, mkpod
+
+    hub = Hub()
+    sched = _sched(hub)
+    try:
+        for i in range(4):
+            hub.create_node(mknode(i))
+        for wave in range(3):
+            for j in range(4):
+                hub.create_pod(mkpod(f"p-{wave}-{j}"))
+            sched.run_until_idle()
+            sched.cache.update_snapshot(sched.snapshot)
+            sched.cache.update_snapshot(sched.snapshot)
+        sched.run_maintenance()
+        stats = sched.cache.snapshot_stats()
+        assert tuple(stats) == SNAPSHOT_STATS
+        assert stats["full"] == 1
+        assert stats["delta"] >= 3 and stats["noop"] >= 3
+        assert stats["cloned"] >= 4 + 3
+        m = sched.metrics
+        for path in ("noop", "delta", "full"):
+            assert m.cache_snapshot_refresh.value(path=path) == stats[path]
+        text = m.registry.render_text()
+        assert ('scheduler_cache_snapshot_refresh_total{path="delta"} '
+                f'{stats["delta"]}') in text
+        srv = ServingEndpoints(sched, port=0, debug_auth=token_auth("t"))
+        srv.start()
+        try:
+            req = urllib.request.Request(
+                f"http://127.0.0.1:{srv.port}/debug/trace?n=1")
+            req.add_header("Authorization", "Bearer t")
+            tr = json.loads(urllib.request.urlopen(req, timeout=5).read())
+        finally:
+            srv.stop()
+        assert tr["snapshot"] == sched.cache.snapshot_stats()
+    finally:
+        sched.close()
+        hub.close()
