@@ -67,11 +67,6 @@ def main(argv=None) -> int:
                         help="append each scheduling cycle's flight-"
                              "recorder trace as a JSON line to this file "
                              "(offline phase analysis)")
-    parser.add_argument("--trace-export-learn", action="store_true",
-                        help="with --trace-export: also export each "
-                             "placement's feature vector AND top-K "
-                             "alternative scores (the learn-loop "
-                             "daemon's training + regret substrate)")
     parser.add_argument("--leader-elect", action="store_true")
     parser.add_argument("--leader-elect-lease-duration", type=float,
                         default=15.0)
@@ -113,9 +108,6 @@ def main(argv=None) -> int:
     cfg = load_config(args.config) if args.config else default_config()
     if args.trace_export:
         cfg.trace_export_path = args.trace_export
-        if args.trace_export_learn:
-            cfg.trace_export_features = True
-            cfg.trace_export_alts = True
     for part in filter(None, args.feature_gates.split(",")):
         name, _, val = part.partition("=")
         cfg.feature_gates[name.strip()] = val.strip().lower() in (

@@ -58,8 +58,7 @@ def config_from_dict(doc: dict) -> SchedulerConfiguration:
                 "async_binding", "binding_workers", "batch_size",
                 "node_capacity", "pod_table_capacity",
                 "flight_recorder_capacity", "trace_export_path",
-                "trace_export_max_bytes", "trace_export_features",
-                "trace_export_alts", "tie_break_seed"):
+                "trace_export_max_bytes", "tie_break_seed"):
         if key in doc:
             setattr(cfg, key, doc[key])
     profiles = [_profile(p) for p in doc.get("profiles") or []]

@@ -118,25 +118,12 @@ class SchedulerConfiguration:
     # gate on p50/p99 across >4096 pods must size it to the workload or
     # the oldest pods silently fall out of the percentile pass
     timelines_capacity: int = 4096
-    # append each cycle trace as a JSON line here (offline analysis /
-    # the learned-scorer replay dataset; export format v2 carries
-    # per-pod placement rows)
+    # append each cycle trace as a JSON line here (offline analysis;
+    # export format v2 carries per-pod placement rows)
     trace_export_path: Optional[str] = None
     # size-based keep-last-1 rotation bound for the trace export file
     # (0 = unbounded); long trace-collection runs must not fill the disk
     trace_export_max_bytes: int = 64 * 1024 * 1024
-    # ALSO export each placement's chosen-node learned-feature vector
-    # (the replay-training substrate). Opt-in: it compiles the feature
-    # kernels into every launch and adds per-cycle D2H pulls + export
-    # bytes — phase-timing-only export users should not pay for it
-    trace_export_features: bool = False
-    # ALSO export each placement's top-K alternative node scores
-    # (export v3 "alt" rows — the counterfactual substrate behind
-    # per-placement regret and the learn-loop's contextual-bandit
-    # fine-tune). Opt-in like trace_export_features: it compiles a
-    # [B, K] top_k into every launch and rides the existing per-cycle
-    # device_get (no extra sync)
-    trace_export_alts: bool = False
     # device-side gang packing (ops/gang.pack_gangs): place a whole
     # PodGroup in one fused launch — all-or-nothing feasibility on
     # device, one host commit, no per-member Permit round-trips. Off

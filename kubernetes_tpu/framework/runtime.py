@@ -55,7 +55,6 @@ _WEIGHT_FIELD = {
     "ImageLocality": "image_locality",
     "PodTopologySpread": "pod_topology_spread",
     "InterPodAffinity": "inter_pod_affinity",
-    "LearnedScore": "learned",
 }
 
 

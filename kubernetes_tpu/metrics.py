@@ -460,7 +460,7 @@ class SchedulerMetrics:
         self.device_live_buffer_bytes = r.register(Gauge(
             "scheduler_device_live_buffer_bytes",
             "Resident device-buffer bytes by buffer family (cluster "
-            "tensors, pod batch, DRA inventories, learned params)"))
+            "tensors, pod batch, DRA inventories)"))
         # scenario replay driver (scenario/replay.py): trace events it
         # injected into this scheduler's hub, SLO-gate breaches, and
         # the last replay's trace-time bind tail
@@ -552,28 +552,6 @@ class SchedulerMetrics:
         self.tenant_quota_used = r.register(Gauge(
             "scheduler_tenant_quota_used",
             "Admission-time quota reservation by tenant and resource"))
-        # learned scoring subsystem (plugins/learned.py + ops/learned.py)
-        self.learned_checkpoint_version = r.register(Gauge(
-            "scheduler_learned_checkpoint_version",
-            "Active learned-scorer checkpoint version by profile "
-            "(0 = none loaded)"))
-        self.learned_reloads = r.register(Counter(
-            "scheduler_learned_reloads_total",
-            "Learned-scorer checkpoint hot-reloads (mtime change "
-            "observed at snapshot-sync time); generation 0 = a manual "
-            "publish, >0 = the learn-loop's gated promotion",
-            ("profile", "generation")))
-        self.learned_load_errors = r.register(Counter(
-            "scheduler_learned_load_errors_total",
-            "Learned-scorer checkpoint loads rejected (corrupt/"
-            "mismatched file; the last good params keep serving)",
-            ("profile",)))
-        self.learned_magnitude = r.register(Histogram(
-            "scheduler_learned_score_magnitude",
-            "Mean |weighted learned-score term| per launch over "
-            "feasible (pod, node) pairs — drift watch for the fused "
-            "MLP term", (0.01, 0.1, 0.5, 1.0, 2.0, 5.0, 10.0, 20.0,
-                         50.0, 100.0, 200.0, 500.0)))
         self.queue_incoming_pods = r.register(Counter(
             "queue_incoming_pods_total",
             "Pods added to scheduling queues by event/queue",

@@ -49,7 +49,6 @@ import jax.numpy as jnp
 
 from kubernetes_tpu.ops import common as C
 from kubernetes_tpu.ops import filters as FL
-from kubernetes_tpu.ops import learned as LN
 from kubernetes_tpu.ops import scores as SC
 from kubernetes_tpu.ops import topology as T
 from kubernetes_tpu.ops.topology import table_blocks_for  # noqa: F401
@@ -88,7 +87,6 @@ SCORE_PLUGINS = (
     "ImageLocality",              # w=1, 0..100
     "PodTopologySpread",          # w=2, spread-normalized
     "InterPodAffinity",           # w=2, max-min-normalized
-    "LearnedScore",               # w=0 by default (profile-gated MLP term)
 )
 
 # default HardPodAffinityWeight (apis/config/v1/defaults.go)
@@ -97,17 +95,6 @@ HARD_POD_AFFINITY_WEIGHT = 1.0
 # phase-1 (parallel Filter/Score) sub-batch size: bounds the transient
 # [chunk, selector-capacity, N] gather footprint for giant drain batches
 PHASE1_CHUNK = 1024
-
-# top-K alternative-candidate export (with_alts; export v3): how many
-# runner-up (node, score) pairs each placement row carries — the
-# counterfactual substrate behind per-placement regret (learn/regret.py).
-# Small and static: a [B, K] top_k fused into the launch, K-1 extra rows
-# per exported placement.
-ALT_K = 4
-# alt_score padding sentinel for infeasible/absent candidate slots;
-# aggregate scores are bounded (a few hundred), so anything below
-# ALT_NONE/2 is "no candidate" on the host side
-ALT_NONE = -1e9
 
 # commit-scan unroll factor (the steps in one block of the scan's loop, see
 # schedule_batch): amortizes per-iteration dispatch overhead, which
@@ -175,9 +162,6 @@ class ScoreWeights:
     image_locality: jax.Array
     pod_topology_spread: jax.Array
     inter_pod_affinity: jax.Array
-    # the learned MLP term (ops/learned.py); 0 unless a profile enables
-    # the LearnedScore plugin, so the default aggregate is unchanged
-    learned: jax.Array
 
 
 def default_weights() -> ScoreWeights:
@@ -189,7 +173,6 @@ def default_weights() -> ScoreWeights:
         image_locality=jnp.float32(1.0),
         pod_topology_spread=jnp.float32(2.0),
         inter_pod_affinity=jnp.float32(2.0),
-        learned=jnp.float32(0.0),
     )
 
 
@@ -232,22 +215,6 @@ class BatchResult:
     # the pod's host_reject_counts under "DynamicResources" so diagnosis
     # and requeue hints match the host filter path exactly.
     dra_reject: jax.Array
-    # [] f32: mean |weighted learned-score term| over feasible (pod,
-    # node) pairs this launch (0.0 when the launch carried no learned
-    # params). Pulled only when the learned scorer is active — feeds the
-    # scheduler_learned_score_magnitude histogram.
-    learned_mag: jax.Array
-    # [B, ops.learned.NUM_FEATURES] f32: the CHOSEN node's learned-score
-    # feature row per pod (zeros unless the launch was compiled
-    # with_feats — the flight-recorder export's replay-dataset rows).
-    chosen_feat: jax.Array
-    # [B, ALT_K] i32 / f32: the top-K candidate node rows and their
-    # aggregate scores per pod (-1 / ALT_NONE padding unless the launch
-    # was compiled with_alts — the export v3 counterfactual substrate
-    # behind per-placement regret). The chosen node itself rides along
-    # (it is top-1 in the common case); the offline consumer filters it.
-    alt_row: jax.Array
-    alt_score: jax.Array
     # [] i32: commit-scan steps this launch ran (whole blocks of
     # scan_unroll() up to the last row that carries a pod; 0 on the
     # auction path, which has no scan). scan_steps_for() is the same
@@ -563,8 +530,7 @@ def _soft_scores(soft: _SoftTopo, placed, gid_oh):
 def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
                    img, unres, weights, free0, nzr0, host_score=None,
                    fit_strategy="LeastAllocated", fit_shape=None,
-                   dra_reject=None, learned=None, tie_seed=None,
-                   with_feats=False, with_alts=False, soft=None,
+                   dra_reject=None, tie_seed=None, soft=None,
                    unroll=None):
     """Parallel auction replacing the per-pod commit scan when the batch has
     no topology constraints and no host ports: every round, all unplaced
@@ -629,26 +595,19 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
     def fit_all(free):
         return jnp.all(pods.req[:, None, :] <= eff_all(free), axis=-1)
 
-    def per_pod_scores(nzr, nzreq, t_raw, a_raw, feas):
-        """One pod's normalized per-plugin score arrays against ``nzr``
-        (shared by the round totals and the learned-feature export)."""
-        frac = SC.utilization_fractions(alloc2, nzr, nzreq)
-        least = SC.fit_score_from_fractions(frac, fit_strategy, fit_shape)
-        bal = SC.balanced_allocation_from_fractions(frac)
-        taint = SC.normalize_inverse(t_raw, feas)
-        aff = SC.normalize_max(a_raw, feas)
-        return frac, least, bal, taint, aff
-
     def totals(nzr, feasible, sp_b=None, ipa_b=None):
         def per_pod(nzreq, t_raw, a_raw, im, feas, *topo):
-            frac, least, bal, taint, aff = per_pod_scores(
-                nzr, nzreq, t_raw, a_raw, feas)
+            frac = SC.utilization_fractions(alloc2, nzr, nzreq)
+            least = SC.fit_score_from_fractions(frac, fit_strategy,
+                                                fit_shape)
+            bal = SC.balanced_allocation_from_fractions(frac)
+            taint = SC.normalize_inverse(t_raw, feas)
+            aff = SC.normalize_max(a_raw, feas)
             total = (weights.taint_toleration * taint
                      + weights.node_affinity * aff
                      + weights.resources_fit * least
                      + weights.balanced_allocation * bal
                      + weights.image_locality * im)
-            sp_n = ipa_n = None
             if topo:
                 # soft-topology mode: normalize + weight the live soft
                 # halves per pod, exactly like the serial scan's step
@@ -659,10 +618,6 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
                                                      ign_row), 0.0)
                 total = (total + weights.pod_topology_spread * sp_n
                          + weights.inter_pod_affinity * ipa_n)
-            if learned is not None:
-                total = total + weights.learned * LN.learned_term(
-                    learned, frac, least, bal, taint, aff, im, sp_n,
-                    ipa_n)
             return total
         args = (pods.nonzero_req, taint_raw, aff_raw, img, feasible)
         if sp_b is not None:
@@ -767,102 +722,6 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
     reject_counts = jnp.concatenate(
         [static_rejects, fit_rejects[:, None], zeros[:, None],
          ipa_rejects[:, None]], axis=1)
-    # learned-score magnitude + chosen-node feature export, attributed
-    # against the END state like the reject diagnostics above (the
-    # per-round states the losers scored against are gone)
-    learned_mag = jnp.float32(0.0)
-    chosen_feat = jnp.zeros((B, LN.NUM_FEATURES), jnp.float32)
-    alt_row = jnp.full((B, ALT_K), -1, jnp.int32)
-    alt_score = jnp.full((B, ALT_K), ALT_NONE, jnp.float32)
-    if learned is not None or with_feats or with_alts:
-        ok_end = static_ok & fit       # end-state feasible, like rejects
-        if ipa_mask is not None:
-            ok_end = ok_end & ipa_mask
-        rows_c = jnp.clip(placed, 0, N - 1)
-        chosen_oh = ((rows_c[:, None] == jnp.arange(N)[None, :])
-                     & (placed >= 0)[:, None])                # [B, N]
-        # the chosen node joins its own candidate/normalization mask
-        # even when end-state fit excludes it (it WAS feasible when it
-        # won)
-        cand = ok_end | chosen_oh
-        if soft is not None:
-            # end-state soft halves ride the export totals (and, via
-            # LN.feature_rows' spread/ipa columns, the feature export)
-            # exactly like the reject diagnostics above
-            ipa_end_g, sp_end_g = _soft_scores(soft, placed, gid_oh)
-            ipa_end_b = ipa_end_g[soft.gid]
-            sp_end_b = sp_end_g[soft.gid]
-        else:
-            ipa_end_b = jnp.zeros((B, N), jnp.float32)
-            sp_end_b = jnp.zeros((B, N), jnp.float32)
-            ign_b = jnp.ones((B, N), bool)
-            soft_b = jnp.zeros((B,), bool)
-
-        def pod_eval(nzreq, t_raw, a_raw, im, feas_row, own_row,
-                     ipa_row, sp_row, ign_row, softp):
-            # ONE evaluation feeds every export tail (features, the
-            # fused learned term, the alt totals) — like the serial
-            # scan deriving all three from one per-step state. The
-            # pod's OWN committed usage is subtracted first:
-            # utilization_fractions re-adds the request, so feeding
-            # end-state nzr directly would double-count the pod on its
-            # chosen node — skewing the exported training distribution
-            # away from inference AND deflating exactly the chosen
-            # basis regret compares against the runner-ups
-            nzr_i = nzr - own_row[:, None] * nzreq[None, :]
-            frac, least, bal, taint, aff = per_pod_scores(
-                nzr_i, nzreq, t_raw, a_raw, feas_row)
-            ipa_n = SC.normalize_maxmin(ipa_row, feas_row)
-            sp_n = jnp.where(softp,
-                             SC.normalize_spread(sp_row, feas_row,
-                                                 ign_row), 0.0)
-            feats_row = LN.feature_rows(frac, least, bal, taint, aff,
-                                        im, sp_n, ipa_n)     # [N, F]
-            lterm_row = (jnp.clip(LN.mlp_apply(learned, feats_row),
-                                  0.0, LN.MAX_SCORE)
-                         if learned is not None
-                         else jnp.zeros_like(least))          # [N]
-            total = (weights.taint_toleration * taint
-                     + weights.node_affinity * aff
-                     + weights.resources_fit * least
-                     + weights.balanced_allocation * bal
-                     + weights.image_locality * im
-                     + weights.pod_topology_spread * sp_n
-                     + weights.inter_pod_affinity * ipa_n
-                     + weights.learned * lterm_row)
-            return feats_row, lterm_row, total
-        # unused outputs are DCE'd per compiled flag combination
-        feats, lterm, tot = jax.vmap(pod_eval)(
-            pods.nonzero_req, taint_raw, aff_raw, img, cand,
-            chosen_oh.astype(nzr.dtype),
-            ipa_end_b, sp_end_b, ign_b, soft_b)
-        if learned is not None:
-            # same feasible-pair definition as the serial path's live
-            # mask (modulo end-state attribution): one histogram, one
-            # metric meaning across commit paths
-            n_ok = jnp.maximum(jnp.sum(ok_end), 1)
-            learned_mag = (jnp.sum(jnp.where(
-                ok_end, jnp.abs(weights.learned * lterm), 0.0))
-                / n_ok.astype(jnp.float32))
-        if with_feats:
-            chosen_feat = jnp.take_along_axis(
-                feats, rows_c[:, None, None], axis=1)[:, 0, :]
-        if with_alts:
-            # top-K candidate nodes + aggregate scores, attributed
-            # against the END state like the feature/reject
-            # diagnostics above (the per-round states the losers
-            # scored against are gone); the chosen node rides the
-            # candidate set so its score is comparable to its
-            # runners-up on ONE basis
-            if host_score is not None:
-                tot = tot + host_score
-            masked = jnp.where(cand, tot, ALT_NONE)
-            k = min(ALT_K, N)
-            a_s, a_r = jax.lax.top_k(masked, k)
-            a_r = jnp.where(a_s > ALT_NONE * 0.5,
-                            a_r.astype(jnp.int32), -1)
-            alt_score = alt_score.at[:, :k].set(a_s)
-            alt_row = alt_row.at[:, :k].set(a_r)
     return BatchResult(node_row=placed, score=win, feasible_count=feas,
                        reject_counts=reject_counts,
                        unresolvable_count=unres, free=free, nzr=nzr,
@@ -870,8 +729,6 @@ def _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw, aff_raw,
                        guard=_guard_reduction(win, free),
                        dra_reject=(jnp.zeros((B,), jnp.int32)
                                    if dra_reject is None else dra_reject),
-                       learned_mag=learned_mag, chosen_feat=chosen_feat,
-                       alt_row=alt_row, alt_score=alt_score,
                        scan_steps=jnp.int32(0),
                        table_blocks=(T.table_blocks(ct) if soft is not None
                                      else jnp.int32(0)))
@@ -897,10 +754,7 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                    pct_nodes: int = 0,
                    pct_start: jnp.ndarray | None = None,
                    dra=None,
-                   learned=None,
                    tie_seed=None,
-                   with_feats: bool = False,
-                   with_alts: bool = False,
                    topo_soft: bool = False,
                    auction_unroll: int | None = None,
                    ) -> BatchResult:
@@ -956,20 +810,8 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
     vmapped predicate ANDed into the feasible mask, and the per-pod
     rejected-node count lands in BatchResult.dra_reject.
 
-    ``learned`` (an ops.learned params pytree, or None) fuses the
-    profile-gated MLP scorer into the aggregate as one more weighted
-    term (weights.learned); a NaN-poisoned checkpoint trips the guard
-    reduction like any other device fault. ``tie_seed`` (dynamic scalar)
-    keys the tie-break hash for A/B-deterministic paired runs; seed
-    0/None is the historical hash. ``with_feats`` (STATIC) additionally
-    materializes each pod's chosen-node feature row in
-    BatchResult.chosen_feat — the flight-recorder export's replay rows;
-    off, the field is zeros and the feature kernels are DCE'd.
-    ``with_alts`` (STATIC) materializes the top-ALT_K candidate node
-    rows + aggregate scores per pod in BatchResult.alt_row/.alt_score —
-    the export v3 counterfactual substrate behind per-placement regret
-    (learn/regret.py); off, the fields are padding and the top_k is
-    DCE'd."""
+    ``tie_seed`` (dynamic scalar) keys the tie-break hash for
+    A/B-deterministic paired runs; seed 0/None is the historical hash."""
     ct = unpack_cluster(cblobs, caps)
     pods = unpack_pods(pblobs, caps, pfields, ptmpl)  # leaves [B, ...]
     free0 = ct.free if state is None else state[0]
@@ -1093,8 +935,8 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
         return _rounds_commit(ct, pods, static_ok, static_rejects, taint_raw,
                               aff_raw, img, unres, weights, free0, nzr0,
                               host_score, fit_strategy, fit_shape,
-                              dra_reject, learned, tie_seed, with_feats,
-                              with_alts, soft=soft, unroll=auction_unroll)
+                              dra_reject, tie_seed, soft=soft,
+                              unroll=auction_unroll)
     soft_st = None
     if enable_topology and topo_soft:
         # ---- phase 1b (SOFT): the reduced per-group statics — exactly
@@ -1500,15 +1342,6 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                  + weights.image_locality * im
                  + weights.pod_topology_spread * spread
                  + weights.inter_pod_affinity * ipa)
-        if learned is not None:
-            # the fused MLP term, against the SAME live per-step state
-            # the hand-tuned terms see (as-if-serial holds for it too)
-            lterm = weights.learned * LN.learned_term(
-                learned, frac, least, bal, taint, aff, im, spread, ipa)
-            total = total + lterm
-            lmag_step = (jnp.sum(jnp.where(feasible, jnp.abs(lterm), 0.0))
-                         / jnp.maximum(jnp.sum(feasible), 1)
-                         .astype(jnp.float32))
         if host_score is not None:
             total = total + host_score[b]
         row = C.masked_argmax_random(total, feasible, ptb)
@@ -1545,31 +1378,6 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
             out_carry = out_carry + (start,)
         ys = (row, win, jnp.sum(feasible).astype(jnp.int32),
               port_rejects, fit_rejects, sp_rejects, ipa_rejects)
-        if learned is not None:
-            ys = ys + (lmag_step,)
-        if with_feats:
-            ys = ys + (LN.feature_row_at(r, frac, least, bal, taint, aff,
-                                         im, spread, ipa),)
-        if with_alts:
-            # top-K candidates against the pod's LIVE per-step state —
-            # exactly the alternatives this pod could have taken at its
-            # decision time (the serial path's as-if-serial
-            # counterfactual; top_k breaks ties by row index, so the
-            # tie-perturbed winner need not be slot 0 — the offline
-            # consumer treats its entry as the chosen value's basis
-            # wherever it lands)
-            masked_t = jnp.where(feasible, total, ALT_NONE)
-            k_alt = min(ALT_K, masked_t.shape[0])
-            a_s, a_r = jax.lax.top_k(masked_t, k_alt)
-            if k_alt < ALT_K:
-                a_s = jnp.concatenate(
-                    [a_s, jnp.full((ALT_K - k_alt,), ALT_NONE,
-                                   jnp.float32)])
-                a_r = jnp.concatenate(
-                    [a_r, jnp.full((ALT_K - k_alt,), -1, a_r.dtype)])
-            a_r = jnp.where(a_s > ALT_NONE * 0.5,
-                            a_r.astype(jnp.int32), -1)
-            ys = ys + (a_r, a_s)
         return out_carry, ys
 
     xs = (jnp.arange(B), static_ok, taint_raw, aff_raw, img,
@@ -1621,12 +1429,6 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
             lambda x: jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)),
             xs[1:])
     ys_fill = [-1, 0.0, 0, 0, 0, 0, 0]       # row, win, feas, 4 rejects
-    if learned is not None:
-        ys_fill.append(0.0)
-    if with_feats:
-        ys_fill.append(0.0)
-    if with_alts:
-        ys_fill += [-1, ALT_NONE]
     ys_sds = jax.eval_shape(lambda c, x: body(c, x)[1], init,
                             jax.tree.map(lambda x: x[0], xs))
     ys_init = tuple(jnp.full((B + pad,) + sd.shape, fill, sd.dtype)
@@ -1651,22 +1453,7 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
     if pad:
         ys_out = tuple(y[:B] for y in ys_out)
     (rows, win_scores, feas, port_rejects, fit_rejects, sp_rejects,
-     ipa_rejects) = ys_out[:7]
-    extra = list(ys_out[7:])
-    learned_mag = jnp.float32(0.0)
-    if learned is not None:
-        lmags = extra.pop(0)                                      # [B]
-        n_valid = jnp.maximum(jnp.sum(pods.valid), 1)
-        learned_mag = (jnp.sum(jnp.where(pods.valid, lmags, 0.0))
-                       / n_valid.astype(jnp.float32))
-    chosen_feat = (extra.pop(0) if with_feats
-                   else jnp.zeros((B, LN.NUM_FEATURES), jnp.float32))
-    if with_alts:
-        alt_row = extra.pop(0)                                 # [B, K]
-        alt_score = extra.pop(0)
-    else:
-        alt_row = jnp.full((B, ALT_K), -1, jnp.int32)
-        alt_score = jnp.full((B, ALT_K), ALT_NONE, jnp.float32)
+     ipa_rejects) = ys_out
     free_out, nzr_out = carry_out[0], carry_out[1]
     start_out = carry_out[-1] if pct_nodes else jnp.int32(0)
 
@@ -1679,9 +1466,7 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                        reject_counts=reject_counts, unresolvable_count=unres,
                        free=free_out, nzr=nzr_out, pct_start=start_out,
                        guard=_guard_reduction(win_scores, free_out),
-                       dra_reject=dra_reject, learned_mag=learned_mag,
-                       chosen_feat=chosen_feat,
-                       alt_row=alt_row, alt_score=alt_score,
+                       dra_reject=dra_reject,
                        scan_steps=jnp.minimum(n_blocks * u, B),
                        table_blocks=(T.table_blocks(ct) if enable_topology
                                      else jnp.int32(0)))
@@ -1691,7 +1476,6 @@ def schedule_batch(cblobs: ClusterBlobs, pblobs: PodBlobs,
                                    "enabled_filters", "serial_scan",
                                    "active", "pfields", "g_cap",
                                    "fit_strategy", "pct_nodes",
-                                   "with_feats", "with_alts",
                                    "topo_soft", "auction_unroll"))
 def schedule_batch_jit(cblobs, pblobs, wk, weights, caps,
                        enable_topology=True, d_cap=None,
@@ -1700,16 +1484,14 @@ def schedule_batch_jit(cblobs, pblobs, wk, weights, caps,
                        gid=None, rep=None, g_cap=0, host_ok=None,
                        host_score=None, fit_strategy="LeastAllocated",
                        fit_shape=None, pct_nodes=0, pct_start=None,
-                       dra=None, learned=None, tie_seed=None,
-                       with_feats=False, with_alts=False,
+                       dra=None, tie_seed=None,
                        topo_soft=False, auction_unroll=None):
     return schedule_batch(cblobs, pblobs, wk, weights, caps,
                           enable_topology, d_cap, enabled_filters,
                           serial_scan, state, active, pfields, ptmpl,
                           gid, rep, g_cap, host_ok, host_score,
                           fit_strategy, fit_shape, pct_nodes, pct_start,
-                          dra, learned, tie_seed, with_feats, with_alts,
-                          topo_soft, auction_unroll)
+                          dra, tie_seed, topo_soft, auction_unroll)
 
 
 @partial(jax.jit, static_argnames=("caps",))
@@ -1828,8 +1610,7 @@ def launch_batch(spec, wk, weights, caps, enabled_filters=None,
                  serial_scan=True, state=None, host_ok=None,
                  host_score=None, fit_strategy="LeastAllocated",
                  fit_shape=None, pct_nodes=0, pct_start=None,
-                 learned=None, tie_seed=None,
-                 with_feats=False, with_alts=False) -> BatchResult:
+                 tie_seed=None) -> BatchResult:
     """schedule_batch_jit driven by a Mirror.prepare_launch LaunchSpec."""
     return schedule_batch_jit(
         spec.cblobs, spec.pblobs, wk, weights, caps,
@@ -1840,5 +1621,4 @@ def launch_batch(spec, wk, weights, caps, enabled_filters=None,
         host_ok=host_ok, host_score=host_score,
         fit_strategy=fit_strategy, fit_shape=fit_shape,
         pct_nodes=pct_nodes, pct_start=pct_start, dra=spec.dra,
-        learned=learned, tie_seed=tie_seed, with_feats=with_feats,
-        with_alts=with_alts, topo_soft=spec.topo_soft)
+        tie_seed=tie_seed, topo_soft=spec.topo_soft)

@@ -193,15 +193,6 @@ def in_tree_registry() -> dict[str, PluginDescriptor]:
             name="ImageLocality", points=("score",), device_score=True,
             default_weight=1,
             events=[_ev(R.NODE, A.ADD | A.UPDATE_NODE_LABEL)]),
-        # learned MLP score term (ops/learned.py), fused into the same
-        # launch; OFF by default — a profile opts in at the score point
-        # and names its checkpoint in plugin_config. The factory builds
-        # the host-side checkpoint manager (plugins/learned.py), which
-        # is NOT a host ScorePlugin: scoring stays on device
-        PluginDescriptor(
-            name="LearnedScore", points=("score",), device_score=True,
-            default_weight=1,
-            factory=_learned_factory),
         PluginDescriptor(
             name="DefaultPreemption", points=("post_filter", "pre_enqueue"),
             factory=_default_preemption_factory,
@@ -289,12 +280,6 @@ def _dra_factory(args: dict):
     from kubernetes_tpu.plugins.dra import DynamicResources
 
     return DynamicResources(hub)
-
-
-def _learned_factory(args: dict):
-    from kubernetes_tpu.plugins.learned import LearnedScore
-
-    return LearnedScore(args)
 
 
 def _volume_factory(name: str):

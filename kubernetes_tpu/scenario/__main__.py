@@ -58,8 +58,6 @@ def main(argv: list[str] | None = None) -> int:
                    help="wall-clock seconds")
     f.add_argument("--seed", type=int, default=0)
     f.add_argument("--speed", type=float, default=3.0)
-    f.add_argument("--objective", choices=("p99", "regret"),
-                   default="p99")
     f.add_argument("--regime", action="append", default=[],
                    help="restrict to these regimes (default: all)")
     f.add_argument("--file-to", default=None,
@@ -90,8 +88,7 @@ def main(argv: list[str] | None = None) -> int:
         return 0 if rep["ok"] else 1
     if args.cmd == "fuzz":
         rep = fuzz(regimes=args.regime or None, budget_s=args.budget,
-                   seed=args.seed, speed=args.speed,
-                   objective=args.objective, out_dir=args.file_to,
+                   seed=args.seed, speed=args.speed, out_dir=args.file_to,
                    log=lambda s: print(s, flush=True))
         print(json.dumps({k: v for k, v in rep.items() if k != "rows"},
                          indent=1, default=str))

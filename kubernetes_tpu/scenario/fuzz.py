@@ -1,7 +1,6 @@
 """Adversarial workload fuzzer: search generator parameter space for
-the trace that maximizes trace-time p99 time-to-bind (or per-placement
-regret), and auto-file SLO-breaching traces as permanent regression
-gates.
+the trace that maximizes trace-time p99 time-to-bind, and auto-file
+SLO-breaching traces as permanent regression gates.
 
 Search shape: seeded random sampling over each regime's declared
 parameter bounds, then coordinate-descent refinement around the worst
@@ -70,14 +69,12 @@ def _perturb(rng: random.Random, params: dict, bounds: dict,
     return out
 
 
-def _score(report: dict, objective: str) -> float:
+def _score(report: dict) -> float:
     if not report.get("completed"):
         # a wedged trace is the worst outcome there is — but it can't be
         # filed as a gate (it never produces a stable verdict), so rank
         # it high without letting it win over real completed tails
         return float(report["stats"]["time_to_bind_p99_ms"]) + 1.0
-    if objective == "regret":
-        return float(report.get("regret", {}).get("regret_p99", 0.0))
     return float(report["stats"]["time_to_bind_p99_ms"])
 
 
@@ -86,8 +83,7 @@ def _gate_from(stats: dict) -> dict:
             for m in GATED_METRICS if m in stats}
 
 
-def file_regression_trace(trace: Trace, report: dict, out_dir: str,
-                          objective: str) -> str:
+def file_regression_trace(trace: Trace, report: dict, out_dir: str) -> str:
     """Re-stamp the losing trace with its ratchet gate + provenance and
     write it as JSON-lines under ``out_dir``."""
     trace.gate = _gate_from(report["stats"])
@@ -95,7 +91,7 @@ def file_regression_trace(trace: Trace, report: dict, out_dir: str,
         **trace.meta,
         "filed_by": "scenario.fuzz",
         "filed_speed": report["speed"],
-        "objective": objective,
+        "objective": "p99",
         "observed": dict(report["stats"]),
         "violated_slo": dict(report["slo"]["target"]),
         "breaches": report["slo"]["breaches"],
@@ -108,11 +104,10 @@ def file_regression_trace(trace: Trace, report: dict, out_dir: str,
 
 
 def fuzz(regimes: Optional[list[str]] = None, budget_s: float = 120.0,
-         seed: int = 0, speed: float = 3.0, objective: str = "p99",
+         seed: int = 0, speed: float = 3.0,
          out_dir: Optional[str] = None, refine_rounds: int = 2,
          replay_timeout_s: float = 60.0,
-         log: Callable[[str], None] = lambda s: None,
-         config=None) -> dict:
+         log: Callable[[str], None] = lambda s: None) -> dict:
     """Run the adversarial search; returns the summary report.
 
     Phase 1 (random): round-robin the regimes, sampling params inside
@@ -130,26 +125,17 @@ def fuzz(regimes: Optional[list[str]] = None, budget_s: float = 120.0,
     candidates = []
     worst = None            # (score, trace, report)
     filed_best: dict[str, tuple] = {}   # regime -> (score, trace, report)
-    if objective == "regret" and config is None:
-        import tempfile
-
-        from kubernetes_tpu.config.types import default_config
-        config = default_config()
-        config.trace_export_path = os.path.join(
-            tempfile.mkdtemp(prefix="scenario-fuzz-"), "export.jsonl")
-        config.trace_export_alts = True
 
     def run_candidate(regime: str, params: dict, cand_seed: int):
         nonlocal worst
         trace = GENERATORS[regime].generate(params, seed=cand_seed)
         try:
             report = replay_trace(trace, speed=speed,
-                                  timeout_s=replay_timeout_s,
-                                  config=config)
+                                  timeout_s=replay_timeout_s)
         except Exception as exc:  # noqa: BLE001 — a crashing candidate
             log(f"  {regime} seed={cand_seed} CRASHED: {exc!r}")
             return None           # is logged, not fatal to the search
-        score = _score(report, objective)
+        score = _score(report)
         row = {"regime": regime, "seed": cand_seed, "params": params,
                "score": round(score, 2),
                "slo_ok": report["slo"]["ok"],
@@ -211,11 +197,9 @@ def fuzz(regimes: Optional[list[str]] = None, budget_s: float = 120.0,
     filed = []
     if out_dir:
         for regime, (_, trace, report) in sorted(filed_best.items()):
-            filed.append(file_regression_trace(trace, report, out_dir,
-                                               objective))
+            filed.append(file_regression_trace(trace, report, out_dir))
             log(f"  filed {filed[-1]}")
     return {
-        "objective": objective,
         "speed": speed,
         "budget_s": budget_s,
         "elapsed_s": round(time.time() - t0, 1),

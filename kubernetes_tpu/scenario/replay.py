@@ -32,7 +32,6 @@ Replay semantics:
 
 from __future__ import annotations
 
-import copy
 import os
 import time
 from typing import Callable, Optional
@@ -188,18 +187,12 @@ def _warmup(hub: Hub, sched: Scheduler, now, sleep,
 
 def replay_trace(trace: Trace, speed: float = 10.0, warmup: bool = True,
                  timeout_s: float = 180.0,
-                 config: Optional[object] = None,
                  now: Callable[[], float] = time.time,
                  sleep: Callable[[float], None] = time.sleep) -> dict:
-    """Replay one trace; return the full report (stats + verdicts).
-
-    ``config`` (a SchedulerConfiguration) overrides the defaults BEFORE
-    the trace's own config hints are applied — the fuzzer uses it to
-    turn on the alt-export needed for the regret objective.
-    """
+    """Replay one trace; return the full report (stats + verdicts)."""
     speed = max(float(speed), 1e-6)
     tcfg = trace.config or {}
-    cfg = copy.deepcopy(config) if config is not None else default_config()
+    cfg = default_config()
     cfg.batch_size = int(tcfg.get("batch_size", 32))
     # replaying a K×-compressed world compresses the scheduler's time
     # constants too: un-scaled backoff would make a retry cost K trace-
@@ -398,25 +391,4 @@ def replay_trace(trace: Trace, speed: float = 10.0, warmup: bool = True,
         "ok": completed and audit_ok and slo_verdict["ok"]
         and gate_verdict["ok"],
     }
-    # regret objective support (learn/regret.py over export-v3 alt
-    # rows) — only when the caller's config exported alternatives
-    if getattr(cfg, "trace_export_path", None) \
-            and getattr(cfg, "trace_export_alts", False):
-        try:
-            from kubernetes_tpu.learn import regret as RG
-            from kubernetes_tpu.learn.replay import (
-                iter_placement_rows,
-                iter_trace_lines,
-            )
-
-            paths = [cfg.trace_export_path + ".1", cfg.trace_export_path]
-            rows = [r for pth in paths if os.path.exists(pth)
-                    for r in iter_placement_rows(iter_trace_lines(pth))]
-            evicted, node_domain = RG.harvest_hub_outcomes(hub)
-            keep = trace_pod_uids | evicted
-            rows = [r for r in rows if r.get("uid") in keep]
-            report["regret"] = RG.summarize_regret(
-                RG.compute_regret(rows, evicted, node_domain))
-        except Exception:  # noqa: BLE001 — a torn export must not fail
-            pass           # the replay it decorates
     return report

@@ -62,7 +62,6 @@ from kubernetes_tpu.utils.gcguard import guard as gc_guard
 from kubernetes_tpu.utils.tracing import FlightRecorder, PodTimelines
 from kubernetes_tpu.models.pipeline import (
     ADAPTIVE_PCT,
-    ALT_NONE,
     FILTER_PLUGINS,
     BatchResult,
     extract_state_jit,
@@ -85,6 +84,28 @@ SLOW_CYCLE_SECONDS = 0.1
 # commit batch k-1 while launches k and k+1 queue on the device, which
 # hides the device wait entirely when host commit time ~ device time
 PIPELINE_DEPTH = 2
+
+
+@dataclasses.dataclass
+class Wave:
+    """One dispatched launch on its way from _dispatch to _finish: the
+    pods it carries, its BatchResult, when its dispatch span closed and
+    how long the cycle took up to there, the cycle's trace, whether the
+    flight recorder was exporting when it launched (the pull then takes
+    the scores too), the profiler's shape key and whether the launch
+    compiled, and the commit thread's future of its pull (None when the
+    pull runs inline)."""
+
+    runnable: list
+    out: BatchResult
+    t_dispatched: float
+    dispatch_s: float
+    trace: object
+    exporting: bool
+    shape: Optional[tuple]
+    compiled: bool
+    pull: object
+
 
 # chain-surviving churn bounds: above CHAIN_PATCH_MAX pending patches a
 # full resync is cheaper than the scatter (and the pow2 patch buckets are
@@ -257,17 +278,6 @@ class Scheduler:
         self.timelines = PodTimelines(
             capacity=getattr(self.config, "timelines_capacity", 4096),
             now=now)
-        # placement FEATURE export (the replay-training substrate) is
-        # opt-in on top of the export itself: phase-timing export users
-        # must not pay the feature kernels + extra D2H + line growth
-        self._export_feats = (self.flight.exporting and getattr(
-            self.config, "trace_export_features", False))
-        # placement ALTERNATIVE export (top-K candidate node scores, the
-        # regret counterfactual substrate): same opt-in discipline — it
-        # compiles a [B, K] top_k into every launch and rides the
-        # existing per-cycle pull
-        self._export_alts = (self.flight.exporting and getattr(
-            self.config, "trace_export_alts", False))
         if self.flight.enabled:
             for fw in self.frameworks.values():
                 fw.plugin_timer = self.flight.plugin_observe
@@ -332,11 +342,6 @@ class Scheduler:
                    # unfiltered, exactly as the host path did
                    "dra_filter": "DynamicResources" in {
                        n for n, _ in fw.points["filter"]},
-                   # the profile-gated learned scorer's checkpoint
-                   # manager (plugins/learned.py); None unless the
-                   # profile enables the LearnedScore plugin — the
-                   # launch then compiles the MLP term out entirely
-                   "learned": fw.instance("LearnedScore"),
                    # device gang packing only engages for profiles that
                    # run the GangScheduling plugin at all — without it
                    # gang labels are inert and members are plain pods
@@ -465,7 +470,7 @@ class Scheduler:
         # commit thread does ONLY jax.device_get (+ the chaos seam) —
         # host mutation (assume/bind/queue/timeline) stays on the
         # single-mutator loop thread, preserving the _wrap threading
-        # model; exceptions surface in _finish via fut.result() and ride
+        # model; exceptions surface in _finish via wave.pull and ride
         # the existing _finish_contained blast-radius ladder.
         self._commit_pool: Optional[ThreadPoolExecutor] = (
             ThreadPoolExecutor(max_workers=1, thread_name_prefix="commit")
@@ -1113,16 +1118,16 @@ class Scheduler:
     # into the quarantine set with escalating backoff, a hub Event, and
     # a metric. The daemon never dies because the accelerator path did.
 
-    def _finish_contained(self, inflight: tuple) -> None:
+    def _finish_contained(self, wave: Wave) -> None:
         """_finish with blast-radius containment: an exception commits
         nothing further and routes the batch's still-pending pods down
         the ladder instead of escaping the loop."""
         try:
-            self._finish(inflight)
+            self._finish(wave)
         except Unavailable:
-            self._park_batch_unreachable(self._still_pending(inflight[0]))
+            self._park_batch_unreachable(self._still_pending(wave.runnable))
         except Exception as e:  # noqa: BLE001 — the containment seam
-            self._contain_batch_fault(inflight[0], e)
+            self._contain_batch_fault(wave.runnable, e)
 
     def _still_pending(self, runnable: list[QueuedPodInfo]
                        ) -> list[QueuedPodInfo]:
@@ -1647,12 +1652,12 @@ class Scheduler:
         return True
 
     def _dispatch(self, runnable: list[QueuedPodInfo], chained: bool,
-                  tr, flush_pending=None) -> Optional[tuple]:
+                  tr, flush_pending=None) -> Optional[Wave]:
         """Pack + launch one batch (async dispatch; no host<->device block).
-        Returns (runnable, BatchResult) or None if every pod was routed to
+        Returns the dispatched Wave, or None if every pod was routed to
         the failure path during packing. ``tr`` is the cycle's trace, open
-        since its pop (_pop_runnable); _finish records it (the dispatched
-        tuple carries it through the pipelined drain). ``flush_pending``
+        since its pop (_pop_runnable); _finish records it (the wave
+        carries it through the pipelined drain). ``flush_pending``
         commits a still-in-flight previous launch before any fallback
         re-sync, so a chained dispatch that has to re-bucket never syncs a
         cache missing the previous batch's placements."""
@@ -1748,35 +1753,6 @@ class Scheduler:
         else:
             raise RuntimeError("mirror re-bucketing did not converge")
 
-        # learned scorer (profile-gated): poll the checkpoint's mtime at
-        # snapshot-sync time — a stat when unchanged, a load + H2D push
-        # when an offline trainer published a new version. Params then
-        # ride this launch as one more weighted term; a reload mid-run
-        # never recompiles (same architecture = same jit signature).
-        learned_params = None
-        mgr = pcfg["learned"]
-        if mgr is not None:
-            with span("learned_score", tr):
-                mgr.maybe_reload()
-                learned_params = mgr.params()
-            # reloads = swaps AFTER the initial load (the manager's
-            # count); errors delta-mirrored like other external counts
-            # the generation label rides the delta at reload time:
-            # promoted-vs-manual publishes stay distinguishable in the
-            # fleet scrape (generation 0 = manual)
-            self._mirror_count(f"learned_reloads:{prof}", mgr.reloads,
-                               self.metrics.learned_reloads,
-                               profile=prof,
-                               generation=str(mgr.generation))
-            w = getattr(mgr, "_watcher", None)
-            if w is not None:
-                self._mirror_count(f"learned_errs:{prof}", w.load_errors,
-                                   self.metrics.learned_load_errors,
-                                   profile=prof)
-            self.metrics.learned_checkpoint_version.set(
-                float(mgr.version if learned_params is not None else 0),
-                profile=prof)
-
         # batched DRA allocator: pack this batch's claim tensors and fuse
         # the device verdict into the launch (ops/dra.py + the dra arg of
         # schedule_batch). Pods whose claims sit outside the device-
@@ -1843,13 +1819,11 @@ class Scheduler:
             with span("host_plugins", tr):
                 host_ok, host_score = self._run_host_plugins(runnable)
         fit_strategy, fit_shape = pcfg["fit"]
-        # export-pull flags captured ONCE: the launch compiles against
-        # them and the commit thread pulls against them, so they must be
-        # the same observation (a rotation-disabled export mid-cycle
-        # must not desync the pull list from the launch outputs)
+        # captured ONCE: the commit thread pulls the scores against it,
+        # and _finish writes the placement rows against the same
+        # observation (a rotation-disabled export mid-cycle must not
+        # desync the two)
         exporting = self.flight.exporting
-        want_feats = self._export_feats and exporting
-        want_alts = self._export_alts and exporting
         if state is None:
             # seed the usage chain from the freshly synced mirror so every
             # launch carries explicit state: one jit signature for chained
@@ -1865,12 +1839,7 @@ class Scheduler:
             # arg pytree and therefore one trace/compile
             pct_start=(self._pct_start if self._pct_start is not None
                        else np.int32(0)) if pct else None,
-            learned=learned_params, tie_seed=self._tie_seed,
-            # chosen-node feature rows only materialize while the
-            # feature export is opted in AND the export file is still
-            # open (a failed rotation disables the export; the feature
-            # kernels must not keep running for output nobody pulls)
-            with_feats=want_feats, with_alts=want_alts)
+            tie_seed=self._tie_seed)
         if self.fault_injector is not None:
             out = self.fault_injector.on_result(out)
         if pct:
@@ -1904,9 +1873,8 @@ class Scheduler:
             pshape = shape_key(
                 self.caps, spec.pblobs.f32.shape[0],
                 spec.enable_topology, spec.d_cap, spec.g_cap,
-                not use_auction, spec.dra is not None,
-                learned_params is not None, want_feats,
-                alts=want_alts, soft=spec.topo_soft, active=spec.active)
+                not use_auction, spec.dra is not None, False, False,
+                soft=spec.topo_soft, active=spec.active)
             compiled = prof.note_launch(
                 pshape, len(runnable),
                 None if use_auction else scan_steps_for(
@@ -1919,16 +1887,12 @@ class Scheduler:
                 prof.note_buffers({
                     "cluster": tree_nbytes(spec.cblobs),
                     "pods": tree_nbytes(spec.pblobs),
-                    "dra": tree_nbytes(spec.dra),
-                    "learned": tree_nbytes(learned_params)})
+                    "dra": tree_nbytes(spec.dra)})
         # off-thread commit: the wave's blocking D2H pull rides the
         # commit thread from HERE, so it overlaps whatever the loop (and
-        # the device) does next; _finish harvests the future. The flags
-        # tuple snapshots what the launch actually compiled so the pull
-        # list matches its outputs.
-        flags = (learned_params is not None, exporting,
-                 want_feats, want_alts)
-        fut = (self._commit_pool.submit(self._pull_launch, out, flags, tr)
+        # the device) does next; _finish harvests the future
+        fut = (self._commit_pool.submit(self._pull_launch, out, exporting,
+                                        tr)
                if self._commit_pool is not None else None)
         # the span closes on the hand-over: the profiler's note and the
         # submit are the dispatch's own work (a commit_pull that began
@@ -1938,8 +1902,8 @@ class Scheduler:
         del spec, state
         disp.end()
         t_done = disp.t1
-        return (runnable, out, t_done, t_done - t_cycle0, tr,
-                flags, pshape, compiled, fut)
+        return Wave(runnable, out, t_done, t_done - t_cycle0, tr,
+                    exporting, pshape, compiled, fut)
 
     # ------------- device-side gang packing (ISSUE 12) -------------
     #
@@ -2549,53 +2513,42 @@ class Scheduler:
                         host_score[i, row] += sc
         return host_ok, host_score
 
-    def _pull_launch(self, out: BatchResult, flags: tuple,
+    def _pull_launch(self, out: BatchResult, exporting: bool,
                      tr=None) -> tuple:
         """The commit-thread half of _finish: ONE blocking D2H pull of the
-        launch's verdict tensors (rows + guard + the flag-gated
-        learned-magnitude / export tensors — a second device_get would be
-        a second full round trip). Under pipelined waves this runs on the
+        launch's verdict tensors (rows + guard, and the scores while the
+        flight recorder exports — a second device_get would be a second
+        full round trip). Under pipelined waves this runs on the
         commit thread so the transfer wait — the wave's actual
         serialization — overlaps the next wave's device time. It touches
         NO host state (the single-mutator invariant: assume/bind/queue
         mutation stays on the loop thread) and takes no locks; exceptions
         (including the chaos commit_pull seam) surface in _finish via
-        fut.result() and ride the normal containment ladder. Returns
-        (vals, t_ready) — t_ready timestamps verdict availability (the
-        honest end of the device span). With ``tr`` (off-thread) this
+        the wave's pull future and ride the normal containment ladder.
+        Returns (vals, t_ready) — t_ready timestamps verdict availability
+        (the honest end of the device span). With ``tr`` (off-thread) this
         thread's own wall inside the pull is the cycle's overlapped
         commit_pull span, reported HERE, on this thread, when the pull
         ends; inline, the caller's device_launch span covers it."""
         if tr is None:
-            return self._pull_verdicts(out, flags), self.now()
+            return self._pull_verdicts(out, exporting), self.now()
         with self.flight.span("commit_pull", tr) as pull:
-            vals = self._pull_verdicts(out, flags)
+            vals = self._pull_verdicts(out, exporting)
         return vals, pull.t1
 
-    def _pull_verdicts(self, out: BatchResult, flags: tuple) -> tuple:
-        learned_on, exporting, want_feats, want_alts = flags
+    def _pull_verdicts(self, out: BatchResult, exporting: bool) -> tuple:
+        """(node_row, guard, score); score is None unless exporting."""
         fi = self.fault_injector
         if fi is not None:
             hook = getattr(fi, "on_commit_pull", None)
             if hook is not None:
                 hook()          # chaos seam: may raise
-        pull = [out.node_row, out.guard]
-        if learned_on:
-            pull.append(out.learned_mag)
-        if exporting:
-            pull.append(out.score)
-            if want_feats:
-                pull.append(out.chosen_feat)
-            if want_alts:
-                pull.append(out.alt_row)
-                pull.append(out.alt_score)
-        return jax.device_get(tuple(pull))
+        return jax.device_get((out.node_row, out.guard,
+                               out.score if exporting else None))
 
-    def _finish(self, inflight: tuple) -> None:
+    def _finish(self, wave: Wave) -> None:
         """Pull one dispatched launch's results and commit/fail each pod."""
-        (runnable, out, t_dispatched, pack_s, tr, flags,
-         pshape, compiled, fut) = inflight
-        learned_on, exporting, want_feats, want_alts = flags
+        runnable, out, tr = wave.runnable, wave.out, wave.trace
         # re-attach the cycle's trace: the pipelined drain may have
         # dispatched k+1 (opening its trace) before finishing k
         self.flight.resume(tr)
@@ -2610,8 +2563,8 @@ class Scheduler:
         # blast-radius ladder an inline fault would. Pipelining off: the
         # pull runs inline and the loop is blocked for all of it.
         with span("device_launch", tr):
-            vals, t_ready = (fut.result() if fut is not None
-                             else self._pull_launch(out, flags))
+            vals, t_ready = (wave.pull.result() if wave.pull is not None
+                             else self._pull_launch(out, wave.exporting))
         # PreFilter gang-capacity reductions cannot ride the commit
         # thread's pull (they register on the loop thread, possibly
         # after dispatch); rare — gang PreFilter only — so they get
@@ -2622,22 +2575,7 @@ class Scheduler:
                 tuple(arr for _key, _tok, arr in cap_pulls))
             for (ckey, ctok, _arr), v in zip(cap_pulls, cvals):
                 self._gang.resolve_cap(ckey, ctok, int(v))
-        rows_arr, guard = vals[0], vals[1]
-        k = 2
-        lmag = None
-        if learned_on:
-            lmag = vals[k]
-            k += 1
-        scores_arr = feats_arr = alt_rows_arr = alt_scores_arr = None
-        if exporting:
-            scores_arr = vals[k]
-            k += 1
-            if want_feats:
-                feats_arr = vals[k]
-                k += 1
-            if want_alts:
-                alt_rows_arr = vals[k]
-                alt_scores_arr = vals[k + 1]
+        rows_arr, guard, scores_arr = vals
         if int(guard):
             # the launch's own guard reduction tripped: NaN scores or a
             # poisoned usage chain — nothing below can be trusted; the
@@ -2646,50 +2584,22 @@ class Scheduler:
                 f"launch guard tripped (mask {int(guard):#x}): "
                 f"{'NaN scores ' if int(guard) & 1 else ''}"
                 f"{'poisoned usage state' if int(guard) & 2 else ''}")
-        if lmag is not None:
-            # observed only AFTER the guard check: a NaN-poisoned
-            # checkpoint must not corrupt the magnitude histogram's sum
-            # forever (Histogram.observe accumulates the raw value)
-            self.metrics.learned_magnitude.observe(float(lmag))
         rows = np.asarray(rows_arr)[:n].tolist()
         # the device span ends when the verdict pull completed (t_ready,
         # stamped by whichever thread ran it) — under pipelining the loop
         # may harvest the future long after, and that host overlap time
         # must not masquerade as device time
-        launch_s = max(t_ready - t_dispatched, 0.0)
-        if exporting:
-            # export v2/v3 placement rows: (pod, chosen node, aggregate
-            # score[, chosen-node feature vector when
-            # trace_export_features][, top-K alternative node scores
-            # when trace_export_alts]) — the replay dataset's substrate,
-            # already pulled with rows+guard above. Failed attempts
-            # export node=None (time-to-bind anchors).
+        launch_s = max(t_ready - wave.t_dispatched, 0.0)
+        if scores_arr is not None:
+            # export v2 placement rows: (pod, chosen node, aggregate
+            # score), the score already pulled with rows+guard above.
+            # Failed attempts export node=None (time-to-bind anchors).
             placements = []
             for i, (qp, row) in enumerate(zip(runnable, rows)):
                 rec = {"pod": qp.pod.key(), "uid": qp.uid}
                 if row >= 0:
                     rec["node"] = self.mirror.name_of_row(row)
                     rec["score"] = round(float(scores_arr[i]), 4)
-                    if feats_arr is not None:
-                        rec["feat"] = [round(float(v), 5)
-                                       for v in feats_arr[i]]
-                    if alt_rows_arr is not None:
-                        # the chosen node's own entry RIDES ALONG when
-                        # top_k surfaced it: on the auction path the
-                        # alt scores are end-state attributed while
-                        # "score" is the decision-round win — regret
-                        # must compare chosen vs alternatives on ONE
-                        # basis, so the offline consumer prefers the
-                        # chosen node's in-list score as its value
-                        alt = []
-                        for ar, asc in zip(alt_rows_arr[i],
-                                           alt_scores_arr[i]):
-                            if int(ar) < 0 or float(asc) <= ALT_NONE / 2:
-                                continue
-                            nm = self.mirror.name_of_row(int(ar))
-                            if nm:
-                                alt.append([nm, round(float(asc), 4)])
-                        rec["alt"] = alt
                 else:
                     rec["node"] = None
                 # the wire-trace stamps known at commit time (the
@@ -2740,10 +2650,10 @@ class Scheduler:
             # after a cycle's last span is nobody's phase): all but the
             # record itself, which flushes the spans and so follows them
             commit_s = self.now() - t1
-            cycle_s = pack_s + launch_s + commit_s
-            if self.profiler is not None and pshape is not None:
-                self.profiler.observe_walltime(pshape, launch_s)
-                if compiled:
+            cycle_s = wave.dispatch_s + launch_s + commit_s
+            if self.profiler is not None and wave.shape is not None:
+                self.profiler.observe_walltime(wave.shape, launch_s)
+                if wave.compiled:
                     # attribution view: this cycle's launch walltime was
                     # (mostly) an XLA compile — the stall MixedChurn's
                     # re-bucketing pays, now visible per phase
@@ -2754,7 +2664,7 @@ class Scheduler:
             m.algorithm_duration.observe(launch_s)
             m.batch_duration.observe(cycle_s)
             m.extension_point_duration.observe(
-                pack_s, extension_point="PreFilter")
+                wave.dispatch_s, extension_point="PreFilter")
             m.extension_point_duration.observe(
                 launch_s, extension_point="Filter")
             m.extension_point_duration.observe(
@@ -2792,17 +2702,17 @@ class Scheduler:
                 runnable = self._schedule_gang_units(runnable)
             if runnable:
                 try:
-                    inflight = self._dispatch(
+                    wave = self._dispatch(
                         runnable, self._chain_eligible(
                             [qp.pod for qp in runnable]), tr)
                 except Unavailable:
                     self._park_batch_unreachable(runnable)
-                    inflight = None
+                    wave = None
                 except Exception as e:  # noqa: BLE001 — containment seam
                     self._contain_batch_fault(runnable, e)
-                    inflight = None
-                if inflight is not None:
-                    self._finish_contained(inflight)
+                    wave = None
+                if wave is not None:
+                    self._finish_contained(wave)
             self._drain_bind_results(wait=True)
             # async preemption: victims queued by PostFilter are evicted
             # here, OUTSIDE the cycle (prepareCandidateAsync's analog)
@@ -3934,7 +3844,7 @@ class Scheduler:
         # dispatching the newest gives the device a whole iteration of
         # host-side commit work as head start (the batched analog of the
         # reference's scheduling/binding goroutine overlap, P3)
-        pending: deque[tuple] = deque()
+        pending: deque[Wave] = deque()
 
         def flush_all() -> None:
             while pending:
@@ -4023,9 +3933,9 @@ class Scheduler:
                 if nxt is not None:
                     pending.append(nxt)
                     # pipeline-depth observability: how many waves were
-                    # in flight right after this dispatch (tr is tuple
-                    # element 4) — the stall detector for satellite runs
-                    nxt[4].depth = len(pending)
+                    # in flight right after this dispatch — the stall
+                    # detector for satellite runs
+                    nxt.trace.depth = len(pending)
             # keep up to PIPELINE_DEPTH launches outstanding: batch k-1 is
             # committed only after k AND k+1 are queued, so the device gets
             # a full iteration (dispatch + commit) of head start. The
@@ -4038,7 +3948,7 @@ class Scheduler:
                 # reads PIPELINE_DEPTH, a stalled one 1. Waves the trim
                 # itself committed (the off arm) keep their dispatch-time
                 # depth of 1.
-                nxt[4].depth = len(pending)
+                nxt.trace.depth = len(pending)
             # async preemption evictions run between cycles (kep 4832)
             self._flush_evictions_safe()
         flush_all()

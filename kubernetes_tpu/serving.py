@@ -21,10 +21,6 @@ Flight-recorder surface:
   lifecycle timeline (enqueue/pop/bind/park stamps) and its last
   unschedulable diagnosis (which device filter rejected how many nodes,
   which host plugin rejected).
-- ``/debug/scorer`` — per-profile learned-scorer state (active
-  checkpoint version/fingerprint, learn-loop generation + the regret
-  summaries stamped by the promotion gate, reload and load-error
-  counts).
 """
 
 from __future__ import annotations
@@ -155,18 +151,6 @@ class ServingEndpoints:
                         "device": (prof.snapshot() if prof is not None
                                    else None),
                     }, indent=2, default=str)
-                elif path == "/debug/scorer":
-                    # learned-scorer state per profile: checkpoint
-                    # path/version/fingerprint, learn-loop generation
-                    # + promoted-meta regret view, reload + load-error
-                    # counts (plugins/learned.py manager stats)
-                    payload = {}
-                    for name, pcfg in getattr(sched, "_profile_cfg",
-                                              {}).items():
-                        mgr = (pcfg or {}).get("learned")
-                        payload[name] = (mgr.stats() if mgr is not None
-                                         else {"enabled": False})
-                    body = json.dumps(payload, indent=2, default=str)
                 elif path == "/debug/fabric":
                     # control-plane fabric surface: the hub's shard map
                     # + per-shard journal state (ShardedHub), and the

@@ -33,8 +33,8 @@ and execution indistinguishably. This instrument attributes it:
   of the table's capacity.
 * **Live buffer bytes** — the HBM footprint of what the scheduler keeps
   resident: the nodes×resources cluster tensors, the per-batch pod
-  tensors, the dense DRA inventories, the learned-scorer params
-  (``.nbytes`` over the pytrees; metadata reads, no device sync).
+  tensors, the dense DRA inventories (``.nbytes`` over the pytrees;
+  metadata reads, no device sync).
 
 Surfaced as ``scheduler_device_*`` metrics, the ``device_compile``
 flight-recorder view phase (a compiling launch's walltime, double-
@@ -53,6 +53,7 @@ _CAP_FIELDS = ("nodes", "pods", "pod_labels", "node_labels", "domains",
                "ext_resources", "domain_cap")
 
 
+# learned, with_feats and alts are always False: benchmark code pins them
 def shape_key(caps, b_bucket: int, enable_topology: bool, d_cap,
               g_cap: int, serial_scan: bool, dra: bool, learned: bool,
               with_feats: bool, gang: int = 0,
@@ -62,8 +63,7 @@ def shape_key(caps, b_bucket: int, enable_topology: bool, d_cap,
     shape buckets, as a flat hashable tuple. ``gang`` is the gang-pack
     launch's gang-row bucket (0 for the normal scheduling launch) — a
     gang-shape recompile attributes to its own row instead of landing
-    in "unattributed". ``alts`` is the with_alts static flag (the
-    export v3 top-K candidate kernels); ``soft`` is the topo_soft
+    in "unattributed". ``soft`` is the topo_soft
     static flag (the reduced soft-topology program);
     ``active`` the launch features compiled into the program
     (``LaunchSpec.active``: "nodeaffinity", "taints", ...), last, so a
@@ -148,7 +148,7 @@ def _diff_cause(prev: Optional[tuple], cur: tuple) -> str:
     if changed & {"topo", "d_cap", "g_cap"}:
         return "topology_bucket"
     if changed:
-        return "flags"                    # dra/learned/feats/commit mode
+        return "flags"                    # dra/commit mode
     return "unattributed"                 # same shape, cache still grew
 
 
@@ -256,7 +256,7 @@ class DeviceProfiler:
 
     def note_buffers(self, buffers: dict[str, int]) -> None:
         """Record the live device-buffer footprint by buffer family
-        (cluster / pods / dra / learned), bytes."""
+        (cluster / pods / dra), bytes."""
         self.buffer_bytes = dict(buffers)
         if self._metrics is not None:
             for name, nbytes in buffers.items():

@@ -81,12 +81,7 @@ CYCLE_PHASES = (
                           # DynamicResources PreFilter/Filter time (view;
                           # the fused in-launch eval rides device_launch)
     "dra_commit",         # DynamicResources Reserve/PreBind time (view)
-    "learned_score",      # learned-scorer checkpoint mtime poll /
-                          # reload / params fetch at snapshot-sync time
-                          # (a REAL exclusive phase, counted in totals —
-                          # a slow checkpoint path must show up in the
-                          # A/B latency gate; the fused MLP eval itself
-                          # rides device_launch)
+    "learned_score",      # opened by nothing: benchmark/host_wait.py names it
     "device_compile",     # launch walltime of a cycle whose dispatch
                           # triggered an XLA compile (view: the same
                           # seconds already sit in device_launch — the
@@ -158,10 +153,7 @@ LOOP_VIEW_PHASES = (
 # (they double-count time already inside pack/host_plugins/commit)
 DRA_VIEW_PHASES = ("dra_mask_compile", "dra_device_eval", "dra_commit")
 
-# attribution views excluded from cycle totals and the host-tail share.
-# NOTE: learned_score is NOT here — its time is exclusive (nothing else
-# measures the checkpoint poll), so hiding it would let a slow reload
-# path pass the --ab-scorer parity gate unseen
+# attribution views excluded from cycle totals and the host-tail share
 VIEW_PHASES = DRA_VIEW_PHASES + (
     "device_compile", "snapshot_cache", "mirror_sync") + LOOP_VIEW_PHASES
 
@@ -193,12 +185,8 @@ EXCLUDED_PHASES = VIEW_PHASES + OVERLAP_PHASES
 UNCOUNTED_PHASES = frozenset(EXCLUDED_PHASES + LOOP_PHASES)
 
 # trace-export JSON-lines format version (CycleTrace.to_dict "v"):
-# v2 added per-pod placement rows (pod, chosen node, aggregate score,
-# chosen-node learned-feature vector) — the replay-dataset substrate;
-# v3 adds the opt-in top-K alternative-node scores per placement
-# ("alt": [[node, score], ...], trace_export_alts) — the counterfactual
-# substrate behind per-placement regret (learn/regret.py). Additive:
-# v2 rows remain valid replay input (learn/replay.py reads >= 2).
+# v2 added per-pod placement rows (pod, uid, chosen node, aggregate
+# score, and the wire stamps known at commit time).
 # v4 adds "spans": every phase span of the cycle as [name, start, end,
 # thread] on the recorder's clock (phases_ms stays: it is their sums).
 # v5 gives each span a fifth element, the milliseconds between its two
@@ -231,7 +219,7 @@ HOST_PHASES = (
     "queue_pop", "snapshot_sync", "chain_patch", "host_plugins", "pack",
     "commit",
     "failure_handling", "binder_drain", "eviction_flush", "host_fallback",
-    "learned_score", "gang_commit",
+    "gang_commit",
 )
 
 
@@ -275,9 +263,9 @@ class CycleTrace:
         self.spans: list[tuple] = []
         self.plugins: dict[str, float] = {}   # "plugin/point" -> secs
         # per-pod placement rows (export v2+): {"pod", "uid", "node",
-        # "score"[, "feat"][, "alt"]} — node None for failed attempts,
-        # "alt" the v3 top-K alternative (node, score) pairs. Populated
-        # by the scheduler only while the export file is open.
+        # "score"[, "wire"]} — node None (and no score) for failed
+        # attempts. Populated by the scheduler only while the export
+        # file is open.
         self.placements: list[dict] | None = None
 
     def add(self, phase: str, secs: float) -> None:

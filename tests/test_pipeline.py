@@ -21,10 +21,11 @@ from kubernetes_tpu.ops.features import Capacities
 CAPS = Capacities(nodes=16, pods=64)
 
 
-def _run(mirror, pods, batch=8):
+def _run(mirror, pods, batch=8, tie_seed=None):
     return schedule_batch_jit(mirror.to_blobs(),
                               mirror.pack_batch_blobs(pods, batch),
-                              mirror.well_known(), default_weights(), CAPS)
+                              mirror.well_known(), default_weights(), CAPS,
+                              tie_seed=tie_seed)
 
 
 def test_batch_places_all_when_space():
@@ -498,3 +499,31 @@ def test_a_launch_without_topology_reads_no_table_block(serial_scan):
     out = _launch(mirror, pods, 16, SCAN_CAPS, serial_scan=serial_scan)
     assert int(out.table_blocks) == 0
     assert (np.asarray(out.node_row)[:5] >= 0).all()
+
+
+def test_tie_seed_runs_are_reproducible():
+    """Eight nodes that tie on every score: the same seed places the
+    same batch the same way, and seed 0 is the unseeded hash stream."""
+    from kubernetes_tpu.models.testbed import make_node
+    from kubernetes_tpu.backend.cache import Cache
+    from kubernetes_tpu.backend.mirror import Mirror
+    from kubernetes_tpu.backend.snapshot import Snapshot
+
+    cache = Cache()
+    for i in range(8):
+        node = make_node(i, zones=1)
+        node.status.images = []
+        cache.add_node(node)
+    snap = Snapshot()
+    cache.update_snapshot(snap)
+    mirror = Mirror(caps=CAPS)
+    mirror.sync(snap)
+    pods = [make_pod(i) for i in range(6)]
+
+    def rows(seed=None):
+        return np.asarray(_run(mirror, pods, tie_seed=seed).node_row
+                          )[:6].tolist()
+
+    seed = np.uint32(424242)
+    assert rows(seed) == rows(seed), "same seed, same batch, same rows"
+    assert rows(np.uint32(0)) == rows(), "seed 0 is the historical hash"

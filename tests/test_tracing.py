@@ -8,6 +8,8 @@ keeps its coverage in test_metrics.py.
 """
 
 import json
+import math
+import os
 import urllib.error
 import urllib.request
 
@@ -221,6 +223,45 @@ def test_recorder_jsonl_export(tmp_path):
     assert lines[2]["phases_ms"]["commit"] == 3.0
 
 
+def test_export_rotation_bounds_disk(tmp_path):
+    path = str(tmp_path / "t.jsonl")
+    rec = FlightRecorder(capacity=8, export_path=path,
+                         export_max_bytes=2000)
+    for i in range(100):
+        tr = rec.begin(start=float(i), pods=1)
+        tr.add("commit", 0.001)
+        rec.record(tr)
+    rec.close()
+    assert os.path.exists(path + ".1"), "keep-last-1 rotation happened"
+    assert os.path.getsize(path) <= 2200
+    assert os.path.getsize(path + ".1") <= 2200
+    # every surviving line is intact JSON (rotation never tears a line)
+    for p in (path, path + ".1"):
+        for line in open(p):
+            json.loads(line)
+
+
+def test_export_rotation_failure_disables_export(tmp_path, monkeypatch):
+    """A failed rotation (permissions/directory gone) must DISABLE the
+    export, not fall back to unbounded appends — the size bound is the
+    feature's contract."""
+    path = str(tmp_path / "t.jsonl")
+    rec = FlightRecorder(capacity=8, export_path=path,
+                         export_max_bytes=500)
+
+    def deny(*_a):
+        raise OSError("denied")
+
+    monkeypatch.setattr("kubernetes_tpu.utils.tracing.os.replace", deny)
+    for i in range(50):
+        tr = rec.begin(start=float(i), pods=1)
+        tr.add("commit", 0.001)
+        rec.record(tr)
+    assert not rec.exporting, "failed rotation disables the export"
+    assert os.path.getsize(path) <= 700, "writes stopped at the bound"
+    rec.close()
+
+
 # --------------------------------------------------- PodTimelines
 
 
@@ -353,6 +394,35 @@ def test_scheduler_trace_export(tmp_path):
         sched.close()
     lines = [json.loads(ln) for ln in open(path)]
     assert lines and "phases_ms" in lines[0]
+
+
+def test_export_v2_rows_name_the_bound_node_and_carry_a_finite_score(
+        tmp_path):
+    """Each bound pod has one placement row: it names the node the pod
+    is bound to and carries a finite aggregate score, and nothing else
+    of the launch."""
+    from kubernetes_tpu.utils.tracing import EXPORT_VERSION
+
+    export = str(tmp_path / "traces.jsonl")
+    hub = Hub()
+    sched = _sched(hub, export_path=export)
+    try:
+        hub.create_node(mknode(0))
+        for i in range(4):
+            hub.create_pod(mkpod(f"p{i}"))
+        sched.run_until_idle()
+    finally:
+        sched.close()
+    lines = [json.loads(x) for x in open(export) if x.strip()]
+    assert lines and all(ln["v"] == EXPORT_VERSION for ln in lines)
+    placed = [r for ln in lines for r in ln.get("placements", [])
+              if r["node"]]
+    bound = {p.metadata.uid: p.spec.node_name for p in hub.list_pods()}
+    assert sorted(r["uid"] for r in placed) == sorted(bound)
+    for r in placed:
+        assert r["node"] == bound[r["uid"]] == "node-0"
+        assert math.isfinite(r["score"]) and r["score"] > 0
+        assert set(r) <= {"pod", "uid", "node", "score", "wire"}
 
 
 # --------------------------------------- /debug/trace + /debug/pod
